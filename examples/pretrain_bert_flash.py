@@ -39,9 +39,12 @@ def main():
     import jax.numpy as jnp
 
     import paddle_tpu as pt
+    from paddle_tpu.core.compile_cache import enable_compile_cache
     from paddle_tpu.models.bert import (BertConfig, BertForPretraining,
                                         pretrain_loss)
     from paddle_tpu.static.trainer import Trainer, TrainerConfig
+
+    enable_compile_cache()
 
     cfg = BertConfig.tiny() if args.tiny else BertConfig.base()
     cfg.dropout = 0.0

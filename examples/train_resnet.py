@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 
 import paddle_tpu as pt
+from paddle_tpu.core.compile_cache import enable_compile_cache
 from paddle_tpu.models import ResNet
 from paddle_tpu.ops import loss as L
 
@@ -23,6 +24,7 @@ def main():
     ap.add_argument("--ckpt", default=None, help="checkpoint dir")
     args = ap.parse_args()
 
+    enable_compile_cache()
     model = ResNet(args.depth, num_classes=10, small_input=True)
     variables = model.init(jax.random.key(0))
     params, state = variables["params"], variables["state"]

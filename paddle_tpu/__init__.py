@@ -31,13 +31,6 @@ Layer map (mirrors reference SURVEY.md §1, re-architected TPU-first):
 
 __version__ = "0.1.0"
 
-import jax as _jax
-
-if not hasattr(_jax.lax, "axis_size"):
-    # jax < 0.4.38 compat: psum of a Python literal folds statically to
-    # the mapped axis size — the pre-axis_size idiom
-    _jax.lax.axis_size = lambda axis_name: _jax.lax.psum(1, axis_name)
-
 from paddle_tpu.core import enforce, flags
 from paddle_tpu.core.dtype import (
     bfloat16,

@@ -662,12 +662,14 @@ def serve_verify_budget_contracts(slots=SERVE_VERIFY_SLOTS,
 # on jax-cpu (tests/test_compile_smoke.py re-measures every run):
 # measured/predicted sits at ~0.85 (train flops), ~4.3 (train bytes —
 # the traffic estimate undercounts XLA's interpret-mode and rematerial-
-# ization traffic), ~1.02 (decode flops), ~2.1 (decode bytes), so each
-# budget leaves ~1.4-1.5x headroom over today's compiles while a real
-# regression (an unfused xent materializing [rows, V] traffic, a dense
-# Tmax attention) blows through it.
+# ization traffic), ~1.02 (decode flops), ~3.4 (decode bytes: 2.117e6
+# compiled against 6.298e5 predicted under jax 0.9.0's CPU backend; it
+# was ~2.1 under 0.4.37 — the budget follows the CPU compiler, ROADMAP
+# D1), so each budget leaves ~1.4-1.5x headroom over today's compiles
+# while a real regression (an unfused xent materializing [rows, V]
+# traffic, a dense Tmax attention) blows through it.
 TRAIN_BUDGET_TOLERANCE = {"flops": 1.25, "bytes": 6.0}
-SERVE_BUDGET_TOLERANCE = {"flops": 1.5, "bytes": 3.0}
+SERVE_BUDGET_TOLERANCE = {"flops": 1.5, "bytes": 5.0}
 # verify: measured/predicted sits at ~1.4 (flops) and ~9.2 (bytes — the
 # per-position head + sampling unroll re-reads the tied embedding and
 # its [slots, vocab] rows window times; that re-read traffic is exactly

@@ -77,9 +77,10 @@ define_flag("resnet_s2d_stem", False,
 define_flag("pallas_interpret", False,
             "Run Pallas kernels in interpreter mode (CPU testing).")
 define_flag("flash_block_q", 512,
-            "Flash attention query-block size (tools/flash_tune.py sweeps).")
+            "Flash attention query-block size (the autotuner sweeps it: "
+            "tools/autotune.py sweep --kernel flash_attention).")
 define_flag("flash_block_k", 512,
-            "Flash attention key-block size (tools/flash_tune.py sweeps).")
+            "Flash attention key-block size (swept with flash_block_q).")
 # escape hatch for the Pallas fused layer_norm (ADVICE r1: gate the kernel)
 define_flag("use_pallas_layer_norm", True,
             "Route layer_norm through the Pallas TPU kernel; False forces "

@@ -1,4 +1,4 @@
-"""Model zoo — the reference's flagship configs (BASELINE.md).
+"""Model zoo — the reference's flagship configs (BASELINE.json).
 
   resnet       ResNet-18/50/101 (ImageNet/CIFAR)   ref: dist_se_resnext.py, book
   bert         BERT-base/large pretraining          ref: PaddleNLP Fluid bert

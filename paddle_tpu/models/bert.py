@@ -1,6 +1,6 @@
 """BERT — transformer encoder for masked-LM pretraining.
 
-Ref: BASELINE.md flagship "BERT-base pretraining (PaddleNLP Fluid bert/
+Ref: BASELINE.json flagship "BERT-base pretraining (PaddleNLP Fluid bert/
 recipe)". The reference frames it over fluid.layers (multi_head_attention in
 layers/nn.py + ERNIE-style recipes); here it's a first-class model with
 flash-attention, bf16 policy support, and mesh-shardable params.

@@ -1,6 +1,6 @@
 """CTR models: DeepFM and Wide&Deep — the sparse-embedding flagship path.
 
-Ref: BASELINE.md "DeepFM / Wide&Deep CTR (sparse embedding + pserver
+Ref: BASELINE.json "DeepFM / Wide&Deep CTR (sparse embedding + pserver
 distributed path)" and the reference's CTR fixture
 (/root/reference/python/paddle/fluid/tests/unittests/dist_ctr.py — embedding
 + fc over sparse slots trained against pservers).

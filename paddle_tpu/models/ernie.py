@@ -1,6 +1,6 @@
 """ERNIE 1.0 — knowledge-enhanced BERT pretraining.
 
-Ref: BASELINE.md capability target "ERNIE 1.0". ERNIE 1.0 (Baidu, 2019 —
+Ref: BASELINE.json capability target "ERNIE 1.0". ERNIE 1.0 (Baidu, 2019 —
 contemporary with the reference's Fluid BERT recipes) keeps the BERT
 transformer backbone and changes the *pretraining masking strategy*:
 instead of masking only independent word pieces, whole PHRASES and named

@@ -3,7 +3,7 @@
 Ref: the reference ships ResNet as a *model recipe* over fluid.layers
 (/root/reference/python/paddle/fluid/tests/unittests/dist_se_resnext.py and
 tests/book image_classification — conv_bn_layer + bottleneck patterns).
-BASELINE.md flagship: ResNet-50 ImageNet throughput.
+BASELINE.json flagship: ResNet-50 ImageNet throughput.
 
 TPU-first: NCHW inputs accepted but compute can run bf16 via amp.Policy;
 XLA's layout assignment handles the HWCN internals. BN state functional.

@@ -1,6 +1,6 @@
 """Transformer (encoder-decoder) for NMT — WMT en-de "big"/"base" configs.
 
-Ref: BASELINE.md "Transformer big WMT en-de (Fluid
+Ref: BASELINE.json "Transformer big WMT en-de (Fluid
 neural_machine_translation)" and the reference's transformer test fixture
 (/root/reference/python/paddle/fluid/tests/unittests/dist_transformer.py —
 the Fluid-era layers implementation). Rebuilt with first-class attention ops

@@ -230,7 +230,7 @@ def _shard_specs(layout, vocab_axis, batch_axis):
 def _sharded_fwd(h, w, b, labels, layout, ls, chunk, vocab_axis,
                  batch_axis, mesh):
     from jax.sharding import PartitionSpec as P
-    from paddle_tpu.parallel.pipeline import shard_map
+    from jax import shard_map
     v = w.shape[0] if layout == "vh" else w.shape[1]
     need_sum = ls != 0.0
 
@@ -279,7 +279,7 @@ def _fxs_fwd(h, w, b, labels, layout, ls, chunk, vocab_axis, batch_axis,
 
 def _fxs_bwd(layout, ls, chunk, vocab_axis, batch_axis, mesh, res, g):
     from jax.sharding import PartitionSpec as P
-    from paddle_tpu.parallel.pipeline import shard_map
+    from jax import shard_map
     h, w, b, labels, logz = res
     v = w.shape[0] if layout == "vh" else w.shape[1]
     sn, sp = _smooth_consts(v, ls)

@@ -271,11 +271,10 @@ def pool2d(x, pool_size=2, pool_type="max", stride=None, padding=0,
 
     Max pooling's backward is XLA's native SelectAndScatter. An
     argmax scatter-add alternative (flag `maxpool_custom_vjp`) was
-    built in r3 and REMOVED after silicon measurement (2026-07-31):
-    duplicate-index scatters serialize on TPU — 327 ms/step vs
-    48 ms on the ResNet-50 bench — while the native lowering already
-    runs near the HBM roofline (874 us for the stem maxpool-grad,
-    ~530 GB/s). See BASELINE.md "Second silicon window"."""
+    built in r3 and REMOVED: duplicate-index scatters serialize on TPU
+    (the builders' 2026-07-31 account, never reproduced by the driver:
+    several times the step time of the native lowering on the
+    ResNet-50 bench)."""
     if global_pooling:
         axes = (2, 3) if data_format == "NCHW" else (1, 2)
         if pool_type == "max":

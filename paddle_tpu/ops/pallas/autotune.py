@@ -39,9 +39,6 @@ logger = logging.getLogger("paddle_tpu.autotune")
 _CACHE = None       # process-wide cache, keyed to the flag's path
 _TIMER = None       # injectable timer (tests: set_timer(fake))
 
-_TPU_KINDS = ("v6e", "v5p", "v5e", "v4")
-
-
 def signature(**dims):
     """Stable shape-signature string: ``signature(b=2, tq=128)`` ->
     ``"b2,tq128"``. Keys sort, so call sites need not agree on order."""
@@ -49,20 +46,14 @@ def signature(**dims):
 
 
 def chip_key(devices=None):
-    """The chip family the current backend runs on — same normalization
-    as autoplan/topology.detect(), so cache entries and topology presets
-    agree on what a "chip" is."""
-    try:
-        import jax
-        d = (list(devices) if devices is not None else jax.devices())[0]
-        kind = (str(getattr(d, "device_kind", "")) or d.platform
-                or "cpu").lower()
-    except Exception:
-        return "cpu"
-    for k in _TPU_KINDS:
-        if k in kind:
-            return k
-    return "tpu" if "tpu" in kind else "cpu"
+    """The chip the current backend runs on (``"v5e"``, ``"cpu"``, ...)
+    — autoplan/topology.chip_name's answer, the same one its detect()
+    keys on, so cache entries and topology presets agree on what a
+    "chip" is."""
+    import jax
+    from paddle_tpu.parallel.autoplan.topology import chip_name
+    return chip_name((list(devices) if devices is not None
+                      else jax.devices())[0])
 
 
 def cache_key(kernel, sig):

@@ -22,11 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
-
 from paddle_tpu.ops.pallas import log_fallback, on_tpu
 
 NEG_INF = -1e30
@@ -49,10 +44,10 @@ def kernel_mode(kernel, *, enable_flag=None, unsupported=None,
       * ``enable_flag`` False -> None, silently (the flag is the
         documented escape hatch; flipping it off is a request, not a
         refusal worth a warning).
-      * off-TPU without ``pallas_interpret``, or no pltpu backend ->
-        None. Silent by default (plain CPU runs are not an anomaly);
-        ``log_unavailable=True`` emits ``unavailable_reason`` the way
-        the xent kernels always have.
+      * off-TPU without ``pallas_interpret`` -> None. Silent by default
+        (plain CPU runs are not an anomaly); ``log_unavailable=True``
+        emits ``unavailable_reason`` the way the xent kernels always
+        have.
       * ``unsupported`` (a reason string naming requested vs supported
         configuration, or None when the shapes qualify) -> None with a
         `log_fallback` — a silent drop under GSPMD is invisible, so
@@ -65,7 +60,7 @@ def kernel_mode(kernel, *, enable_flag=None, unsupported=None,
     if enable_flag is not None and not get_flag(enable_flag):
         return None
     interpret = get_flag("pallas_interpret")
-    if (not (on_tpu() or interpret)) or pltpu is None:
+    if not (on_tpu() or interpret):
         if log_unavailable and unavailable_reason:
             log_fallback(kernel, unavailable_reason, level)
         return None
@@ -82,10 +77,11 @@ def kernel_call(kernel_fn, *, name, grid=None, grid_spec=None,
     ``raw-pallas-call`` rule rejects any other). Accepts either a plain
     ``grid`` + in/out specs or a prebuilt ``grid_spec`` (e.g. the
     scalar-prefetch spec of the paged decode kernel, which carries its
-    own scratch shapes). ``name`` identifies the kernel to the autotuner
-    and in debugging; it is not forwarded to Pallas."""
-    del name
-    kwargs = {}
+    own scratch shapes). ``name`` is the kernel's stable identity: it
+    names the Mosaic kernel, so the compiled HLO's ``tpu_custom_call``
+    and the device trace's events carry it (what chip_smoke.py's kernel
+    evidence and a trace reduction key on)."""
+    kwargs = {"name": name}
     if grid_spec is not None:
         kwargs["grid_spec"] = grid_spec
     else:
@@ -96,6 +92,54 @@ def kernel_call(kernel_fn, *, name, grid=None, grid_spec=None,
         kwargs["scratch_shapes"] = scratch_shapes
     return pl.pallas_call(kernel_fn, out_shape=out_shape,
                           interpret=interpret, **kwargs)
+
+
+def partitioned(fn, in_dims, out_dims):
+    """Run a kernel per shard when the step is partitioned over a mesh.
+
+    A Mosaic kernel is opaque to GSPMD: the chip's compiler refuses a
+    jitted step over a mesh that reaches a bare ``pallas_call``
+    ("Mosaic kernels cannot be automatically partitioned. Please wrap
+    the call in a shard_map"). So under an enclosing ``with mesh:`` of
+    more than one device, ``fn`` — arrays in, arrays out, every shape
+    read off its arguments — is wrapped in that shard_map:
+    ``in_dims``/``out_dims`` give, per argument and per result, the
+    batch-like dim (row tiles, batch) or None. That dim is split over
+    the mesh's data axis (``parallel.mesh.DP``, the axis ``shard_batch``
+    and the Trainer stage batches over) where it divides; every other
+    axis computes its block redundantly, since no kernel here splits
+    its contraction dims. No kernel reduces across the dim it lets be
+    split, so the shards need no collective.
+
+    With no mesh, one device, or inside a shard_map that is already
+    manual over every axis, ``fn`` is returned as it is."""
+    from jax.sharding import PartitionSpec as P
+
+    from paddle_tpu.parallel.mesh import DP, current_mesh
+    mesh = current_mesh()
+    if mesh is None or mesh.size == 1:
+        return fn
+    auto = frozenset(mesh.axis_names) - frozenset(
+        jax.sharding.get_abstract_mesh().manual_axes)
+    if not auto:
+        return fn
+    n = mesh.shape[DP] if DP in auto else 1
+
+    def per_shard(*args):
+        split = n > 1 and all(a.shape[d] % n == 0
+                              for a, d in zip(args, in_dims)
+                              if d is not None)
+
+        def spec(d):
+            return P(*([None] * d), DP) if split and d is not None else P()
+
+        out_specs = (tuple(spec(d) for d in out_dims)
+                     if isinstance(out_dims, tuple) else spec(out_dims))
+        return jax.shard_map(
+            fn, mesh=mesh, in_specs=tuple(spec(d) for d in in_dims),
+            out_specs=out_specs, axis_names=auto, check_vma=False)(*args)
+
+    return per_shard
 
 
 # --------------------------------------------------- BlockSpec/grid builders
@@ -138,13 +182,59 @@ def pick_block_rows(rows, cols, dtype_bytes, vmem_budget=2 ** 21, copies=2,
     return max(min(vmem_budget // per_row, rows, cap), floor)
 
 
-def pick_rv_blocks(n, v, h, dtype_bytes, vmem_budget=2 ** 22):
-    """(row tile, vocab tile) for the rows x vocab kernels: h-tile +
-    w-tile + f32 logits tile within ~4MB."""
-    bv = max(min(v, 1024), 128)
-    per_row = h * dtype_bytes + bv * 4          # hidden row + logits row
-    bn = max(min(vmem_budget // max(per_row, 1), n, 512), 8)
-    return bn, bv
+#: Mosaic's default scoped-VMEM limit on the v5e, and what a rows x vocab
+#: kernel may plan to use of it. Compiled inside the whole train step the
+#: same kernel was allocated up to 3 MB more than compiled alone (XLA's
+#: memory-space assignment moves operands around the call: dW/db at
+#: 512 x 1024 tiles took 13.22 MB alone and 16.21 MB in the GPT-small
+#: step), so the plan stops 4 MB short of the limit.
+VMEM_LIMIT_BYTES = 16 * 2 ** 20
+RV_VMEM_BUDGET = VMEM_LIMIT_BYTES - 4 * 2 ** 20
+
+
+def rv_vmem_bytes(bn, bv, h, dtype_bytes, row_blocks, out):
+    """Scoped VMEM one grid step of a rows x vocab kernel plans for — an
+    upper bound on what the v5e compiler allocated at every tile shape
+    probed (tests/test_mosaic_compile.py compiles the real shapes):
+
+      * the pipeline's double buffers: the [bn, h] hidden and [bv, h]
+        weight tiles in the input dtype; ``row_blocks`` [bn, 1] row
+        operands/outputs, each row padded to a full 128-lane f32 tile;
+        and the f32 block the kernel accumulates — ``out`` "rows" is
+        [bn, h] (dh), "vocab" is [bv, h] (dW, the big one), None is
+        nothing beyond the row blocks (forward stats);
+      * the working set: logits, probabilities and their masks, two and
+        a half f32 [bn, bv] tiles (the bf16->f32 upcasts of the operand
+        tiles stream through registers and were not seen allocated).
+    """
+    out_elems = {"rows": bn * h, "vocab": bv * h, None: 0}[out]
+    pipeline = 2 * (dtype_bytes * h * (bn + bv)
+                    + row_blocks * bn * 128 * 4
+                    + 4 * out_elems)
+    return pipeline + 10 * bn * bv
+
+
+def pick_rv_blocks(n, v, h, dtype_bytes, resident="rows", row_blocks=5,
+                   out=None, vmem_budget=RV_VMEM_BUDGET):
+    """(row tile, vocab tile) for a rows x vocab kernel: the largest
+    tiles whose :func:`rv_vmem_bytes` fits the budget. ``resident`` names
+    the axis whose block stays put while the grid sweeps the other one —
+    its tile is maximized first, because the streamed operand is re-read
+    once per resident block ("rows": stats and dh re-read the weight per
+    row tile; "vocab": dW/db re-reads the hidden rows per vocab tile)."""
+    # a block dim is legal when it tiles (x8 rows, x128 lanes) or covers
+    # the array; candidates past the array clamp to it
+    bns = sorted({min(c, max(n, 8)) for c in (512, 256, 128, 64, 32, 16,
+                                              8)}, reverse=True)
+    bvs = sorted({max(min(c, v), 128) for c in (1024, 512, 256, 128)},
+                 reverse=True)
+    pairs = ([(bn, bv) for bn in bns for bv in bvs] if resident == "rows"
+             else [(bn, bv) for bv in bvs for bn in bns])
+    for bn, bv in pairs:
+        if rv_vmem_bytes(bn, bv, h, dtype_bytes, row_blocks,
+                         out) <= vmem_budget:
+            return bn, bv
+    return bns[-1], bvs[-1]
 
 
 # ------------------------------------------------------- masking builders
@@ -203,18 +293,6 @@ def tail_valid_cols(idx, block, total, shape, axis=1):
     return pos < total
 
 
-# ------------------------------------------------- quantized-tile primitive
-
-def dequant_rows(x, row_scales):
-    """Dequantize a loaded [H, R, D] int8 value tile against its per-row
-    symmetric scales ([R], one scale per token row shared across heads
-    and head_dim — the paged-KV layout of ops/attention.py). Lives here
-    rather than in the decode kernel because it is the tiled-primitive
-    counterpart of quantize_kv_rows: any future int8 kernel (prefill
-    chunk, flash over quantized caches) reuses the same contract."""
-    return x.astype(jnp.float32) * row_scales[None, :, None]
-
-
 # ------------------------------------------- online-softmax (m, l) combiner
 
 def softmax_init(m_scr, l_scr, *acc_scrs):
@@ -227,7 +305,9 @@ def softmax_init(m_scr, l_scr, *acc_scrs):
 
 
 def softmax_update(s, m_scr, l_scr, valid=None):
-    """One online-softmax step over a [R, C] score tile: rescale the
+    """One online-softmax step over a [R, C] score tile (or any
+    [..., R, C] stack of them — the reduction is over the last axis, the
+    carry keeps a size-1 last dim): rescale the
     running (m, l) carry and return ``(p, alpha)`` — the tile's masked
     probabilities and the accumulator rescale factor — so the caller
     applies ``acc <- acc * alpha + p @ v`` with whatever contraction its
@@ -239,12 +319,12 @@ def softmax_update(s, m_scr, l_scr, valid=None):
     if valid is not None:
         s = jnp.where(valid, s, NEG_INF)
     m_prev = m_scr[:]                            # [R, 1]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     p = jnp.exp(s - m_new)                       # [R, C]
     if valid is not None:
         p = jnp.where(valid, p, 0.0)
     alpha = jnp.exp(m_prev - m_new)              # [R, 1]
-    l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=1, keepdims=True)
+    l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
     m_scr[:] = m_new
     return p, alpha
 

@@ -17,15 +17,20 @@ the one written this step). Grid (S, H/block_h, Pmax) with the page axis
 innermost (sequential on TPU) carrying the softmax state; the head axis
 is the autotuned tile knob (``block_h``, default all heads). fp32
 statistics and accumulation regardless of the pool dtype (bf16 pools
-re-read through f32 math — same contract as flash_attention).
+re-read through f32 math — same contract as flash_attention). Inside
+the kernel the query keeps a size-1 row dim ([BH, 1, hd]) so both
+products are head-batched matmuls with a real non-contracting lhs dim.
 
 Int8 pools ride the same (m, l, acc) pipeline: the per-row scales
-([N, page_size] beside the pool) come in as two extra gathered blocks
-and ``core.dequant_rows`` folds them into the loaded K/V tiles before
-the score matmul — dequant is a tile-level extension of the existing
-pipeline, not a separate kernel (the TPP argument). The quantized
-variant registers under its own autotune shape-sig (``kv=int8``), so
-sweeps and measured rates feed the cost model per dtype.
+([N, page_size] beside the pool) come in as two extra gathered blocks —
+the aligned group of ``SCALE_ROWS`` pages that holds the live one — and
+fold in after the contractions (K's scale onto the score column, V's
+onto the probability column; a scale is per token row, shared over heads
+and head_dim, so this is the same product as dequantizing the tiles).
+Dequant is a tile-level extension of the existing pipeline, not a
+separate kernel (the TPP argument). The quantized variant registers
+under its own autotune shape-sig (``kv=int8``), so sweeps and measured
+rates feed the cost model per dtype.
 
 Every page_table entry must be an IN-RANGE page index (0 for unallocated
 slots/pages is fine — the kernel skips blocks past `length`, but the
@@ -39,10 +44,16 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from paddle_tpu.ops.pallas.core import (NEG_INF, dequant_rows, kernel_call,
-                                        pltpu, softmax_finalize,
+from paddle_tpu.ops.pallas.core import (kernel_call, softmax_finalize,
                                         softmax_init, softmax_update)
+
+#: rows of the [N, page_size] scale arrays one gathered block carries. A
+#: (1, page_size) block is illegal on the chip (second-to-last block dim
+#: must be a multiple of 8 or the array's), so the kernel fetches the
+#: aligned group of 8 pages holding the live one and picks its row.
+SCALE_ROWS = 8
 
 
 def _decode_kernel(ptab_ref, lens_ref, q_ref, k_ref, v_ref, *refs,
@@ -64,24 +75,32 @@ def _decode_kernel(ptab_ref, lens_ref, q_ref, k_ref, v_ref, *refs,
 
     @pl.when(j * page_size < length)
     def _step():
-        q = q_ref[0].astype(jnp.float32)               # [BH, hd]
-        if quantized:
-            k = dequant_rows(k_ref[0], ks_ref[0])      # [BH, ps, hd]
-            v = dequant_rows(v_ref[0], vs_ref[0])
-        else:
-            k = k_ref[0].astype(jnp.float32)           # [BH, ps, hd]
-            v = v_ref[0].astype(jnp.float32)
+        # the one query row keeps a size-1 row dim: [BH, 1, hd] against
+        # [BH, ps, hd] is a head-batched matmul Mosaic lowers, where the
+        # row-less [BH, hd] form has no non-contracting lhs dim
+        q = q_ref[0].astype(jnp.float32)               # [BH, 1, hd]
+        k = k_ref[0].astype(jnp.float32)               # [BH, ps, hd]
+        v = v_ref[0].astype(jnp.float32)
         sc = jax.lax.dot_general(
-            q, k, (((1,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * scale  # [BH, ps]
+            q, k, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32) * scale  # [BH, 1, ps]
+        if quantized:
+            # per-row scales fold in AFTER the contraction (one scale
+            # per token row, shared over heads and head_dim): the score
+            # column and the probability column carry them, so the
+            # [BH, ps, hd] tiles are never rescaled elementwise
+            row = ptab_ref[s, j] % SCALE_ROWS
+            sc = sc * ks_ref[pl.ds(row, 1), :][None]   # [1, 1, ps]
         pos = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, page_size), 1)
-        valid = pos < length                 # [1, ps] broadcasts over heads
+            jnp.int32, (1, 1, page_size), 2)
+        valid = pos < length                 # broadcasts over heads
         p, alpha = softmax_update(sc, m_scr, l_scr,
                                   jnp.broadcast_to(valid, sc.shape))
+        if quantized:
+            p = p * vs_ref[pl.ds(row, 1), :][None]
         acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)         # [BH, hd]
+            p, v, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)         # [BH, 1, hd]
 
     @pl.when(j == nj - 1)
     def _finalize():
@@ -135,39 +154,36 @@ def paged_decode_attention_tpu(q, k_pages, v_pages, page_table, lengths,
     bh = block_h if h % block_h == 0 else h
     kernel = functools.partial(_decode_kernel, scale=scale,
                                page_size=page_size, quantized=quantized)
-    in_specs = [
-        pl.BlockSpec((1, bh, hd), lambda s, b, j, pt, ln: (s, b, 0)),
-        pl.BlockSpec((1, bh, page_size, hd),
-                     lambda s, b, j, pt, ln: (pt[s, j], b, 0, 0)),
-        pl.BlockSpec((1, bh, page_size, hd),
-                     lambda s, b, j, pt, ln: (pt[s, j], b, 0, 0)),
-    ]
-    operands = [q, k_pages, v_pages]
+    # q/out carry an explicit size-1 row dim ([S, H, 1, hd]); see _step
+    q_spec = pl.BlockSpec((1, bh, 1, hd),
+                          lambda s, b, j, pt, ln: (s, b, 0, 0))
+    page_spec = pl.BlockSpec((1, bh, page_size, hd),
+                             lambda s, b, j, pt, ln: (pt[s, j], b, 0, 0))
+    in_specs = [q_spec, page_spec, page_spec]
+    operands = [q[:, :, None, :], k_pages, v_pages]
     if quantized:
-        in_specs += [
-            pl.BlockSpec((1, page_size),
-                         lambda s, b, j, pt, ln: (pt[s, j], 0)),
-            pl.BlockSpec((1, page_size),
-                         lambda s, b, j, pt, ln: (pt[s, j], 0)),
-        ]
+        scale_spec = pl.BlockSpec(
+            (SCALE_ROWS, page_size),
+            lambda s, b, j, pt, ln: (pt[s, j] // SCALE_ROWS, 0))
+        in_specs += [scale_spec, scale_spec]
         operands += [k_scale, v_scale]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(s_slots, h // bh, p_max),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, bh, hd),
-                               lambda s, b, j, pt, ln: (s, b, 0)),
+        out_specs=q_spec,
         scratch_shapes=[
-            pltpu.VMEM((bh, 1), jnp.float32),
-            pltpu.VMEM((bh, 1), jnp.float32),
-            pltpu.VMEM((bh, hd), jnp.float32),
+            pltpu.VMEM((bh, 1, 1), jnp.float32),
+            pltpu.VMEM((bh, 1, 1), jnp.float32),
+            pltpu.VMEM((bh, 1, hd), jnp.float32),
         ],
     )
-    return kernel_call(
+    out = kernel_call(
         kernel,
         name="decode_attention",
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s_slots, h, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((s_slots, h, 1, hd), q.dtype),
         interpret=interpret,
     )(page_table.astype(jnp.int32), lengths.astype(jnp.int32),
       *operands)
+    return out[:, :, 0, :]

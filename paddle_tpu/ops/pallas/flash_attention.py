@@ -44,13 +44,14 @@ import logging
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from paddle_tpu.ops.pallas import describe_sharding, log_fallback
 from paddle_tpu.ops.pallas.core import (NEG_INF, block_valid, kernel_call,
                                         kernel_mode, legal_block,
-                                        softmax_finalize, softmax_init,
-                                        softmax_update, tail_zero,
-                                        tail_zero_row, tile_spec)
+                                        partitioned, softmax_finalize,
+                                        softmax_init, softmax_update,
+                                        tail_zero, tail_zero_row, tile_spec)
 
 logger = logging.getLogger("paddle_tpu.flash")
 
@@ -114,55 +115,57 @@ def _flash_attention_fwd_tpu(q, k, v, scale, causal, block_q, block_k,
     if interpret is None:
         from paddle_tpu.core.flags import get_flag
         interpret = get_flag("pallas_interpret")
-    from paddle_tpu.ops.pallas.core import pltpu
-    b, h, tq, d = q.shape
-    tk = k.shape[2]
-    bh = b * h
-    q3 = q.reshape(bh, tq, d)
-    k3 = k.reshape(bh, tk, d)
-    v3 = v.reshape(bh, tk, d)
-    block_q = legal_block(block_q, tq, interpret)
-    block_k = legal_block(block_k, tk, interpret)
-    grid = (bh, pl.cdiv(tq, block_q), pl.cdiv(tk, block_k))
     has_mask = kv_mask is not None
-    kernel = functools.partial(_fa_kernel, scale=scale, causal=causal,
-                               block_q=block_q, block_k=block_k,
-                               causal_offset=tk - tq, tq=tq, tk=tk,
-                               has_mask=has_mask)
-    in_specs = [
-        tile_spec((1, block_q, d), (0, 1, None)),
-        tile_spec((1, block_k, d), (0, 2, None)),
-        tile_spec((1, block_k, d), (0, 2, None)),
-    ]
-    operands = [q3, k3, v3]
-    if has_mask:
-        in_specs.append(pl.BlockSpec(
-            (1, 1, block_k), lambda bhi, qi, ki: (bhi // h, 0, ki)))
-        operands.append(kv_mask.astype(jnp.int32).reshape(b, 1, tk))
-    out, lse = kernel_call(
-        kernel,
-        name="flash_attention",
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=[
-            tile_spec((1, block_q, d), (0, 1, None)),
-            tile_spec((1, 1, block_q), (0, None, 1)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, 1, tq), jnp.float32),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
-        ],
-        interpret=interpret,
-    )(*operands)
-    out = out.reshape(b, h, tq, d)
-    if return_lse:
-        return out, lse.reshape(b, h, tq)
-    return out
+
+    def call(q, k, v, *mask):
+        # batch and heads are read off the arguments: under a mesh this
+        # runs per shard, on the shard's [b, h]
+        b, h, tq, d = q.shape
+        tk = k.shape[2]
+        bh = b * h
+        bq = legal_block(block_q, tq, interpret)
+        bk = legal_block(block_k, tk, interpret)
+        kernel = functools.partial(_fa_kernel, scale=scale, causal=causal,
+                                   block_q=bq, block_k=bk,
+                                   causal_offset=tk - tq, tq=tq, tk=tk,
+                                   has_mask=has_mask)
+        in_specs = [
+            tile_spec((1, bq, d), (0, 1, None)),
+            tile_spec((1, bk, d), (0, 2, None)),
+            tile_spec((1, bk, d), (0, 2, None)),
+        ]
+        operands = [q.reshape(bh, tq, d), k.reshape(bh, tk, d),
+                    v.reshape(bh, tk, d)]
+        if has_mask:
+            in_specs.append(pl.BlockSpec(
+                (1, 1, bk), lambda bhi, qi, ki: (bhi // h, 0, ki)))
+            operands.append(mask[0].astype(jnp.int32).reshape(b, 1, tk))
+        out, lse = kernel_call(
+            kernel,
+            name="flash_attention",
+            grid=(bh, pl.cdiv(tq, bq), pl.cdiv(tk, bk)),
+            in_specs=in_specs,
+            out_specs=[
+                tile_spec((1, bq, d), (0, 1, None)),
+                tile_spec((1, 1, bq), (0, None, 1)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
+                jax.ShapeDtypeStruct((bh, 1, tq), jnp.float32),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((bq, 1), jnp.float32),
+                pltpu.VMEM((bq, 1), jnp.float32),
+                pltpu.VMEM((bq, d), jnp.float32),
+            ],
+            interpret=interpret,
+        )(*operands)
+        return out.reshape(b, h, tq, d), lse.reshape(b, h, tq)
+
+    mask = (kv_mask,) if has_mask else ()
+    out, lse = partitioned(call, (0,) * (3 + len(mask)), (0, 0))(
+        q, k, v, *mask)
+    return (out, lse) if return_lse else out
 
 
 def _bwd_p(s, lse_row, valid):
@@ -291,89 +294,94 @@ def _flash_attention_bwd_tpu(q, k, v, out, lse, do, scale, causal,
     if interpret is None:
         from paddle_tpu.core.flags import get_flag
         interpret = get_flag("pallas_interpret")
-    from paddle_tpu.ops.pallas.core import pltpu
-    b, h, tq, d = q.shape
-    tk = k.shape[2]
-    bh = b * h
-    # delta_i = rowsum(dO_i * O_i) — cheap elementwise, XLA fuses it
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1)                         # [B, H, Tq]
-    q3 = q.reshape(bh, tq, d)
-    k3 = k.reshape(bh, tk, d)
-    v3 = v.reshape(bh, tk, d)
-    do3 = do.reshape(bh, tq, d)
-    lse2 = lse.reshape(bh, 1, tq)
-    dlt2 = delta.reshape(bh, 1, tq)
-    block_q = legal_block(block_q, tq, interpret)
-    block_k = legal_block(block_k, tk, interpret)
-    nq = pl.cdiv(tq, block_q)
-    nk = pl.cdiv(tk, block_k)
-    offset = tk - tq
     has_mask = kv_mask is not None
-    mask_i32 = (kv_mask.astype(jnp.int32).reshape(b, 1, tk)
-                if has_mask else None)
-    common = dict(scale=scale, causal=causal, block_q=block_q,
-                  block_k=block_k, causal_offset=offset, tq=tq, tk=tk,
-                  has_mask=has_mask)
-    # dq grid (bh, nq, nk): grid axis 1 picks q blocks, axis 2 kv blocks
-    q_specs = [
-        tile_spec((1, block_q, d), (0, 1, None)),
-        tile_spec((1, block_k, d), (0, 2, None)),
-        tile_spec((1, block_k, d), (0, 2, None)),
-        tile_spec((1, block_q, d), (0, 1, None)),
-        tile_spec((1, 1, block_q), (0, None, 1)),
-        tile_spec((1, 1, block_q), (0, None, 1)),
-    ]
-    q_ops = [q3, k3, v3, do3, lse2, dlt2]
-    if has_mask:
-        q_specs.append(pl.BlockSpec(
-            (1, 1, block_k), lambda bhi, qi, ki: (bhi // h, 0, ki)))
-        q_ops.append(mask_i32)
-    dq = kernel_call(
-        functools.partial(_fa_bwd_dq_kernel, **common),
-        name="flash_attention_bwd_dq",
-        grid=(bh, nq, nk),
-        in_specs=q_specs,
-        out_specs=tile_spec((1, block_q, d), (0, 1, None)),
-        out_shape=jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        interpret=interpret,
-    )(*q_ops)
-    # dkv grid (bh, nk, nq): grid axis 1 picks kv blocks, axis 2 q blocks
-    kv_specs = [
-        tile_spec((1, block_q, d), (0, 2, None)),
-        tile_spec((1, block_k, d), (0, 1, None)),
-        tile_spec((1, block_k, d), (0, 1, None)),
-        tile_spec((1, block_q, d), (0, 2, None)),
-        tile_spec((1, 1, block_q), (0, None, 2)),
-        tile_spec((1, 1, block_q), (0, None, 2)),
-    ]
-    kv_ops = [q3, k3, v3, do3, lse2, dlt2]
-    if has_mask:
-        kv_specs.append(pl.BlockSpec(
-            (1, 1, block_k), lambda bhi, ki, qi: (bhi // h, 0, ki)))
-        kv_ops.append(mask_i32)
-    dk, dv = kernel_call(
-        functools.partial(_fa_bwd_dkv_kernel, **common),
-        name="flash_attention_bwd_dkv",
-        grid=(bh, nk, nq),
-        in_specs=kv_specs,
-        out_specs=[
-            tile_spec((1, block_k, d), (0, 1, None)),
-            tile_spec((1, block_k, d), (0, 1, None)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bh, tk, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, tk, d), v.dtype),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
-        ],
-        interpret=interpret,
-    )(*kv_ops)
-    return (dq.reshape(b, h, tq, d), dk.reshape(b, h, tk, d),
-            dv.reshape(b, h, tk, d))
+
+    def call(q, k, v, out, lse, do, *mask):
+        b, h, tq, d = q.shape
+        tk = k.shape[2]
+        bh = b * h
+        # delta_i = rowsum(dO_i * O_i) — cheap elementwise, XLA fuses it
+        delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
+                        axis=-1)                         # [B, H, Tq]
+        q3 = q.reshape(bh, tq, d)
+        k3 = k.reshape(bh, tk, d)
+        v3 = v.reshape(bh, tk, d)
+        do3 = do.reshape(bh, tq, d)
+        lse2 = lse.reshape(bh, 1, tq)
+        dlt2 = delta.reshape(bh, 1, tq)
+        bq = legal_block(block_q, tq, interpret)
+        bk = legal_block(block_k, tk, interpret)
+        nq = pl.cdiv(tq, bq)
+        nk = pl.cdiv(tk, bk)
+        offset = tk - tq
+        mask_i32 = (mask[0].astype(jnp.int32).reshape(b, 1, tk)
+                    if has_mask else None)
+        common = dict(scale=scale, causal=causal, block_q=bq,
+                      block_k=bk, causal_offset=offset, tq=tq, tk=tk,
+                      has_mask=has_mask)
+        # dq grid (bh, nq, nk): axis 1 picks q blocks, axis 2 kv blocks
+        q_specs = [
+            tile_spec((1, bq, d), (0, 1, None)),
+            tile_spec((1, bk, d), (0, 2, None)),
+            tile_spec((1, bk, d), (0, 2, None)),
+            tile_spec((1, bq, d), (0, 1, None)),
+            tile_spec((1, 1, bq), (0, None, 1)),
+            tile_spec((1, 1, bq), (0, None, 1)),
+        ]
+        q_ops = [q3, k3, v3, do3, lse2, dlt2]
+        if has_mask:
+            q_specs.append(pl.BlockSpec(
+                (1, 1, bk), lambda bhi, qi, ki: (bhi // h, 0, ki)))
+            q_ops.append(mask_i32)
+        dq = kernel_call(
+            functools.partial(_fa_bwd_dq_kernel, **common),
+            name="flash_attention_bwd_dq",
+            grid=(bh, nq, nk),
+            in_specs=q_specs,
+            out_specs=tile_spec((1, bq, d), (0, 1, None)),
+            out_shape=jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
+            scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+            interpret=interpret,
+        )(*q_ops)
+        # dkv grid (bh, nk, nq): axis 1 picks kv blocks, axis 2 q blocks
+        kv_specs = [
+            tile_spec((1, bq, d), (0, 2, None)),
+            tile_spec((1, bk, d), (0, 1, None)),
+            tile_spec((1, bk, d), (0, 1, None)),
+            tile_spec((1, bq, d), (0, 2, None)),
+            tile_spec((1, 1, bq), (0, None, 2)),
+            tile_spec((1, 1, bq), (0, None, 2)),
+        ]
+        kv_ops = [q3, k3, v3, do3, lse2, dlt2]
+        if has_mask:
+            kv_specs.append(pl.BlockSpec(
+                (1, 1, bk), lambda bhi, ki, qi: (bhi // h, 0, ki)))
+            kv_ops.append(mask_i32)
+        dk, dv = kernel_call(
+            functools.partial(_fa_bwd_dkv_kernel, **common),
+            name="flash_attention_bwd_dkv",
+            grid=(bh, nk, nq),
+            in_specs=kv_specs,
+            out_specs=[
+                tile_spec((1, bk, d), (0, 1, None)),
+                tile_spec((1, bk, d), (0, 1, None)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((bh, tk, d), k.dtype),
+                jax.ShapeDtypeStruct((bh, tk, d), v.dtype),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((bk, d), jnp.float32),
+                pltpu.VMEM((bk, d), jnp.float32),
+            ],
+            interpret=interpret,
+        )(*kv_ops)
+        return (dq.reshape(b, h, tq, d), dk.reshape(b, h, tk, d),
+                dv.reshape(b, h, tk, d))
+
+    mask = (kv_mask,) if has_mask else ()
+    return partitioned(call, (0,) * (6 + len(mask)), (0, 0, 0))(
+        q, k, v, out, lse, do, *mask)
 
 
 def chunked_attention(q, k, v, scale=None, causal=False, kv_mask=None,
@@ -521,8 +529,8 @@ def flash_attention(q, k, v, scale=None, causal=False, kv_mask=None,
     Elsewhere: chunked XLA formulation (same math, same semantics).
     """
     from paddle_tpu.core.flags import get_flag
-    # default block sizes come from flags so a flash_tune.py sweep result
-    # applies fleet-wide via PT_FLAGS_flash_block_{q,k} (no code change)
+    # default block sizes come from flags so a tools/autotune.py sweep
+    # result applies fleet-wide via PT_FLAGS_flash_block_{q,k}
     block_q = block_q if block_q is not None else get_flag("flash_block_q")
     block_k = block_k if block_k is not None else get_flag("flash_block_k")
     scale = float(scale) if scale is not None else 1.0 / (q.shape[-1] ** 0.5)

@@ -24,7 +24,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from paddle_tpu.ops.pallas.core import (INTERPRET, kernel_call, kernel_mode,
-                                        pick_block_rows, tile_spec)
+                                        partitioned, pick_block_rows,
+                                        tile_spec)
 
 
 def _ln_fwd_kernel(x_ref, g_ref, b_ref, o_ref, m_ref, r_ref, *, epsilon):
@@ -67,30 +68,38 @@ def _stats_pallas(x2d, gamma, beta, epsilon, interpret=False,
             "layer_norm", x2d,
             lambda block_rows: _stats_pallas(x2d, gamma, beta, epsilon,
                                              interpret, block_rows))
-    br = block_rows
     kern = functools.partial(_ln_fwd_kernel, epsilon=epsilon)
-    grid = (pl.cdiv(R, br),)
-    return kernel_call(
-        kern,
-        name="layer_norm",
-        grid=grid,
-        in_specs=[
-            tile_spec((br, C), (0, None)),
-            tile_spec((C,), (None,)),
-            tile_spec((C,), (None,)),
-        ],
-        out_specs=[
-            tile_spec((br, C), (0, None)),
-            tile_spec((br, 1), (0, None)),
-            tile_spec((br, 1), (0, None)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((R, C), x2d.dtype),
+
+    def call(x2d, gamma, beta):
+        R = x2d.shape[0]                 # the shard's rows under a mesh
+        br = min(block_rows, R)
+        return kernel_call(
+            kern,
+            name="layer_norm",
+            grid=(pl.cdiv(R, br),),
+            in_specs=[
+                tile_spec((br, C), (0, None)),
+                tile_spec((C,), (None,)),
+                tile_spec((C,), (None,)),
+            ],
+            out_specs=_row_out_specs(br, C),
+            out_shape=_row_out_shapes(R, C, x2d.dtype),
+            interpret=interpret,
+        )(x2d, gamma, beta)
+
+    return partitioned(call, (0, None, None), (0, 0, 0))(x2d, gamma, beta)
+
+
+def _row_out_specs(br, C):
+    return [tile_spec((br, C), (0, None)), tile_spec((br, 1), (0, None)),
+            tile_spec((br, 1), (0, None))]
+
+
+def _row_out_shapes(R, C, dtype):
+    """(normalized rows, mean, rstd) — what both LN kernels emit."""
+    return [jax.ShapeDtypeStruct((R, C), dtype),
             jax.ShapeDtypeStruct((R, 1), jnp.float32),
-            jax.ShapeDtypeStruct((R, 1), jnp.float32),
-        ],
-        interpret=interpret,
-    )(x2d, gamma, beta)
+            jax.ShapeDtypeStruct((R, 1), jnp.float32)]
 
 
 def _stats_xla(x2d, gamma, beta, epsilon):
@@ -196,30 +205,28 @@ def _stats_add_pallas(x2d, h2d, gamma, beta, epsilon, interpret=False,
             lambda block_rows: _stats_add_pallas(x2d, h2d, gamma, beta,
                                                  epsilon, interpret,
                                                  block_rows))
-    br = block_rows
     kern = functools.partial(_ln_add_fwd_kernel, epsilon=epsilon)
-    return kernel_call(
-        kern,
-        name="add_layer_norm",
-        grid=(pl.cdiv(R, br),),
-        in_specs=[
-            tile_spec((br, C), (0, None)),
-            tile_spec((br, C), (0, None)),
-            tile_spec((C,), (None,)),
-            tile_spec((C,), (None,)),
-        ],
-        out_specs=[
-            tile_spec((br, C), (0, None)),
-            tile_spec((br, 1), (0, None)),
-            tile_spec((br, 1), (0, None)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((R, C), x2d.dtype),
-            jax.ShapeDtypeStruct((R, 1), jnp.float32),
-            jax.ShapeDtypeStruct((R, 1), jnp.float32),
-        ],
-        interpret=interpret,
-    )(x2d, h2d, gamma, beta)
+
+    def call(x2d, h2d, gamma, beta):
+        R = x2d.shape[0]                 # the shard's rows under a mesh
+        br = min(block_rows, R)
+        return kernel_call(
+            kern,
+            name="add_layer_norm",
+            grid=(pl.cdiv(R, br),),
+            in_specs=[
+                tile_spec((br, C), (0, None)),
+                tile_spec((br, C), (0, None)),
+                tile_spec((C,), (None,)),
+                tile_spec((C,), (None,)),
+            ],
+            out_specs=_row_out_specs(br, C),
+            out_shape=_row_out_shapes(R, C, x2d.dtype),
+            interpret=interpret,
+        )(x2d, h2d, gamma, beta)
+
+    return partitioned(call, (0, 0, None, None), (0, 0, 0))(
+        x2d, h2d, gamma, beta)
 
 
 def _stats_add(x2d, h2d, gamma, beta, epsilon):

@@ -33,11 +33,12 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from paddle_tpu.ops.pallas.core import (INTERPRET, kernel_call, kernel_mode,
-                                        legal_block, pick_block_rows,
-                                        tail_valid_cols, tail_zero,
-                                        tile_spec)
+                                        legal_block, partitioned,
+                                        pick_block_rows, tail_valid_cols,
+                                        tail_zero, tile_spec)
 
 _ACTS = {
     # exact erf gelu — must match ops/activations.py A.gelu for parity
@@ -46,6 +47,40 @@ _ACTS = {
     "silu": jax.nn.silu,
     "identity": lambda x: x,
 }
+
+# erf(x) ~= x * P(x^2) / Q(x^2) on |x| <= 4 (saturated beyond): the
+# float32 rational fit XLA itself expands erf into, highest order first
+_ERF_P = (-2.72614225801306e-10, 2.77068142495902e-08,
+          -2.10102402082508e-06, -5.69250639462346e-05,
+          -7.34990630326855e-04, -2.95459980854025e-03,
+          -1.60960333262415e-02)
+_ERF_Q = (-1.45660718464996e-05, -2.13374055278905e-04,
+          -1.68282697438203e-03, -7.37332916720468e-03,
+          -1.42647390514189e-02)
+
+
+def _horner(x, coeffs):
+    acc = jnp.full_like(x, coeffs[0])
+    for c in coeffs[1:]:
+        acc = acc * x + c
+    return acc
+
+
+def _gelu_erf_kernel(x):
+    """Exact (erf-form) gelu for the kernel body. Mosaic lowers neither
+    ``erf`` nor the ``erfc`` jax.nn.gelu expands to, so erf is the
+    rational polynomial above — within float32 rounding of lax.erf, so
+    the kernel still matches `_ACTS["gelu"]` at the parity tolerance
+    (this is NOT the tanh approximation)."""
+    z = jnp.clip(x * 0.7071067811865476, -4.0, 4.0)
+    z2 = z * z
+    erf = z * _horner(z2, _ERF_P) / _horner(z2, _ERF_Q)
+    return 0.5 * x * (1.0 + erf)
+
+
+# what the kernel body evaluates: the same functions, except where Mosaic
+# has no lowering for the primitive the XLA form uses
+_KERNEL_ACTS = {**_ACTS, "gelu": _gelu_erf_kernel}
 
 
 def _mlp_kernel(x_ref, w1_ref, b1_ref, *rest, act, total_i, block_i,
@@ -65,13 +100,13 @@ def _mlp_kernel(x_ref, w1_ref, b1_ref, *rest, act, total_i, block_i,
     h = jax.lax.dot_general(
         x, w1_ref[:].astype(jnp.float32), (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)                # [BN, BI]
-    h = h + b1_ref[:].astype(jnp.float32)[None, :]
-    a = _ACTS[act](h)
+    h = h + b1_ref[:].astype(jnp.float32)               # [1, BI] bias row
+    a = _KERNEL_ACTS[act](h)
     if has_gate:
         g = jax.lax.dot_general(
             x, wg_ref[:].astype(jnp.float32), (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        a = a * (g + bg_ref[:].astype(jnp.float32)[None, :])
+        a = a * (g + bg_ref[:].astype(jnp.float32))
     w2 = w2_ref[:].astype(jnp.float32)                     # [BI, Hout]
     if total_i % block_i:
         # padded intermediate tail: clean BOTH matmul operands (select
@@ -84,45 +119,54 @@ def _mlp_kernel(x_ref, w1_ref, b1_ref, *rest, act, total_i, block_i,
 
     @pl.when(j == nj - 1)
     def _finalize():
-        o_ref[:] = (acc_scr[:]
-                    + b2_ref[:].astype(jnp.float32)[None, :]).astype(
-                        o_ref.dtype)
+        o_ref[:] = (acc_scr[:] + b2_ref[:].astype(jnp.float32)).astype(
+            o_ref.dtype)
 
 
 def _mlp_pallas(x2, w1, b1, w2, b2, wg, bg, act, interpret=False,
                 blocks=None):
-    from paddle_tpu.ops.pallas.core import pltpu
-    R, H = x2.shape
-    I, Hout = w1.shape[1], w2.shape[1]
     if blocks is None:
         blocks = _tuned_mlp_blocks(x2, w1, b1, w2, b2, wg, bg, act,
                                    interpret)
-    bn, bi = blocks
     has_gate = wg is not None
-    kern = functools.partial(_mlp_kernel, act=act, total_i=I, block_i=bi,
-                             has_gate=has_gate)
-    in_specs = [
-        tile_spec((bn, H), (0, None)),
-        tile_spec((H, bi), (None, 1)),
-        tile_spec((bi,), (1,)),
-    ]
-    operands = [x2, w1, b1]
-    if has_gate:
-        in_specs += [tile_spec((H, bi), (None, 1)), tile_spec((bi,), (1,))]
-        operands += [wg, bg]
-    in_specs += [tile_spec((bi, Hout), (1, None)), tile_spec((Hout,),
-                                                             (None,))]
-    operands += [w2, b2]
-    return kernel_call(
-        kern,
-        name="mlp",
-        grid=(pl.cdiv(R, bn), pl.cdiv(I, bi)),
-        in_specs=in_specs,
-        out_specs=tile_spec((bn, Hout), (0, None)),
-        out_shape=jax.ShapeDtypeStruct((R, Hout), x2.dtype),
-        scratch_shapes=[pltpu.VMEM((bn, Hout), jnp.float32)],
-        interpret=interpret,
-    )(*operands)
+
+    def call(x2, w1, b1, w2, b2, *gate):
+        R, H = x2.shape                  # the shard's rows under a mesh
+        I, Hout = w1.shape[1], w2.shape[1]
+        bn, bi = min(blocks[0], R), blocks[1]
+        kern = functools.partial(_mlp_kernel, act=act, total_i=I,
+                                 block_i=bi, has_gate=has_gate)
+        # biases ride as [1, n] rows: a 1-D block's Mosaic tiling need
+        # not match the layout XLA gives the 1-D operand (bf16[3072] in
+        # (512,) blocks was refused on the v5e); a (1, n) block is
+        # always legal
+        in_specs = [
+            tile_spec((bn, H), (0, None)),
+            tile_spec((H, bi), (None, 1)),
+            tile_spec((1, bi), (None, 1)),
+        ]
+        operands = [x2, w1, b1[None, :]]
+        if has_gate:
+            in_specs += [tile_spec((H, bi), (None, 1)),
+                         tile_spec((1, bi), (None, 1))]
+            operands += [gate[0], gate[1][None, :]]
+        in_specs += [tile_spec((bi, Hout), (1, None)),
+                     tile_spec((1, Hout), (None, None))]
+        operands += [w2, b2[None, :]]
+        return kernel_call(
+            kern,
+            name="mlp",
+            grid=(pl.cdiv(R, bn), pl.cdiv(I, bi)),
+            in_specs=in_specs,
+            out_specs=tile_spec((bn, Hout), (0, None)),
+            out_shape=jax.ShapeDtypeStruct((R, Hout), x2.dtype),
+            scratch_shapes=[pltpu.VMEM((bn, Hout), jnp.float32)],
+            interpret=interpret,
+        )(*operands)
+
+    weights = (w1, b1, w2, b2) + ((wg, bg) if has_gate else ())
+    return partitioned(call, (0,) + (None,) * len(weights), 0)(
+        x2, *weights)
 
 
 def _default_mlp_blocks(x2, w1, w2, interpret):

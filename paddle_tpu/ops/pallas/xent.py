@@ -62,7 +62,7 @@ def _xent_fwd_kernel(h_ref, w_ref, b_ref, lbl_ref, m_ref, s_ref, p_ref,
     logits = jax.lax.dot_general(
         h, w, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)                # [BN, BV]
-    logits = logits + b_ref[:].astype(jnp.float32)[None, :]
+    logits = logits + b_ref[:].astype(jnp.float32)          # [1, BV] row
     col = j * block_v + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
     valid = col < total_vocab                   # mask the padded tail tile
     masked = jnp.where(valid, logits, NEG_INF)
@@ -81,7 +81,10 @@ def _tuned_blocks(kernel, hidden, v, runner):
     """(bn, bv) from the shared VMEM heuristic, or — with the ``autotune``
     flag on — the cached/swept winner for this (shape, chip)."""
     n, h = hidden.shape
-    bn, bv = pick_rv_blocks(n, v, h, hidden.dtype.itemsize)
+    # forward stats: the label block plus four [bn, 1] outputs, no
+    # [*, h] accumulator
+    bn, bv = pick_rv_blocks(n, v, h, hidden.dtype.itemsize,
+                            resident="rows", row_blocks=5, out=None)
     from paddle_tpu.core.flags import get_flag
     if not get_flag("autotune"):
         return bn, bv
@@ -123,13 +126,13 @@ def xent_stats_pallas(hidden, weight, bias, labels, interpret=False,
         in_specs=[
             tile_spec((bn, H), (0, None)),
             tile_spec((bv, H), (1, None)),
-            tile_spec((bv,), (1,)),
+            tile_spec((1, bv), (None, 1)),
             tile_spec((bn, 1), (0, None)),
         ],
         out_specs=[row_out] * 4,
         out_shape=[jax.ShapeDtypeStruct((N, 1), jnp.float32)] * 4,
         interpret=interpret,
-    )(hidden, weight, bias, labels[:, None].astype(jnp.int32))
+    )(hidden, weight, bias[None, :], labels[:, None].astype(jnp.int32))
     if return_parts:
         return m[:, 0], s[:, 0], picked[:, 0], sl[:, 0]
     logz = m[:, 0] + jnp.log(s[:, 0])
@@ -164,7 +167,7 @@ def _bwd_gch(h, w_ref, b_ref, lbl_ref, logz_ref, g_ref, j, block_v,
     logits = jax.lax.dot_general(
         h, w_ref[:].astype(jnp.float32), (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)                # [BN, BV]
-    logits = logits + b_ref[:].astype(jnp.float32)[None, :]
+    logits = logits + b_ref[:].astype(jnp.float32)          # [1, BV] row
     col = j * block_v + jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
     valid = col < total_vocab
     if extra_valid is not None:
@@ -234,7 +237,17 @@ def xent_bwd_pallas(hidden, weight, bias, labels, logz, g, sn, sp,
     """
     N, H = hidden.shape
     V = weight.shape[0]
-    bn, bv = pick_rv_blocks(N, V, H, hidden.dtype.itemsize)
+    # each kernel sizes its own tiles from what it holds in VMEM: dh
+    # keeps a [bn, H] f32 block across the vocab sweep, dW/db a [bv, H]
+    # one across the row sweep — one shared pair sized for the stats
+    # kernel overflowed the 16 MB scoped limit in dW/db
+    ib = hidden.dtype.itemsize
+    bn, bv = pick_rv_blocks(N, V, H, ib, resident="rows", row_blocks=3,
+                            out="rows")
+    # the bias rides as a [1, V] row: a 1-D block's Mosaic tiling must
+    # equal the layout XLA gave the 1-D operand (f32[V] is T(1024), so
+    # only bv == 1024 was ever legal on the chip)
+    bias2 = bias[None, :]
     lbl2 = labels[:, None].astype(jnp.int32)
     logz2 = logz[:, None].astype(jnp.float32)
     g2 = g[:, None].astype(jnp.float32)
@@ -247,15 +260,17 @@ def xent_bwd_pallas(hidden, weight, bias, labels, logz, g, sn, sp,
         in_specs=[
             tile_spec((bn, H), (0, None)),
             tile_spec((bv, H), (1, None)),
-            tile_spec((bv,), (1,)),
+            tile_spec((1, bv), (None, 1)),
             *row_specs,
         ],
         out_specs=tile_spec((bn, H), (0, None)),
         out_shape=jax.ShapeDtypeStruct((N, H), jnp.float32),
         interpret=interpret,
-    )(hidden, weight, bias, lbl2, logz2, g2)
+    )(hidden, weight, bias2, lbl2, logz2, g2)
     # transposed grid — vocab outer, rows inner — so the [BV, H] dw block
     # (and [1, BV] db block) stays resident across the row sweep
+    bn, bv = pick_rv_blocks(N, V, H, ib, resident="vocab", row_blocks=3,
+                            out="vocab")
     tr_row_specs = [tile_spec((bn, 1), (1, None))] * 3
     dw, db = kernel_call(
         functools.partial(_xent_bwd_dwb_kernel, total_vocab=V, total_rows=N,
@@ -265,7 +280,7 @@ def xent_bwd_pallas(hidden, weight, bias, labels, logz, g, sn, sp,
         in_specs=[
             tile_spec((bn, H), (1, None)),
             tile_spec((bv, H), (0, None)),
-            tile_spec((bv,), (0,)),
+            tile_spec((1, bv), (None, 0)),
             *tr_row_specs,
         ],
         out_specs=[
@@ -277,7 +292,7 @@ def xent_bwd_pallas(hidden, weight, bias, labels, logz, g, sn, sp,
             jax.ShapeDtypeStruct((1, V), jnp.float32),
         ],
         interpret=interpret,
-    )(hidden, weight, bias, lbl2, logz2, g2)
+    )(hidden, weight, bias2, lbl2, logz2, g2)
     return dh, dw, db[0]
 
 

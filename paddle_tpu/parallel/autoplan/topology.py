@@ -53,12 +53,17 @@ class Topology:
         return cls(**d)
 
 
-# per-chip characteristics by device kind: (hbm, peak bf16 flops, ici
-# bytes/s per chip, dcn bytes/s per chip, hbm bytes/s per chip). Peaks
-# mirror observability/perf.py peak_flops(); link numbers are spec-sheet
-# order of magnitude, enough to rank dp-over-DCN vs tp-over-ICI
-# correctly; HBM bandwidth is the roofline term batch-1 decode (and so
-# the speculation break-even) is bound by.
+# THE chip table — per-chip characteristics by chip name: (hbm, peak bf16
+# flops, ici bytes/s per chip, dcn bytes/s per chip, hbm bytes/s per
+# chip). TPU peaks and HBM figures are the published ones (Google Cloud
+# TPU documentation, the "TPU v4" / "v5e" / "v5p" / "v6e" system
+# architecture pages); observability/perf.py reads its MFU denominator
+# from here, so there is one table. The "cpu" row is a planning guess for
+# the virtual-device dev host (it ranks meshes in tests; it is never a
+# utilization denominator — the CPU has no peak). Link numbers are
+# spec-sheet order of magnitude, enough to rank dp-over-DCN vs
+# tp-over-ICI correctly; HBM bandwidth is the roofline term batch-1
+# decode (and so the speculation break-even) is bound by.
 _CHIPS = {
     "cpu": (4 * GIB, 5.0e10, 2.0e10, 2.0e10, 3.0e10),
     "v4": (32 * GIB, 275e12, 2.4e11, 2.5e10, 1.2e12),
@@ -66,6 +71,46 @@ _CHIPS = {
     "v5p": (95 * GIB, 459e12, 4.8e11, 2.5e10, 2.77e12),
     "v6e": (32 * GIB, 918e12, 1.8e11, 2.5e10, 1.64e12),
 }
+
+# ``jax.devices()[0].device_kind`` (lower-cased) -> chip name. On a v5e
+# it is "TPU v5 lite", not anything containing "v5e"; three modules used
+# to guess at it separately and each guessed differently.
+_TPU_KINDS = {
+    "tpu v4": "v4",
+    "tpu v5 lite": "v5e",
+    "tpu v5e": "v5e",
+    "tpu v5p": "v5p",
+    "tpu v5": "v5p",
+    "tpu v6 lite": "v6e",
+    "tpu v6e": "v6e",
+}
+
+
+def chip_name(device):
+    """The one map from what JAX reports to the names the tree uses
+    (observability/perf.py, :func:`detect`, ops/pallas/autotune.py all
+    ask here): ``"cpu"`` for a CPU device, the chip name (``"v5e"``,
+    ...) for a TPU kind the table knows. Anything else raises — a number
+    priced against the wrong chip's peak is worse than no number."""
+    platform = str(device.platform).lower()
+    kind = str(getattr(device, "device_kind", "") or "")
+    if platform == "cpu":
+        return "cpu"
+    name = _TPU_KINDS.get(kind.strip().lower()) if platform == "tpu" else None
+    if name is None:
+        raise ValueError(
+            f"unknown device platform={platform!r} device_kind={kind!r}: "
+            "add it (and its published peak) to "
+            "paddle_tpu/parallel/autoplan/topology.py "
+            f"(known TPU kinds: {sorted(_TPU_KINDS)})")
+    return name
+
+
+def peak_bf16_flops(device):
+    """Published bf16 peak of ``device``'s chip in FLOP/s, or None for
+    the CPU (no peak: ``mfu`` is null there)."""
+    name = chip_name(device)
+    return None if name == "cpu" else _CHIPS[name][1]
 
 # "kind-N" (one slice of N chips) or "MxKIND-N" (M slices). cpuN means N
 # virtual host devices (XLA_FLAGS --xla_force_host_platform_device_count).
@@ -99,24 +144,16 @@ def get_topology(name=None, devices=None):
 
 
 def detect(devices=None):
-    """Derive a Topology from the live ``jax.devices()``."""
-    import jax
-    devices = list(devices) if devices is not None else jax.devices()
-    kind = (getattr(devices[0], "device_kind", "") or "cpu").lower()
-    key = "cpu"
-    for k in ("v6e", "v5p", "v5e", "v4"):
-        if k in kind:
-            key = k
-            break
+    """Derive a Topology from the live ``jax.devices()`` (or the devices
+    handed in, e.g. a described topology's): the chip's table row times
+    the device count. A TPU kind :func:`chip_name` does not know
+    raises."""
+    if devices is None:
+        import jax
+        devices = jax.devices()
+    devices = list(devices)
+    key = chip_name(devices[0])
     hbm, peak, ici, dcn, mem_bw = _CHIPS[key]
-    stats = getattr(devices[0], "memory_stats", None)
-    if callable(stats):
-        try:
-            limit = (stats() or {}).get("bytes_limit")
-            if limit:
-                hbm = int(limit)
-        except Exception:
-            pass  # CPU backends often have no memory_stats
     slices = {getattr(d, "slice_index", 0) or 0 for d in devices}
     return Topology(name=f"detected:{key}{len(devices)}",
                     num_chips=len(devices), hbm_bytes=hbm, peak_flops=peak,

@@ -30,14 +30,10 @@ def _tmap(f, *trees):
 
 
 def _pmean_varying(x, axis_name):
-    """pmean whose output is typed varying-over-axis where the type system
-    exists: jax >= 0.6 shard_map (check_vma) needs the explicit pcast so
-    both lax.cond branches carry the same type; jax 0.4.x (check_rep) has
-    no lax.pcast and needs no cast back."""
-    out = lax.pmean(x, axis_name)
-    if hasattr(lax, "pcast"):
-        out = lax.pcast(out, axis_name, to="varying")
-    return out
+    """pmean whose output is typed varying-over-axis: shard_map's
+    check_vma needs the explicit pcast so both lax.cond branches carry
+    the same type."""
+    return lax.pcast(lax.pmean(x, axis_name), axis_name, to="varying")
 
 
 class GradientMerge:

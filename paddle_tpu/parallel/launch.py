@@ -118,7 +118,12 @@ def host_allgather(arr, rank, world, exchange_dir, tag, timeout=60.0,
 def launch_local(nproc, script, script_args=(), base_port=12355,
                  env_extra=None):
     """Spawn nproc local processes wired into one JAX distributed job
-    (ref: launch.py _start_procs). Used by multi-host simulation tests."""
+    (ref: launch.py _start_procs). Used by multi-host simulation tests.
+
+    CPU-only today: the children run on the CPU unless the caller's
+    environment names another platform in ``JAX_PLATFORMS`` — several
+    local processes cannot share one chip, and a parent that has touched
+    JAX holds it."""
     procs = []
     for rank in range(nproc):
         env = dict(os.environ)

@@ -17,24 +17,11 @@ by the optimizer running sharded over "pp" (each stage updates its own
 params; no cross-replica drift exists).
 """
 
-import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
-
-try:
-    from jax import shard_map
-except ImportError:  # jax < 0.6: experimental namespace, check_rep kwarg
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f=None, **kw):
-        if "check_vma" in kw:
-            kw["check_rep"] = kw.pop("check_vma")
-        if f is None:
-            return functools.partial(shard_map, **kw)
-        return _shard_map(f, **kw)
 
 from paddle_tpu.parallel.mesh import PP
 

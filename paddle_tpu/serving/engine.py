@@ -67,9 +67,9 @@ failed):
     prompt + generated tokens are the durable state, so a recovered
     greedy request finishes token-exact. Bounded by a RetryPolicy
     budget (`serve_step_retries` consecutive failures, then the
-    engine fails every request and re-raises). A runtime Pallas decode
-    failure additionally latches a permanent per-process XLA fallback
-    through the shared pallas.fallback wiring.
+    engine fails every request and re-raises). Recovery re-runs the
+    SAME step programs: a kernel that fails at run time is a failed
+    step that surfaces, never a switch to another implementation.
   * watchdog mitigation — goodput_collapse / ingest_stall anomalies
     invoke the engine's load-shedding action: expired-deadline queued
     requests are shed first, else the single lowest-priority one
@@ -89,7 +89,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from paddle_tpu.core.enforce import enforce
-from paddle_tpu.core.flags import get_flag, set_flags
+from paddle_tpu.core.flags import get_flag
 from paddle_tpu.observability import flight as _flight
 from paddle_tpu.observability import metrics as _metrics
 from paddle_tpu.testing.chaos import fault_point
@@ -331,8 +331,6 @@ class ServingEngine:
         #                               verify) — tokens/target_steps is
         #                               the speculation win
         self.recoveries = 0           # step crashes recovered (engine-wide)
-        self._trace_credit = 0        # legitimate re-traces (jit rebuild
-        #                               after a latched Pallas fallback)
         from paddle_tpu.core.retry import RetryBudget, RetryPolicy
         self._retry_budget = RetryBudget(
             RetryPolicy(max_attempts=cfg.step_retries + 1), "serve.step")
@@ -460,12 +458,7 @@ class ServingEngine:
         return draft, variables["params"]
 
     def _build_jits(self):
-        """(Re)create the two jitted closures. Called once at
-        construction and again when a recovery latches the Pallas->XLA
-        decode fallback (the flag is read at trace time, so a fresh jit
-        cache is the only way to honor the flip); `_trace_credit`
-        absorbs those deliberate re-traces so they don't count as
-        `jit.retraces`."""
+        """Create the jitted step closures, once, at construction."""
         model = self._model
         _sample = self._sample
 
@@ -473,13 +466,10 @@ class ServingEngine:
             n = getattr(self, attr) + 1
             setattr(self, attr, n)
             if n > 1 and not self._aot_trace:
-                if self._trace_credit > 0:
-                    self._trace_credit -= 1
-                else:
-                    # traced-once invariant broken in live serving —
-                    # visible to /metrics and the watchdog, not just
-                    # compile smokes
-                    _metrics.counter("jit.retraces").inc(fn=fn)
+                # traced-once invariant broken in live serving —
+                # visible to /metrics and the watchdog, not just
+                # compile smokes
+                _metrics.counter("jit.retraces").inc(fn=fn)
 
         def decode(params, caches, tokens, page_table, lengths, active,
                    temps, top_ks, top_ps, seeds, counts):
@@ -960,6 +950,24 @@ class ServingEngine:
                 np.zeros(s, np.float32), np.zeros(s, np.int32),
                 np.zeros(s, np.float32), np.zeros(s, np.uint32),
                 np.zeros(s, np.int32)).compile()
+        finally:
+            self._aot_trace = False
+
+    def compiled_prefill(self):
+        """AOT-compile the admission prefill step (one extra trace,
+        absorbed like compiled_decode's) and return the compiled
+        executable, for HLO inspection."""
+        cfg = self.cfg
+        self._aot_trace = True    # a deliberate extra trace, not a retrace
+        try:
+            return self._prefill_jit.lower(
+                self._params, self._caches,
+                np.zeros((1, cfg.prefill_len), np.int32),
+                np.zeros(1, np.int32), np.zeros(1, np.int32),
+                self._page_table[:1], np.zeros(1, np.int32),
+                np.zeros(1, np.float32), np.zeros(1, np.int32),
+                np.zeros(1, np.float32), np.zeros(1, np.uint32),
+                np.zeros(1, np.int32)).compile()
         finally:
             self._aot_trace = False
 
@@ -1565,9 +1573,10 @@ class ServingEngine:
         state is suspect — quarantine it: rebuild the pools, zero the
         scheduler arrays, and re-admit every in-flight request
         recompute-style (host-side prompt + generated tokens are the
-        durable state; a greedy request finishes token-exact). A runtime
-        Pallas decode failure additionally latches the permanent
-        per-process XLA fallback. Bounded: `serve_step_retries`
+        durable state; a greedy request finishes token-exact). The same
+        step programs run again: a kernel that fails at run time keeps
+        failing and surfaces when the budget is spent, never a quiet
+        change of implementation. Bounded: `serve_step_retries`
         consecutive failures, then every request is failed and `exc`
         re-raised."""
         cfg = self.cfg
@@ -1581,23 +1590,6 @@ class ServingEngine:
                 "phase": "serve", "recovery": where,
                 "step": self._step_no, "in_flight": len(victims),
                 "error": f"{type(exc).__name__}: {exc}"[:200]})
-        msg = f"{type(exc).__name__}: {exc}".lower()
-        if get_flag("use_pallas_decode") and any(
-                s in msg for s in ("pallas", "mosaic", "custom_call",
-                                   "custom call")):
-            # runtime kernel failure: latch the per-process XLA fallback
-            # (flag read at trace time -> fresh jit caches required; the
-            # trace credit keeps the deliberate re-traces out of
-            # jit.retraces)
-            from paddle_tpu.ops.pallas import log_fallback
-            set_flags({"use_pallas_decode": False})
-            log_fallback("decode_attention",
-                         f"runtime decode failure ({type(exc).__name__})"
-                         " — latched permanent per-process XLA fallback")
-            # decode + prefill, plus draft/draft-prefill/verify when
-            # speculation is on — all read the flag at trace time
-            self._trace_credit += 2 + (3 if self._spec_on else 0)
-            self._build_jits()
         # quarantine: drop the (donated, possibly poisoned) pools
         self._caches = self._model.init_paged_caches(
             cfg.num_pages, cfg.page_size, dtype=cfg.cache_dtype,

@@ -91,7 +91,7 @@ class TestMoELayer:
         assert np.abs(np.asarray(g["w_gate"])).sum() > 0
 
     def test_expert_parallel_matches_single_device(self):
-        from paddle_tpu.parallel.pipeline import shard_map
+        from jax import shard_map
         from jax.sharding import NamedSharding, PartitionSpec as P
         m, v = self._layer()
         rng = np.random.RandomState(3)

@@ -13,7 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
-from paddle_tpu.parallel.pipeline import shard_map
+from jax import shard_map
 
 import paddle_tpu as pt
 from paddle_tpu.parallel import collective as C
@@ -602,7 +602,7 @@ class TestRingFlashAttention:
     ring_attention math."""
 
     def _run(self, fn, q, causal):
-        from paddle_tpu.parallel.pipeline import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         import paddle_tpu as pt
@@ -746,7 +746,7 @@ def test_ulysses_flash_kernel_interpret():
     """Ulysses default attention now rides the flash kernel: interpret
     mode must match the dense path (full-sequence per head subset is
     exactly the kernel's layout)."""
-    from paddle_tpu.parallel.pipeline import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from paddle_tpu.core.flags import set_flags

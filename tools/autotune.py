@@ -8,13 +8,13 @@ runtime (`autotune=1`) and the autoplan cost model both read, so a sweep
 here prices every later `predict()` on this chip with measured rates.
 
 Usage:
-  timeout 560 python tools/autotune.py sweep [--kernel all|...] [--json]
+  python tools/autotune.py sweep [--kernel all|...] [--json]
   python tools/autotune.py sweep --interpret   # CPU plumbing self-check
   python tools/autotune.py inspect [--json]    # dump the cache, ranked
   python tools/autotune.py clear               # drop the cache file
 
-Like tools/flash_tune.py, silicon timings need a TPU; --interpret runs
-the same plumbing on CPU (timings meaningless, cache still exercised).
+Timings need a TPU; --interpret runs the same plumbing on CPU (timings
+meaningless, cache still exercised).
 """
 
 import argparse

@@ -814,7 +814,8 @@ def _selftest():
         for key in ("wall_s", "tokens_per_s", "mfu", "loss", "memory"):
             assert key in r, (key, r)
         assert isinstance(r["loss"], float), r
-        assert isinstance(r["mfu"], float), r    # cost analysis worked
+        # a float on a chip with a published peak; the CPU has none
+        assert r["mfu"] is None or isinstance(r["mfu"], float), r
         assert r["tokens_per_s"] > 0, r
     assert finals, "final snapshot record missing"
     counters = finals[-1]["counters"]

@@ -49,6 +49,7 @@ class HotPathSync(Rule):
         "paddle_tpu/observability/telemetry.py",
         "paddle_tpu/observability/watchdog.py",
         "paddle_tpu/observability/trace.py",
+        "paddle_tpu/observability/spans.py",
         "paddle_tpu/observability/flight.py",
         "paddle_tpu/data/loader.py",
     )

@@ -15,10 +15,12 @@ Reference-framework ancestry (what each piece re-architects):
                 machine-readable run artifact the reference never had
                 (DeviceWorker VLOG lines were the closest thing).
   spans.py      nestable span() scopes — platform/profiler.h:81
-                RecordEvent, feeding the sorted text table
-                (profiler.h:166 EnableProfiler), the metrics registry,
-                and jax.profiler.TraceAnnotation (the chrome-trace
-                timeline role of tools/timeline.py).
+                RecordEvent, feeding the metrics registry (and from it
+                the sorted text table, profiler.h:166 EnableProfiler),
+                jax.profiler.TraceAnnotation (the chrome-trace timeline
+                role of tools/timeline.py) and, while a profiler
+                session is on, the bounded in-memory span store
+                (start, end, parent, request id, counts).
   perf.py       peak-FLOPs table + XLA cost-analysis + device memory
                 stats (moved from bench.py so bench rows, step records,
                 and tools/run_report.py share one MFU arithmetic).
@@ -67,7 +69,7 @@ from paddle_tpu.observability.runlog import (RunLog, read_records,
 # jax/profiler, which early importers of the metrics registry must not
 _LAZY = {
     "span": "spans", "annotate_span": "spans", "span_summary": "spans",
-    "span_report": "spans", "reset_spans": "spans", "recorder": "spans",
+    "span_report": "spans", "reset_spans": "spans",
     "spans": None, "telemetry": None, "perf": None,
     "catalog": None, "exporter": None, "watchdog": None,
     "trace": None, "flight": None,

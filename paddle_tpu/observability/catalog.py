@@ -198,6 +198,11 @@ CATALOG = {
         "Quantized-KV admissions degraded to private pages by the "
         "quant.kv_write fault point (no prefix-cache mapping or "
         "publish for that request)."),
+    "serve.kv_pages_in_use": MetricSpec(
+        "gauge", (),
+        "KV pages no admission could obtain: pinned by the running "
+        "requests (private pages plus the prefix-cache pages they map); "
+        "set once a scheduling round, whatever the pool's dtype."),
     "serve.kv_quant_pages": MetricSpec(
         "gauge", (),
         "KV pages currently allocated out of an int8-quantized page "

@@ -31,6 +31,8 @@ import threading
 import time
 import uuid
 
+from paddle_tpu.observability import flight
+
 # --------------------------------------------------------------------------
 # event catalog
 # --------------------------------------------------------------------------
@@ -137,7 +139,6 @@ def note_span(name, dt):
     feeding the flight ring (a bounded deque append — no I/O). Called
     from ``spans.span()``'s exit path; returns fast when the flight
     recorder is off."""
-    from paddle_tpu.observability import flight
     rec = flight.recorder()
     if rec is None:
         return
@@ -167,7 +168,6 @@ def write_anchor(run_log, **tags):
     rec = anchor_record(**tags)
     if run_log is not None:
         run_log.write(rec)
-    from paddle_tpu.observability import flight
     fl = flight.recorder()
     if fl is not None:
         fl.note_event("anchor", wall=rec["anchor"]["wall"],

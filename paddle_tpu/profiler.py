@@ -40,13 +40,13 @@ def record_event(name):
     return jax.profiler.TraceAnnotation(name)
 
 
-def span(name):
+def span(name, rid=None):
     """record_event promoted: the registry-backed span
-    (observability/spans.py) — times the scope into the global
-    EventRecorder table AND a metrics histogram AND the device trace.
-    Lazy import: spans.py imports this module for EventRecorder."""
+    (observability/spans.py) — times the scope into a metrics histogram
+    AND the device trace, and records it while a profiler session is
+    on."""
     from paddle_tpu.observability.spans import span as _span
-    return _span(name)
+    return _span(name, rid)
 
 
 def annotate_fn(name):
@@ -62,11 +62,10 @@ class EventRecorder:
     """Host-side timing table (ref: profiler.cc event tables printed by
     DisableProfiler). Times python-visible spans (incl. dispatch+block).
 
-    This is the recorder behind observability.span(); `add()` is the
-    non-context entry those spans feed, `reset()` starts a fresh epoch
-    (state is otherwise append-forever), and summary/report carry
-    p50/p95 alongside min/max — the tail is where step-time regressions
-    live."""
+    `add()` is the non-context entry, `reset()` starts a fresh epoch
+    (state is otherwise append-forever: observability.span() no longer
+    feeds one), and summary/report carry p50/p95 alongside min/max —
+    the tail is where step-time regressions live."""
 
     def __init__(self):
         self._events = defaultdict(list)
@@ -110,15 +109,21 @@ class EventRecorder:
         return rows
 
     def report(self):
-        lines = [f"{'Event':<40}{'Calls':>8}{'Total(s)':>12}{'Avg(ms)':>12}"
-                 f"{'p50(ms)':>12}{'p95(ms)':>12}"
-                 f"{'Min(ms)':>12}{'Max(ms)':>12}"]
-        for r in self.summary():
-            lines.append(f"{r['name']:<40}{r['calls']:>8}{r['total_s']:>12.4f}"
-                         f"{r['avg_ms']:>12.3f}{r['p50_ms']:>12.3f}"
-                         f"{r['p95_ms']:>12.3f}{r['min_ms']:>12.3f}"
-                         f"{r['max_ms']:>12.3f}")
-        return "\n".join(lines)
+        return event_table(self.summary())
+
+
+def event_table(rows):
+    """The sorted text table of summary rows (EventRecorder.summary's,
+    or observability.span_summary's)."""
+    lines = [f"{'Event':<40}{'Calls':>8}{'Total(s)':>12}{'Avg(ms)':>12}"
+             f"{'p50(ms)':>12}{'p95(ms)':>12}"
+             f"{'Min(ms)':>12}{'Max(ms)':>12}"]
+    for r in rows:
+        lines.append(f"{r['name']:<40}{r['calls']:>8}{r['total_s']:>12.4f}"
+                     f"{r['avg_ms']:>12.3f}{r['p50_ms']:>12.3f}"
+                     f"{r['p95_ms']:>12.3f}{r['min_ms']:>12.3f}"
+                     f"{r['max_ms']:>12.3f}")
+    return "\n".join(lines)
 
 
 def trace_op_table(trace_dir, device_filter="TPU", top=30, steps=1):

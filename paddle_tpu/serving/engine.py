@@ -92,6 +92,8 @@ from paddle_tpu.core.enforce import enforce
 from paddle_tpu.core.flags import get_flag
 from paddle_tpu.observability import flight as _flight
 from paddle_tpu.observability import metrics as _metrics
+from paddle_tpu.observability import spans as _spans
+from paddle_tpu.observability.spans import phase, span
 from paddle_tpu.testing.chaos import fault_point
 
 
@@ -371,7 +373,8 @@ class ServingEngine:
             "serve.slo_violations", "serve.recoveries", "serve.shed",
             "serve.prefix_hits", "serve.prefix_misses",
             "serve.cow_copies", "serve.pages_shared",
-            "serve.kv_quant_pages", "serve.spec_proposed",
+            "serve.kv_quant_pages", "serve.kv_pages_in_use",
+            "serve.spec_proposed",
             "serve.spec_accepted", "serve.spec_rollbacks",
             "jit.retraces"])
         self._retired = 0
@@ -613,8 +616,8 @@ class ServingEngine:
         enforce(prompt.size + max_new <= cfg.max_len,
                 f"prompt {prompt.size} + max_new {max_new} exceeds "
                 f"max_len {cfg.max_len}")
-        with self._lock:
-            req = Request(id=next(self._ids), prompt=prompt,
+        with self._lock, span("serve.submit", rid=next(self._ids)) as sp:
+            req = Request(id=sp.rid, prompt=prompt,
                           max_new=max_new,
                           eos_id=eos_id if eos_id is not None
                           else cfg.eos_id,
@@ -678,8 +681,8 @@ class ServingEngine:
                 f"max_len {cfg.max_len}")
         enforce(len(tokens) <= max_new,
                 f"adopted with {len(tokens)} tokens > max_new {max_new}")
-        with self._lock:
-            req = Request(id=next(self._ids), prompt=prompt,
+        with self._lock, span("serve.submit", rid=next(self._ids)) as sp:
+            req = Request(id=sp.rid, prompt=prompt,
                           max_new=max_new,
                           eos_id=eos_id if eos_id is not None
                           else cfg.eos_id,
@@ -759,25 +762,27 @@ class ServingEngine:
         page tables where the next token opens a page, run ONE jitted
         decode step over all slots, and retire requests that hit EOS or
         their token budget. Returns the requests finished this round."""
-        with self._lock:
+        with self._lock, span("serve.step") as sp:
             t0 = self._clock()
             finished = []
-            self._shed_expired(finished)
-            self._admit(finished)
-            stalled = self._grow_pages()
-            while stalled and not self._active.any():
-                # pool deadlock: every live slot needs a fresh page and
-                # none is free. Preempt the lowest-priority /
-                # latest-deadline stalled request (free its pages,
-                # requeue it for re-prefill) so higher-value work always
-                # makes progress — with all-default requests this
-                # reduces to the youngest. Greedy decoding regenerates
-                # the dropped tokens exactly; sampled runs re-draw
-                # (recompute preemption).
-                victim = min((self._running[s] for s in stalled),
-                             key=self._victim_key)
-                self._preempt(victim)
+            with phase("serve.admit"):
+                self._shed_expired(finished)
+                self._admit(finished)
+            with phase("serve.grow"):
                 stalled = self._grow_pages()
+                while stalled and not self._active.any():
+                    # pool deadlock: every live slot needs a fresh page
+                    # and none is free. Preempt the lowest-priority /
+                    # latest-deadline stalled request (free its pages,
+                    # requeue it for re-prefill) so higher-value work
+                    # always makes progress — with all-default requests
+                    # this reduces to the youngest. Greedy decoding
+                    # regenerates the dropped tokens exactly; sampled
+                    # runs re-draw (recompute preemption).
+                    victim = min((self._running[s] for s in stalled),
+                                 key=self._victim_key)
+                    self._preempt(victim)
+                    stalled = self._grow_pages()
             new_tokens = 0
             toks = None
             spec = None
@@ -797,86 +802,38 @@ class ServingEngine:
                     if use_spec:
                         spec = self._spec_round()
                     else:
-                        toks_dev, self._caches = self._decode_jit(
-                            self._params, self._caches, self._last_tokens,
-                            self._page_table, self._lengths, self._active,
-                            self._temps, self._top_ks, self._top_ps,
-                            self._seeds, self._gen_counts)
-                        toks = np.asarray(toks_dev)  # graft-lint: disable=hot-path-sync (the one deliberate sync per decode round: the python scheduler needs this step's tokens to advance/free slots)
+                        with phase("serve.decode"):
+                            toks_dev, self._caches = self._decode_jit(
+                                self._params, self._caches,
+                                self._last_tokens, self._page_table,
+                                self._lengths, self._active, self._temps,
+                                self._top_ks, self._top_ps, self._seeds,
+                                self._gen_counts)
+                        with phase("serve.fetch"):
+                            toks = np.asarray(toks_dev)  # graft-lint: disable=hot-path-sync (the one deliberate sync per decode round: the python scheduler needs this step's tokens to advance/free slots)
                 except Exception as e:
                     self._recover("serve.step", e)
-            if spec is not None:
-                # speculative round: per slot, accept the leading run of
-                # draft proposals that match the target's own samples
-                # and emit accepted + 1 tokens; rejection rollback is
-                # the length simply advancing fewer positions than the
-                # verify window wrote (stale KV/scale rows beyond the
-                # accepted prefix are overwritten by later writes)
-                self._retry_budget.success()
-                self.spec_rounds += 1
-                self.target_steps += 1
-                dt = self._clock() - t0
-                lat = _metrics.histogram("serve.token_latency_s")
-                sampled, props, win = spec
-                spec_proposed = spec_accepted = 0
-                for slot, req in list(self._running.items()):
-                    if not self._active[slot]:
-                        continue               # page-stalled this round
-                    w = int(win[slot])
-                    self.spec_slot_rounds += 1
-                    a = 0
-                    while (a < w - 1
-                           and int(props[slot, a]) == int(sampled[slot, a])):
-                        a += 1
-                    m = a + 1                  # tokens safe to emit
-                    spec_proposed += w - 1
-                    spec_accepted += a
-                    emitted = 0
-                    for j in range(m):
-                        tok = int(sampled[slot, j])
-                        self._lengths[slot] += 1   # its KV is cached
-                        req.tokens.append(tok)
-                        self._gen_counts[slot] += 1
-                        self._last_tokens[slot] = tok
-                        lat.observe(dt / m)
-                        new_tokens += 1
-                        emitted += 1
-                        reason = self._done_reason(req, tok)
-                        if reason:
-                            self._release(req, finished, reason)
-                            break
-                    req.spec_tokens += max(0, emitted - 1)
-                self.spec_proposed += spec_proposed
-                self.spec_accepted += spec_accepted
-                self.spec_rollbacks += spec_proposed - spec_accepted
-                _metrics.counter("serve.spec_proposed").inc(spec_proposed)
-                _metrics.counter("serve.spec_accepted").inc(spec_accepted)
-                _metrics.counter("serve.spec_rollbacks").inc(
-                    spec_proposed - spec_accepted)
-            elif toks is not None:
+            if spec is not None or toks is not None:
                 self._retry_budget.success()   # consecutive-failure reset
                 self.target_steps += 1
-                dt = self._clock() - t0
-                lat = _metrics.histogram("serve.token_latency_s")
-                for slot, req in list(self._running.items()):
-                    if not self._active[slot]:
-                        continue               # page-stalled this round
-                    self._lengths[slot] += 1   # pending token now cached
-                    tok = int(toks[slot])
-                    req.tokens.append(tok)
-                    self._gen_counts[slot] += 1  # next draw = fold(seed, i)
-                    self._last_tokens[slot] = tok
-                    lat.observe(dt)
-                    new_tokens += 1
-                    reason = self._done_reason(req, tok)
-                    if reason:
-                        self._release(req, finished, reason)
+                with phase("serve.advance"):
+                    if spec is not None:
+                        new_tokens, spec_proposed, spec_accepted = \
+                            self._advance_spec(spec, self._clock() - t0,
+                                               finished)
+                    else:
+                        new_tokens = self._advance(
+                            toks, self._clock() - t0, finished)
             _metrics.counter("serve.tokens").inc(new_tokens)
             _metrics.gauge("serve.active_slots").set(len(self._running))
             _metrics.gauge("serve.queue_depth").set(len(self._queue))
             if self.cfg.kv_dtype is not None:
                 _metrics.gauge("serve.kv_quant_pages").set(
                     self.cfg.num_pages - len(self._free_pages))
+            in_use = self.pages_in_use()
+            _metrics.gauge("serve.kv_pages_in_use").set(in_use)
+            sp.count(pages_in_use=in_use, pages_cached=self.pages_cached(),
+                     num_pages=self.cfg.num_pages)
             wall_s = self._clock() - t0
             if self._run_log is not None:
                 rec = {
@@ -898,6 +855,73 @@ class ServingEngine:
                                     retired=self._retired)
             self._step_no += 1
             return finished
+
+    def _advance(self, toks, dt, finished):
+        """After a plain decode round: append each active slot's token,
+        retire what is done. Returns the tokens emitted."""
+        new_tokens = 0
+        lat = _metrics.histogram("serve.token_latency_s")
+        for slot, req in list(self._running.items()):
+            if not self._active[slot]:
+                continue               # page-stalled this round
+            self._lengths[slot] += 1   # pending token now cached
+            tok = int(toks[slot])
+            req.tokens.append(tok)
+            self._gen_counts[slot] += 1  # next draw = fold(seed, i)
+            self._last_tokens[slot] = tok
+            lat.observe(dt)
+            new_tokens += 1
+            reason = self._done_reason(req, tok)
+            if reason:
+                self._release(req, finished, reason)
+        return new_tokens
+
+    def _advance_spec(self, spec, dt, finished):
+        """After a speculative round: per slot, accept the leading run
+        of draft proposals that match the target's own samples and emit
+        accepted + 1 tokens; rejection rollback is the length simply
+        advancing fewer positions than the verify window wrote (stale
+        KV/scale rows beyond the accepted prefix are overwritten by
+        later writes). Returns (tokens emitted, proposed, accepted)."""
+        self.spec_rounds += 1
+        lat = _metrics.histogram("serve.token_latency_s")
+        sampled, props, win = spec
+        new_tokens = spec_proposed = spec_accepted = 0
+        for slot, req in list(self._running.items()):
+            if not self._active[slot]:
+                continue               # page-stalled this round
+            w = int(win[slot])
+            self.spec_slot_rounds += 1
+            a = 0
+            while (a < w - 1
+                   and int(props[slot, a]) == int(sampled[slot, a])):
+                a += 1
+            m = a + 1                  # tokens safe to emit
+            spec_proposed += w - 1
+            spec_accepted += a
+            emitted = 0
+            for j in range(m):
+                tok = int(sampled[slot, j])
+                self._lengths[slot] += 1   # its KV is cached
+                req.tokens.append(tok)
+                self._gen_counts[slot] += 1
+                self._last_tokens[slot] = tok
+                lat.observe(dt / m)
+                new_tokens += 1
+                emitted += 1
+                reason = self._done_reason(req, tok)
+                if reason:
+                    self._release(req, finished, reason)
+                    break
+            req.spec_tokens += max(0, emitted - 1)
+        self.spec_proposed += spec_proposed
+        self.spec_accepted += spec_accepted
+        self.spec_rollbacks += spec_proposed - spec_accepted
+        _metrics.counter("serve.spec_proposed").inc(spec_proposed)
+        _metrics.counter("serve.spec_accepted").inc(spec_accepted)
+        _metrics.counter("serve.spec_rollbacks").inc(
+            spec_proposed - spec_accepted)
+        return new_tokens, spec_proposed, spec_accepted
 
     def drain(self, max_steps=100000):
         """Run step() until every submitted request finishes; returns the
@@ -1112,9 +1136,10 @@ class ServingEngine:
 
     def _trace_event(self, req, event, **extra):
         """One lifecycle trace point: a host clock read, a list append,
-        a bounded-ring append, and (when a RunLog is configured) a JSONL
-        write — never a device sync (the flush-spy test's contract).
-        Returns the timestamp."""
+        a bounded-ring append, (when a RunLog is configured) a JSONL
+        write, and (while a profiler session is on) a span-store record
+        that shares the request's id with its spans — never a device
+        sync (the flush-spy test's contract). Returns the timestamp."""
         t = self._clock()
         req.trace.append((event, t))
         rec = {"event": event, "req": req.id, "trace": req.trace_id,
@@ -1134,6 +1159,9 @@ class ServingEngine:
         fl = _flight.recorder()
         if fl is not None:           # deque append — no I/O, no sync
             fl.note(rec)
+        # the span store stamps the event on its own clock (this
+        # engine's can be injected), and only under a profiler session
+        _spans.event(event, req.id)
         return t
 
     def _stage_chunks(self, seq):
@@ -1152,6 +1180,20 @@ class ServingEngine:
                 for i in range(n)]
 
     # --- page allocation + prefix cache ---------------------------------
+
+    def pages_cached(self):
+        """K/V pages the prefix cache keeps for no running request:
+        evictable, so an admission can still obtain them."""
+        with self._lock:
+            return (self._prefix_cache.evictable()
+                    if self._prefix_cache is not None else 0)
+
+    def pages_in_use(self):
+        """K/V pages no admission could obtain right now: pinned by the
+        running requests (their private pages and the shared pages they
+        map)."""
+        with self._lock:
+            return self.cfg.num_pages - self._pages_available()
 
     def _pages_available(self):
         """Pages an admission could obtain right now: the free list plus
@@ -1326,7 +1368,9 @@ class ServingEngine:
                 _metrics.counter("serve.page_stalls").inc(where="admit")
                 break                      # head-of-line waits for pages
             self._queue.remove(req)
-            if not self._prefill_request(req, total, finished):
+            with phase("serve.prefill", rid=req.id):
+                ok = self._prefill_request(req, total, finished)
+            if not ok:
                 break          # mid-admission page stall or a recovery
         _metrics.gauge("serve.queue_depth").set(len(self._queue))
 
@@ -1403,7 +1447,8 @@ class ServingEngine:
                         self._draft_params, self._draft_caches,
                         req.device_prompt[ci], starts, lens,
                         self._page_table[slot][None, :], floors)
-                tok = int(np.asarray(tok_dev)[0])  # graft-lint: disable=hot-path-sync (admission-time sync, once per prefill chunk: the slot table needs the first token before decode rounds start)
+                with phase("serve.prefill.fetch", rid=req.id):
+                    tok = int(np.asarray(tok_dev)[0])  # graft-lint: disable=hot-path-sync (admission-time sync, once per prefill chunk: the slot table needs the first token before decode rounds start)
             except Exception as e:
                 self._recover("serve.prefill", e, pending=req)
                 return False
@@ -1487,51 +1532,53 @@ class ServingEngine:
         pool is drained (never below 1 — _grow_pages already made the
         pending position writable, so a famine degrades the slot to
         plain-decode behavior instead of stalling it)."""
-        cfg = self.cfg
-        ps = cfg.page_size
-        k = cfg.spec_k
-        win = np.zeros(cfg.num_slots, np.int32)
-        for slot, req in self._running.items():
-            if not self._active[slot]:
-                continue               # page-stalled this round
-            w = min(k + 1, req.max_new - len(req.tokens))
-            ln = int(self._lengths[slot])
-            while w > 1:
-                owned = len(req.shared_pages) + len(req.pages)
-                if (ln + w - 1) // ps < owned:
-                    break              # window fully covered
-                page = self._alloc_page()
-                if page is None:
-                    # pool famine: shrink the window to the pages the
-                    # slot already owns (>= 1 position past _grow_pages)
-                    w = owned * ps - ln
-                    break
-                req.pages.append(page)
-                self._page_table[slot, owned] = page
-            win[slot] = w
-        # draft phase: proposal i+1 is drawn with count+i — the same
-        # key verify re-draws position i+1 with, so a well-matched
-        # draft's proposals survive acceptance token-for-token. Tokens
-        # feed back as device arrays; nothing syncs until the window is
-        # scored.
-        props_dev = []
-        tok = self._last_tokens
-        for i in range(k):
-            step_act = self._active & (win > i + 1)
-            tok, self._draft_caches = self._draft_jit(
-                self._draft_params, self._draft_caches, tok,
-                self._page_table, self._lengths + i, step_act,
-                self._temps, self._top_ks, self._top_ps,
-                self._seeds, self._gen_counts + i)
-            props_dev.append(tok)
-        window = jnp.stack([jnp.asarray(self._last_tokens)] + props_dev,
-                           axis=1)
-        sampled_dev, self._caches = self._verify_jit(
-            self._params, self._caches, window, self._lengths, win,
-            self._page_table, self._temps, self._top_ks, self._top_ps,
-            self._seeds, self._gen_counts)
-        props = np.stack([np.asarray(p) for p in props_dev], axis=1)
-        sampled = np.asarray(sampled_dev)  # graft-lint: disable=hot-path-sync (the speculative round's one deliberate sync point, fetching proposals + verify draws together: acceptance is a host-side compare, and the scheduler needs this round's tokens to advance/free slots)
+        with phase("serve.decode"):
+            cfg = self.cfg
+            ps = cfg.page_size
+            k = cfg.spec_k
+            win = np.zeros(cfg.num_slots, np.int32)
+            for slot, req in self._running.items():
+                if not self._active[slot]:
+                    continue               # page-stalled this round
+                w = min(k + 1, req.max_new - len(req.tokens))
+                ln = int(self._lengths[slot])
+                while w > 1:
+                    owned = len(req.shared_pages) + len(req.pages)
+                    if (ln + w - 1) // ps < owned:
+                        break              # window fully covered
+                    page = self._alloc_page()
+                    if page is None:
+                        # pool famine: shrink the window to the pages the
+                        # slot already owns (>= 1 position past _grow_pages)
+                        w = owned * ps - ln
+                        break
+                    req.pages.append(page)
+                    self._page_table[slot, owned] = page
+                win[slot] = w
+            # draft phase: proposal i+1 is drawn with count+i — the same
+            # key verify re-draws position i+1 with, so a well-matched
+            # draft's proposals survive acceptance token-for-token. Tokens
+            # feed back as device arrays; nothing syncs until the window is
+            # scored.
+            props_dev = []
+            tok = self._last_tokens
+            for i in range(k):
+                step_act = self._active & (win > i + 1)
+                tok, self._draft_caches = self._draft_jit(
+                    self._draft_params, self._draft_caches, tok,
+                    self._page_table, self._lengths + i, step_act,
+                    self._temps, self._top_ks, self._top_ps,
+                    self._seeds, self._gen_counts + i)
+                props_dev.append(tok)
+            window = jnp.stack([jnp.asarray(self._last_tokens)] + props_dev,
+                               axis=1)
+            sampled_dev, self._caches = self._verify_jit(
+                self._params, self._caches, window, self._lengths, win,
+                self._page_table, self._temps, self._top_ks, self._top_ps,
+                self._seeds, self._gen_counts)
+        with phase("serve.fetch"):
+            props = np.stack([np.asarray(p) for p in props_dev], axis=1)
+            sampled = np.asarray(sampled_dev)  # graft-lint: disable=hot-path-sync (the speculative round's one deliberate sync point, fetching proposals + verify draws together: acceptance is a host-side compare, and the scheduler needs this round's tokens to advance/free slots)
         return sampled, props, win
 
     def _free_slot_state(self, req):

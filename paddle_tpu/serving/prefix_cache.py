@@ -72,6 +72,7 @@ class PrefixCache:
         self._entries = {}     # chain key -> _Entry
         self._by_page = {}     # page id -> chain key
         self._tick = 0         # LRU clock (bumped on release-to-idle)
+        self._idle = 0         # entries at refcount zero (evictable())
         self.hits = 0          # full pages served from the cache
         self.misses = 0        # full probe pages not in the cache
         self.collisions = 0    # key present but token content mismatched
@@ -134,7 +135,10 @@ class PrefixCache:
         """Take one reference per page id in `pages` (pages just mapped
         into a slot's table by a match)."""
         for pid in pages:
-            self._entries[self._by_page[int(pid)]].refs += 1
+            ent = self._entries[self._by_page[int(pid)]]
+            if ent.refs == 0:
+                self._idle -= 1
+            ent.refs += 1
 
     def release(self, pages):
         """Drop one reference per page id. Entries hitting refcount zero
@@ -150,6 +154,8 @@ class PrefixCache:
                 freed.append(pid)
                 continue
             ent = self._entries[key]
+            if ent.refs == 1:
+                self._idle += 1
             ent.refs -= 1
             if ent.refs <= 0:
                 ent.refs = 0
@@ -186,8 +192,10 @@ class PrefixCache:
         return out
 
     def evictable(self):
-        """How many cached pages could be evicted right now (refcount 0)."""
-        return sum(1 for e in self._entries.values() if e.refs == 0)
+        """How many cached pages could be evicted right now (refcount
+        0): a count kept by acquire / release / evict, since the engine
+        asks on every admission and every round."""
+        return self._idle
 
     def evict(self, n=1):
         """Evict up to `n` least-recently-released refcount-zero entries;
@@ -198,6 +206,7 @@ class PrefixCache:
         for _, key in idle[:n]:
             ent = self._entries.pop(key)
             del self._by_page[ent.page]
+            self._idle -= 1
             self.evictions += 1
             out.append(ent.page)
         return out
@@ -213,3 +222,4 @@ class PrefixCache:
         its free list wholesale alongside this."""
         self._entries.clear()
         self._by_page.clear()
+        self._idle = 0

@@ -64,6 +64,34 @@ def rng():
     return np.random.RandomState(0)
 
 
+@pytest.fixture
+def fresh_store(monkeypatch):
+    """A span store of this test's own behind span() and event()."""
+    from paddle_tpu.observability import spans
+    store = spans.SpanStore()
+    monkeypatch.setattr(spans, "_STORE", store)
+    return store
+
+
+@pytest.fixture
+def profiler_session(tmp_path):
+    """A context manager that holds a real profiler session open."""
+    import contextlib
+
+    import jax
+
+    @contextlib.contextmanager
+    def hold():
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            yield
+        finally:
+            jax.profiler.stop_trace()
+    return hold
+
+
 def pytest_sessionfinish(session, exitstatus):
     """Shutdown watchdog: orbax/tensorstore's grpc atexit hooks can hang
     interpreter teardown (observed: suite green, process stuck after the

@@ -177,6 +177,143 @@ class TestSpans:
         reset_spans()
 
 
+class TestSpanStore:
+    def test_nothing_recorded_without_session(self, fresh_store):
+        from paddle_tpu.observability import spans
+        spans.reset_spans()
+        for _ in range(3000):
+            with spans.span("quiet") as s:
+                s.count(n=1)
+        spans.event("submitted", rid=1)
+        assert spans.records() == [] and len(fresh_store) == 0
+        # the only state a span leaves is its bounded histogram
+        h = M.registry().get("span.quiet")
+        assert h.count() == 3000
+        assert h.stats()["dropped"] == 3000 - h.max_samples
+        spans.reset_spans()
+
+    def test_nested_pair_is_recorded(self, fresh_store,
+                                     profiler_session):
+        from paddle_tpu.observability import spans
+        with profiler_session():
+            with spans.span("outer", rid=7) as s:
+                with spans.phase("inner"):
+                    pass
+                spans.event("first_token", rid=7)
+                s.count(running=3)
+                s.count(queued=1)
+        inner, ev, outer = spans.records()
+        assert (inner["name"], inner["path"]) == ("inner", "outer/inner")
+        assert (outer["name"], outer["path"]) == ("outer", "outer")
+        assert inner["parent"] == outer["id"] and outer["parent"] is None
+        assert (outer["start"] <= inner["start"] <= inner["end"]
+                <= ev["start"] == ev["end"] <= outer["end"])
+        assert outer["rid"] == 7 and inner["rid"] is None
+        assert outer["counts"] == {"running": 3, "queued": 1}
+        assert inner["counts"] == {}
+        assert (ev["name"], ev["rid"], ev["parent"], ev["path"]) == \
+            ("first_token", 7, outer["id"], None)
+        assert ev["counts"] == {}
+        assert len({inner["id"], ev["id"], outer["id"]}) == 3
+
+    def test_a_phase_feeds_the_session_alone(self, fresh_store,
+                                             profiler_session):
+        from paddle_tpu.observability import flight, spans
+        spans.reset_spans()
+        ring = flight.recorder()
+
+        def nest():
+            with spans.phase("hot") as p:
+                p.count(n=1)
+                with spans.span("cold"):
+                    pass
+
+        nest()                      # no session: the phase leaves nothing
+        assert spans.records() == []
+        with profiler_session():
+            nest()
+        # ... and with one, its record; never a histogram or a ring event
+        cold, hot = spans.records()
+        assert (hot["path"], hot["counts"]) == ("hot", {"n": 1})
+        assert (cold["path"], cold["parent"]) == ("hot/cold", hot["id"])
+        assert [r["name"] for r in spans.span_summary()] == ["hot/cold"]
+        assert M.registry().get("span.hot/cold").count() == 2
+        if ring is not None:
+            assert not [e for e in ring.snapshot()
+                        if e.get("event") == "span" and e["name"] == "hot"]
+        spans.reset_spans()
+
+    def test_exception_still_closes_the_span(self, fresh_store,
+                                             profiler_session):
+        from paddle_tpu.observability import spans
+        with profiler_session():
+            with pytest.raises(RuntimeError):
+                with spans.span("boom"):
+                    raise RuntimeError("x")
+            with spans.span("after"):
+                pass
+        boom, after = spans.records()
+        assert boom["name"] == "boom" and boom["end"] >= boom["start"]
+        # the thread's stack was unwound: no path prefix, no parent
+        assert after["path"] == "after" and after["parent"] is None
+
+    def test_a_second_session_empties_the_store(
+            self, fresh_store, profiler_session):
+        from paddle_tpu.observability import spans
+        with profiler_session():
+            with spans.span("one"):
+                pass
+            with spans.span("two"):
+                pass
+        # kept after the session, for the readers ...
+        assert [r["name"] for r in spans.records()] == ["one", "two"]
+        with spans.span("between"):
+            pass
+        assert len(fresh_store) == 2
+        # ... and dropped when the next one begins
+        with profiler_session():
+            with spans.span("three"):
+                pass
+        assert [r["name"] for r in spans.records()] == ["three"]
+
+    def test_bounded_and_counts_what_it_dropped(
+            self, monkeypatch, profiler_session):
+        from paddle_tpu.observability import spans
+        store = spans.SpanStore(max_records=3)
+        monkeypatch.setattr(spans, "_STORE", store)
+        with profiler_session():
+            with spans.span("parent"):
+                for i in range(4):
+                    with spans.span(f"kid{i}"):
+                        pass
+        recs = spans.records()
+        assert [r["name"] for r in recs] == ["kid2", "kid3", "parent"]
+        assert store.dropped == 2 and len(store) == 3
+        # parents are ids, not positions: they survive the drop
+        assert recs[0]["parent"] == recs[2]["id"]
+
+    def test_self_segments_on_a_hand_made_tree(self):
+        from paddle_tpu.observability import spans
+
+        def rec(i, start, end, parent=None, path="p"):
+            return {"id": i, "name": "n", "path": path, "start": start,
+                    "end": end, "parent": parent, "rid": None,
+                    "counts": {}}
+        recs = [rec(2, 1.0, 3.0, parent=1),      # child
+                rec(4, 1.5, 2.0, parent=2),      # grandchild: not 1's
+                rec(3, 2.5, 6.0, parent=1),      # overlaps its sibling
+                rec(5, 9.0, 12.0, parent=1),     # runs past the parent
+                rec(6, 7.0, 7.0, parent=1, path=None),     # an event
+                rec(1, 0.0, 10.0)]
+        own = spans.self_segments(recs)
+        # children cover [1, 6] and [9, 10] of [0, 10]
+        assert own[1] == [(0.0, 1.0), (6.0, 9.0)]
+        assert own[2] == [(1.0, 1.5), (2.0, 3.0)]
+        assert own[3] == [(2.5, 6.0)]            # a leaf: all of it
+        assert own[4] == [(1.5, 2.0)]
+        assert 6 not in own
+
+
 class TestEventRecorder:
     def test_percentiles_and_reset(self):
         from paddle_tpu.profiler import EventRecorder

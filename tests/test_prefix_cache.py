@@ -111,6 +111,34 @@ class TestPrefixCacheUnit:
         assert freed == [0]
         assert pc.evictable() == 1 and len(pc) == 1
 
+    def test_evictable_count_follows_every_mutation(self):
+        # evictable() is a kept count (the engine reads it every round):
+        # hold it to a walk of the entries through acquire, release
+        # (also of an entry that is idle already), the trim, evict and
+        # clear
+        def walk(pc):
+            return sum(1 for e in pc._entries.values() if e.refs == 0)
+
+        pc = PrefixCache(page_size=2, max_idle_pages=3)
+        pc.insert([1, 2, 3, 4, 5, 6], row_pages=[0, 1, 2])
+        pc.insert([9, 8, 7, 6], row_pages=[3, 4])
+        assert pc.evictable() == walk(pc) == 0
+        pc.release([0, 1, 2])
+        assert pc.evictable() == walk(pc) == 3
+        pc.release([0])                   # idle already: counted once
+        assert pc.evictable() == walk(pc) == 3
+        pc.acquire([1])
+        pc.acquire([1])                   # two slots map it
+        assert pc.evictable() == walk(pc) == 2
+        pc.release([1])
+        assert pc.evictable() == walk(pc) == 2
+        pc.release([1, 3, 4])             # 5 idle: trimmed to 3
+        assert pc.evictable() == walk(pc) == 3
+        pc.evict(1)
+        assert pc.evictable() == walk(pc) == 2
+        pc.clear()
+        assert pc.evictable() == walk(pc) == 0
+
     def test_collision_verified_as_miss_never_corrupt(self, monkeypatch):
         pc = PrefixCache(page_size=2)
         pc.insert([1, 2], row_pages=[4])

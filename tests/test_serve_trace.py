@@ -243,3 +243,118 @@ class TestServeReport:
         for ev in ("submitted", "admitted", "preempted", "resumed",
                    "retired"):
             assert ev in line, (ev, line)
+
+
+class TestEngineSpans:
+    """ISSUE 25: the engine's spans under a real profiler session."""
+
+    PROMPTS = ((5, 4), (20, 3), (3, 5))      # (prompt length, max_new)
+
+    def _serve(self, model, v, cfg, after_step=None):
+        """Three requests, the second two prefill chunks long, through a
+        2-slot engine; returns (engine, {request id: tokens})."""
+        rng = np.random.RandomState(5)
+        eng = _engine(model, v, num_pages=12)
+        for L, mn in self.PROMPTS:
+            eng.submit(rng.randint(0, cfg.vocab_size, (L,))
+                       .astype(np.int32), max_new=mn)
+        steps = 0
+        while eng._queue or eng._running:
+            eng.step()
+            steps += 1
+            if after_step is not None:
+                after_step(eng)
+            assert steps < 100
+        return eng, {r: list(q.tokens) for r, q in eng.requests.items()}
+
+    def test_spans_events_and_counts(self, fresh_store, profiler_session):
+        from paddle_tpu.observability import spans
+        model, v, cfg = _tiny_decoder()
+
+        def hand_count(eng):
+            pinned = set()
+            for req in eng._running.values():
+                pinned.update(req.pages, req.shared_pages)
+            assert eng.pages_in_use() == len(pinned)
+            assert (eng.cfg.num_pages - len(eng._free_pages)
+                    == len(pinned) + eng.pages_cached())
+
+        # no session: nothing is kept, and the span state is one bounded
+        # histogram for each of the two spans; the round's phases have
+        # none
+        _, plain = self._serve(model, v, cfg)
+        assert spans.records() == []
+        paths = {n for n in M.registry().names() if n.startswith("span.")}
+        assert {"span.serve.step", "span.serve.submit"} <= paths
+        assert not [n for n in paths if n.startswith("span.serve.step/")]
+        for n in paths:
+            h = M.registry().get(n)
+            st = h.stats()
+            assert st is None or \
+                st["count"] - st["dropped"] <= h.max_samples
+        with profiler_session():
+            eng, traced = self._serve(model, v, cfg, after_step=hand_count)
+        assert traced == plain                   # token for token
+        assert {n for n in M.registry().names()
+                if n.startswith("span.")} == paths
+        # drained: nothing pinned, the 20-token prompt's two full pages
+        # stay with the prefix cache
+        assert eng.pages_in_use() == 0 and eng.pages_cached() == 2
+
+        recs = spans.records()
+        by_id = {r["id"]: r for r in recs}
+        kids = {}
+        for r in recs:
+            if r["path"] is not None:            # spans, not events
+                kids.setdefault(r["parent"], []).append(r)
+        for group in kids.values():
+            group.sort(key=lambda r: r["start"])
+        steps = [r for r in recs if r["name"] == "serve.step"]
+        decoded = 0
+        for s in steps:
+            names = [k["name"] for k in kids[s["id"]]]
+            assert names[:2] == ["serve.admit", "serve.grow"]
+            assert names[2:] in ([], ["serve.decode", "serve.fetch",
+                                      "serve.advance"])
+            decoded += len(names) == 5
+            c = s["counts"]
+            assert set(c) == {"pages_in_use", "pages_cached", "num_pages"}
+            assert c["num_pages"] == 12
+            assert 0 <= c["pages_in_use"] <= 12 - c["pages_cached"]
+        assert decoded >= 4
+        assert steps[-1]["counts"]["pages_in_use"] == 0
+        # a parent covers its children, at every level
+        for r in recs:
+            if r["path"] is None or r["id"] not in kids:
+                continue
+            inside = kids[r["id"]]
+            assert sum(k["end"] - k["start"] for k in inside) <= \
+                r["end"] - r["start"]
+            assert all(r["start"] <= k["start"] and k["end"] <= r["end"]
+                       for k in inside)
+        own = spans.self_segments(recs)
+        assert all(r["start"] <= a < b <= r["end"] for r in recs
+                   if r["path"] is not None for a, b in own[r["id"]])
+
+        # one request's spans and events share its id
+        prefills = {r["rid"]: r for r in recs if r["name"] == "serve.prefill"}
+        assert set(prefills) == {0, 1, 2}
+        assert by_id[prefills[1]["parent"]]["name"] == "serve.admit"
+        fetches = [r for r in recs if r["name"] == "serve.prefill.fetch"]
+        assert sorted(f["rid"] for f in fetches) == [0, 1, 1, 2]
+        assert all(by_id[f["parent"]]["name"] == "serve.prefill"
+                   for f in fetches)
+        assert {r["rid"] for r in recs if r["name"] == "serve.submit"} == \
+            {0, 1, 2}
+        for rid in (0, 1, 2):
+            ev = {r["name"]: r for r in recs
+                  if r["path"] is None and r["rid"] == rid}
+            assert list(ev) == ["submitted", "admitted", "prefill_done",
+                                "first_token", "retired"]
+            assert (ev["submitted"]["start"] <= ev["admitted"]["start"]
+                    <= ev["first_token"]["start"]
+                    <= ev["retired"]["start"])
+            # the lifecycle points lie inside the request's own spans
+            p = prefills[rid]
+            assert p["start"] <= ev["admitted"]["start"] <= p["end"]
+            assert ev["admitted"]["parent"] == p["id"]

@@ -70,9 +70,11 @@ def prewarm(engine, vocab, seed):
 
 
 def drive(engine, schedule, t0, t_open, t_close, drain_limit_s, log,
-          on_open=None, on_close=None, clock=time.perf_counter):
+          on_open=None, on_close=None, clock=time.perf_counter,
+          sleep=time.sleep):
     """The loop: submit what is due, step, note every new token's time.
     ``schedule`` holds Clients sorted by due time (relative to ``t0``).
+    ``clock`` and ``sleep`` are the tests' way in (a stepped clock).
     Returns the per-round records ``(start, end, running requests, new
     tokens, K/V pages that hold their context, requests admitted)``."""
     live, rounds = {}, []
@@ -111,8 +113,7 @@ def drive(engine, schedule, t0, t_open, t_close, drain_limit_s, log,
                 break
             nxt_due = t0 + schedule[nxt].due if nxt < n else t_close
             with annotate("bench.sleep"):
-                time.sleep(max(0.0, min(nxt_due - now, t_close - now,
-                                        0.002)))
+                sleep(max(0.0, min(nxt_due - now, t_close - now, 0.002)))
             continue
         t_step = clock()
         with annotate("bench.step"):
@@ -207,6 +208,20 @@ def pick_sample(done, seed, want_tokens, most):
     return sample
 
 
+def tokens_in_window(clients, t_open, t_close):
+    """Output tokens OF THE REQUESTS DUE IN THE WINDOW (``measured``, the
+    population of the two tails) that appeared in [t_open, t_close):
+    what ``serve_tokens_per_s`` counts. At a fixed open-loop rate it is
+    how much of what the window asked for was delivered inside it: its
+    ceiling is the demand, and serving every token no later than before
+    never lowers it. (Before PR 26 the metric counted every client's
+    tokens, the warm-up's too: the demand plus the warm-up's backlog
+    spilling in, minus the window's spilling out, so a faster server
+    read LOWER.)"""
+    return sum(1 for c in clients if c.measured
+               for t in c.token_times if t_open <= t < t_close)
+
+
 def window_facts(rounds, clients, cfg_shapes, page, lo, hi):
     """What the per-layer readers need, counted over the rounds that lie
     in [lo, hi]: decode rounds, the live K/V rows they read (in whole
@@ -293,19 +308,37 @@ def measure(engine, ctx, cell, seconds):
         [c.token_times[0] if c.token_times else None for c in measured],
         worst=t_end - t_open)
     gaps = stats.gaps_ms([c.token_times for c in measured])
-    tokens_in_window = sum(
-        sum(1 for t in c.token_times if t_open <= t < t_close)
-        for c in schedule)
+    own_tokens = tokens_in_window(schedule, t_open, t_close)
+    # the count before PR 26, logged so that older runs compare by eye
+    all_tokens = sum(1 for c in schedule for t in c.token_times
+                     if t_open <= t < t_close)
     late = [c.late for c in measured] or [0.0]
     lives = [c.token_times[-1] - (t0 + c.due) for c in done] or [0.0]
     log(f"rate {rate}/s, window {seconds:.1f} s after {warm} s of "
         f"warm-up: {len(measured)} requests due, {len(done)} finished, "
-        f"{tokens_in_window} tokens in the window, drain "
+        f"{own_tokens} tokens in the window of the requests due in it "
+        f"({all_tokens} of all clients, the warm-up's too: the count "
+        f"before PR 26, {stats.rate(all_tokens, seconds):.1f}/s), drain "
         f"{t_end - t_close:.2f} s; a request lives p50 "
         f"{stats.percentile(lives, 50):.1f} s, max {max(lives):.1f} s; "
         f"generator late by p50 {1e3 * stats.percentile(late, 50):.2f} ms, "
         f"max {1e3 * max(late):.2f} ms; programs built inside: "
         f"{built[1] - built[0]}")
+
+    # where a stall of the host sits, if the run held one (PERF.md
+    # section 6, PR 25 and PR 26): inside ``engine.step()`` or in this
+    # loop between two steps
+    inside = [r for r in rounds if t_open <= r[0] < t_close]
+    if len(inside) > 1:
+        longest = max(inside, key=lambda r: r[1] - r[0])
+        pause, at = max((b[0] - a[1], a[1])
+                        for a, b in zip(inside, inside[1:]))
+        log(f"longest engine step {1e3 * (longest[1] - longest[0]):.1f} ms "
+            f"({longest[5]} admitted in it), {longest[0] - t_open:.1f} s "
+            f"into the window, the median "
+            f"{1e3 * stats.percentile([r[1] - r[0] for r in inside], 50):.1f}"
+            f"; longest pause of the loop between two steps "
+            f"{1e3 * pause:.1f} ms, {at - t_open:.1f} s into the window")
 
     # the end-to-end metrics, over every request due in the window and
     # every gap between its tokens. Each tail is the highest percentile
@@ -313,7 +346,7 @@ def measure(engine, ctx, cell, seconds):
     # section 2); the other percentiles are logged and are no metrics
     def tail(xs, q):
         return stats.percentile(xs, q) if xs else math.inf
-    e2e = {"serve_tokens_per_s": stats.rate(tokens_in_window, seconds),
+    e2e = {"serve_tokens_per_s": stats.rate(own_tokens, seconds),
            "ttft_p90_ms": tail(ttft, 90), "gap_p90_ms": tail(gaps, 90)}
     gap_mean = sum(gaps) / len(gaps) if gaps else None
     log("; ".join(f"{k} {v:.1f}" for k, v in e2e.items())

@@ -145,33 +145,31 @@ class NoTemporary(Contract):
 class NoKvDequantTemporary(Contract):
     """int8-paged-KV serve contract: no wide-float tensor at paged-KV
     layout scale in the compiled module. The page pools are laid out
-    [..., page_size, head_dim]; with serve_kv_dtype=int8 the only
-    f32 KV values allowed are the kernel's per-page dequant tiles, so
-    any f32/bf16 tensor that (a) ends in head_dim, (b) carries
-    page_size on an earlier axis, and (c) holds >= ``min_rows`` x
-    (page_size x head_dim) elements is a dequantized pool or
-    pool-gather materialized outside the kernel — the exact temporary
-    int8 storage exists to avoid. ``min_rows`` sits above the kernel's
-    per-tile dequant (block_h rows) and below the smallest whole-pool
-    dequant, so the f32-pool engine is the positive control that trips
-    it."""
+    [num_pages, page_size, H*hd] (ops/attention.py); with
+    serve_kv_dtype=int8 the only f32 KV values allowed are the kernel's
+    per-page dequant tiles, so any f32/bf16 tensor that ends in
+    [page_size, H*hd] and holds >= ``min_pages`` such pages is a
+    dequantized pool or pool-gather materialized outside the kernel —
+    the exact temporary int8 storage exists to avoid. ``min_pages``
+    sits above the kernel's per-tile dequant (one page) and below the
+    smallest whole-pool dequant, so the f32-pool engine is the positive
+    control that trips it."""
 
-    def __init__(self, page_size, head_dim, min_rows,
+    def __init__(self, page_size, row_width, min_pages,
                  dtypes=("f32", "bf16")):
         self.page_size = int(page_size)
-        self.head_dim = int(head_dim)
-        self.min_rows = int(min_rows)
+        self.row_width = int(row_width)
+        self.min_pages = int(min_pages)
         self.dtypes = tuple(dtypes)
         self.name = (f"no-kv-dequant-temporary([...,{page_size},"
-                     f"{head_dim}], rows>={min_rows})")
+                     f"{row_width}], pages>={min_pages})")
 
     def temporaries(self, hlo_text):
         hits = set()
-        tile = self.page_size * self.head_dim
         for _, shp in hlo_shapes(hlo_text, self.dtypes):
-            if (len(shp) >= 3 and shp[-1] == self.head_dim
-                    and self.page_size in shp[:-1]
-                    and math.prod(shp) // tile >= self.min_rows):
+            if (len(shp) >= 3
+                    and shp[-2:] == (self.page_size, self.row_width)
+                    and math.prod(shp[:-2]) >= self.min_pages):
                 hits.add(shp)
         return sorted(hits)
 
@@ -566,13 +564,13 @@ def fused_mlp_contracts(inter=MLP_INTER, min_rows=MLP_MIN_ROWS):
 SERVE_TMAX = 48
 SERVE_MIN_ROWS = 8
 # the serve probe's paged-KV layout (tools/compile_smoke._serve_engine:
-# GPTConfig.tiny heads=4 x hd=16, page_size=8, 13 pages). KV_MIN_ROWS
-# sits above the kernel's per-tile dequant (block_h<=4 rows of ps x hd)
-# and below both the whole-pool dequant (13 x 4 = 52 rows) and the
-# dense gather (slots x Pmax x heads = 48 rows).
+# GPTConfig.tiny heads=4 x hd=16 = a 64-wide token row, page_size=8, 13
+# pages). KV_MIN_PAGES sits above the kernel's per-tile dequant (one
+# page) and below both the whole-pool dequant (13 pages) and the dense
+# gather (slots x Pmax = 12 pages).
 SERVE_PAGE_SIZE = 8
-SERVE_HEAD_DIM = 16
-SERVE_KV_MIN_ROWS = 24
+SERVE_KV_ROW = 64
+SERVE_KV_MIN_PAGES = 6
 
 
 def serve_decode_contracts(tmax=SERVE_TMAX, min_rows=SERVE_MIN_ROWS):
@@ -759,8 +757,8 @@ def serve_decode_int8_contracts():
     the no-f32-KV-temporary detector, with the byte budget re-derived
     from the int8 pool footprint."""
     return (serve_decode_contracts()
-            + [NoKvDequantTemporary(SERVE_PAGE_SIZE, SERVE_HEAD_DIM,
-                                    SERVE_KV_MIN_ROWS)]
+            + [NoKvDequantTemporary(SERVE_PAGE_SIZE, SERVE_KV_ROW,
+                                    SERVE_KV_MIN_PAGES)]
             + serve_budget_contracts(kv_dtype="int8"))
 
 

@@ -126,7 +126,8 @@ define_flag("use_pallas_decode", True,
             "falls back to the XLA gather-and-mask formulation.")
 define_flag("serve_page_size", 16,
             "Tokens per KV-cache page in the serving engine (multiples of "
-            "8; 128 fills a TPU lane tile exactly).")
+            "8: a page is page_size rows of the pool's H*hd-wide "
+            "token rows).")
 define_flag("serve_slots", 4,
             "Concurrent decode slots in the serving engine (the fixed "
             "batch dimension of the jitted serve step).")

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from paddle_tpu import nn
 from paddle_tpu.ops import activations as A
 from paddle_tpu.ops import loss as L
+from paddle_tpu.ops.attention import pool_dims
 
 
 @dataclasses.dataclass
@@ -285,7 +286,7 @@ class GPTDecoder(GPT):
         inactive slots); attention covers lengths+1 tokens.
         -> (logits [S, V], new_caches)."""
         s = tokens.shape[0]
-        num_pages, _, page_size, _ = caches[0]["k"].shape
+        num_pages, page_size = pool_dims(caches[0])
         write_pages = page_table[jnp.arange(s), lengths // page_size]
         write_pages = jnp.where(active, write_pages, num_pages)  # drop
         write_offsets = lengths % page_size
@@ -346,7 +347,7 @@ class GPTDecoder(GPT):
         chunk attention and return the FULL post-ln_f hidden states
         [B, Lp, H] plus the updated pools."""
         b, lp = prompt.shape
-        num_pages, _, page_size, _ = caches[0]["k"].shape
+        num_pages, page_size = pool_dims(caches[0])
         p_max = page_rows.shape[1]
         rel = jnp.arange(lp)
         pos = starts[:, None] + rel[None, :]                    # [B, Lp]

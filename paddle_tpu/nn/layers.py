@@ -623,7 +623,7 @@ class MultiHeadAttention(Module):
     def init_page_pool(self, num_pages, page_size, dtype=jnp.float32,
                        kv_dtype=None):
         """This layer's slice of the paged serving cache:
-        {"k","v"} [num_pages, H, page_size, hd] (plus per-row
+        {"k","v"} [num_pages, page_size, H*hd] (plus per-row
         {"k_scale","v_scale"} for kv_dtype=int8). Reads the embed dim
         from the declaration (ParamSpec), so it works outside apply() —
         the serving engine allocates pools before any forward runs."""
@@ -643,19 +643,32 @@ class MultiHeadAttention(Module):
         from paddle_tpu.ops.attention import (paged_decode_attention,
                                               paged_write)
         s, one, e = x_t.shape
-        hd = e // self.num_heads
-
-        def proj(n):
-            return self._project(x_t, n).reshape(s, self.num_heads, hd)
-
-        q = proj("q")
-        pool = paged_write(pool, proj("k"), proj("v"), write_pages,
-                           write_offsets)
+        q = self._project(x_t, "q").reshape(s, self.num_heads, -1)
+        pool = paged_write(pool, self._project(x_t, "k").reshape(s, e),
+                           self._project(x_t, "v").reshape(s, e),
+                           write_pages, write_offsets)
         ctx = paged_decode_attention(q, pool["k"], pool["v"], page_table,
                                      att_lengths,
                                      k_scale=pool.get("k_scale"),
                                      v_scale=pool.get("v_scale"))
         return self._project(ctx.reshape(s, 1, e), "o"), pool
+
+    def _paged_qkv_write(self, x, pool, page_ids, offsets):
+        """Project a [B, T, E] window, scatter its K/V token rows to
+        (page_ids, offsets) [B, T], and return (q, k, v [B, H, T, hd],
+        new_pool)."""
+        from paddle_tpu.ops.attention import paged_write
+        b, t, e = x.shape
+
+        def heads(y):
+            return y.reshape(b, t, self.num_heads, -1).transpose(0, 2, 1, 3)
+
+        k_rows, v_rows = self._project(x, "k"), self._project(x, "v")
+        pool = paged_write(
+            pool, k_rows.reshape(b * t, e), v_rows.reshape(b * t, e),
+            page_ids.reshape(b * t), offsets.reshape(b * t))
+        return (heads(self._project(x, "q")), heads(k_rows), heads(v_rows),
+                pool)
 
     def paged_prefill(self, x, pool, page_ids, offsets):
         """Batched prompt fill into pages: one causal forward over the
@@ -664,21 +677,8 @@ class MultiHeadAttention(Module):
         how pad positions are discarded). Returns (out [B, T, E],
         new_pool). Causal masking alone keeps pad-at-the-end garbage out
         of every valid position's context."""
-        from paddle_tpu.ops.attention import paged_write
         b, t, e = x.shape
-        hd = e // self.num_heads
-
-        def heads(y):
-            return y.reshape(b, t, self.num_heads, hd).transpose(0, 2, 1, 3)
-
-        q = heads(self._project(x, "q"))
-        k = heads(self._project(x, "k"))
-        v = heads(self._project(x, "v"))
-        pool = paged_write(
-            pool,
-            k.transpose(0, 2, 1, 3).reshape(b * t, self.num_heads, hd),
-            v.transpose(0, 2, 1, 3).reshape(b * t, self.num_heads, hd),
-            page_ids.reshape(b * t), offsets.reshape(b * t))
+        q, k, v, pool = self._paged_qkv_write(x, pool, page_ids, offsets)
         if self.use_flash:
             from paddle_tpu.ops.pallas.flash_attention import \
                 flash_attention
@@ -702,21 +702,10 @@ class MultiHeadAttention(Module):
         single-chunk admissions stay bit-exact with paged_prefill.
         Prefill is admission-rate work; the dense [T, Pmax*ps] score
         temporary never appears on the decode hot path."""
-        from paddle_tpu.ops.attention import NEG_INF, paged_write
+        from paddle_tpu.ops.attention import NEG_INF, gather_pages
         b, t, e = x.shape
         hd = e // self.num_heads
-
-        def heads(y):
-            return y.reshape(b, t, self.num_heads, hd).transpose(0, 2, 1, 3)
-
-        q = heads(self._project(x, "q"))
-        k = heads(self._project(x, "k"))
-        v = heads(self._project(x, "v"))
-        pool = paged_write(
-            pool,
-            k.transpose(0, 2, 1, 3).reshape(b * t, self.num_heads, hd),
-            v.transpose(0, 2, 1, 3).reshape(b * t, self.num_heads, hd),
-            page_ids.reshape(b * t), offsets.reshape(b * t))
+        q, k, v, pool = self._paged_qkv_write(x, pool, page_ids, offsets)
         if self.use_flash:
             from paddle_tpu.ops.pallas.flash_attention import \
                 flash_attention
@@ -728,16 +717,14 @@ class MultiHeadAttention(Module):
         # full-history path: pool pages were just updated with this
         # chunk, so the gather sees prefix + chunk at absolute positions
         # (int8 pools dequantize the gathered pages through the same
-        # per-row scales the decode kernel reads)
-        tk = page_rows.shape[1] * pool["k"].shape[2]
-        kg, vg = pool["k"][page_rows], pool["v"][page_rows]
-        if "k_scale" in pool:
-            from paddle_tpu.ops.attention import dequantize_pages
-            kg = dequantize_pages(kg, pool["k_scale"][page_rows])
-            vg = dequantize_pages(vg, pool["v_scale"][page_rows])
-        kf = jnp.moveaxis(kg, 2, 1).reshape(b, self.num_heads, tk, hd)
-        vf = jnp.moveaxis(vg, 2, 1).reshape(b, self.num_heads, tk, hd)
-        scores = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
+        # per-row scales the decode kernel reads); the pool is
+        # token-major, so the gathered pages are [B, Tk, H, hd] as they lie
+        kf = gather_pages(pool["k"], page_rows, self.num_heads,
+                          pool.get("k_scale"))
+        vf = gather_pages(pool["v"], page_rows, self.num_heads,
+                          pool.get("v_scale"))
+        tk = kf.shape[1]
+        scores = jnp.einsum("bhqd,bkhd->bhqk", q.astype(jnp.float32),
                             kf.astype(jnp.float32)) / (hd ** 0.5)
         keep = (jnp.arange(tk)[None, None, None, :]
                 <= q_pos[:, None, :, None])
@@ -745,7 +732,7 @@ class MultiHeadAttention(Module):
         m = jnp.max(scores, axis=-1, keepdims=True)
         p = jnp.where(keep, jnp.exp(scores - m), 0.0)
         l = jnp.sum(p, axis=-1, keepdims=True)
-        full = jnp.einsum("bhqk,bhkd->bhqd", p, vf.astype(jnp.float32))
+        full = jnp.einsum("bhqk,bkhd->bhqd", p, vf.astype(jnp.float32))
         full = jnp.where(l > 0, full / jnp.maximum(l, 1e-30), 0.0)
         ctx = jnp.where(chunked[:, None, None, None],
                         full.astype(ctx.dtype), ctx)

@@ -88,12 +88,26 @@ def _as_key_padding_mask(mask, batch, tk):
 # The per-request contiguous [B, H, Tmax, hd] decode cache streams the whole
 # padded buffer every generated token and welds requests into one fixed
 # lockstep batch. The paged layout replaces it with a slot/page-pool scheme:
-# one pool of fixed-size pages per layer ([N, H, page_size, hd]) plus a
-# per-slot page table ([slots, Pmax] int32) and token counts ([slots]
-# int32). Memory scales with tokens actually held, mixed-length requests
-# share one batch, and a finished request frees its pages without reshaping
-# anything — the jitted serve step's shapes never change across admissions
-# (paddle_tpu/serving/ owns the host-side allocator).
+# one pool of fixed-size pages per layer plus a per-slot page table
+# ([slots, Pmax] int32) and token counts ([slots] int32). Memory scales with
+# tokens actually held, mixed-length requests share one batch, and a
+# finished request frees its pages without reshaping anything — the jitted
+# serve step's shapes never change across admissions (paddle_tpu/serving/
+# owns the host-side allocator).
+#
+# THE pool layout is token-major and lane-dense: [num_pages, page_size,
+# H*hd], one row per cached token with its heads side by side. All three
+# users take it as it lies: paged_write scatters whole rows on the two
+# leading dims (in place on the donated buffer), the decode kernel's page
+# block is one contiguous (page_size, H*hd) tile, and the chunked prefill's
+# gather pool[page_rows] is a reshape away from [B, T, H, hd]. There is no
+# second layout. A head-major pool ([N, H, ps, hd]) cost three copies of
+# every whole pool in every serve step on the v5e — the compiler held the
+# parameter page-minor, relaid it token-major for the scatter, back for
+# the aliased output and once more row-major for the Mosaic call: 92% of
+# the device's busy time (PERF.md section 6, PR 27;
+# tests/test_mosaic_compile.py::test_pool_layout_no_relayout holds the
+# compiled step to none).
 #
 # Quantized pools (kv_dtype=int8) add {"k_scale","v_scale"} f32
 # [num_pages, page_size] beside the int8 value tensors: one symmetric
@@ -110,31 +124,52 @@ def quantized_pool(pool):
     return "k_scale" in pool
 
 
+def pool_dims(pool):
+    """(num_pages, page_size) of one layer's pool — the one place that
+    unpacks the pool's shape."""
+    num_pages, page_size, _ = pool["k"].shape
+    return num_pages, page_size
+
+
 def quantize_kv_rows(x):
-    """Symmetric per-token-row int8 quantization. x: [T, H, hd] ->
-    (q int8 [T, H, hd], scale f32 [T]) with scale = absmax/127. An
-    all-zero row stores scale 0 and dequantizes to exactly zero."""
-    absmax = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=(1, 2))
-    scale = absmax / 127.0
-    q = jnp.clip(jnp.round(x.astype(jnp.float32)
-                           / jnp.maximum(scale, 1e-30)[:, None, None]),
+    """Symmetric per-token-row int8 quantization. x: [T, ...] (a row is
+    everything behind the leading dim: H*hd, or [H, hd]) -> (q int8 of
+    x.shape, scale f32 [T]) with scale = absmax/127. An all-zero row
+    stores scale 0 and dequantizes to exactly zero."""
+    x = x.astype(jnp.float32)
+    row = (-1,) + (1,) * (x.ndim - 1)
+    scale = jnp.max(jnp.abs(x), axis=tuple(range(1, x.ndim))) / 127.0
+    q = jnp.clip(jnp.round(x / jnp.maximum(scale, 1e-30).reshape(row)),
                  -127.0, 127.0).astype(jnp.int8)
     return q, scale
 
 
 def dequantize_pages(pages, scales):
-    """Dequantize gathered pages. pages: [..., H, ps, hd] int8 with
+    """Dequantize gathered pages. pages: [..., ps, H*hd] int8 with
     leading gather dims; scales: [..., ps] f32 aligned on those dims.
     -> f32 of pages.shape."""
-    return pages.astype(jnp.float32) * scales[..., None, :, None]
+    return pages.astype(jnp.float32) * scales[..., None]
+
+
+def gather_pages(pages, page_table, num_heads, scales=None):
+    """Every table page of every row, densely: [B, Pmax*ps, H, hd], a
+    reshape of pages[page_table] (the pool is token-major). scales
+    ([N, ps]) dequantize an int8 pool to f32 on the gathered pages.
+    Admission-rate and fallback work; the decode hot path reads live
+    pages in the kernel."""
+    b, p_max = page_table.shape
+    g = pages[page_table]                       # [B, Pmax, ps, H*hd]
+    if scales is not None:
+        g = dequantize_pages(g, scales[page_table])
+    return g.reshape(b, p_max * pages.shape[1], num_heads, -1)
 
 
 def init_page_pool(num_pages, num_heads, page_size, head_dim,
                    dtype=jnp.float32, kv_dtype=None):
-    """One layer's KV page pool: {"k","v"} [num_pages, H, page_size, hd].
+    """One layer's KV page pool: {"k","v"} [num_pages, page_size, H*hd].
     kv_dtype=int8 adds {"k_scale","v_scale"} f32 [num_pages, page_size]
     (per-row symmetric scales) and stores values as int8."""
-    shape = (num_pages, num_heads, page_size, head_dim)
+    shape = (num_pages, page_size, num_heads * head_dim)
     if kv_dtype is None or jnp.dtype(kv_dtype) == jnp.dtype(dtype):
         return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
     if jnp.dtype(kv_dtype) != jnp.dtype(jnp.int8):
@@ -148,30 +183,23 @@ def init_page_pool(num_pages, num_heads, page_size, head_dim,
 
 
 def paged_write(pool, k_t, v_t, page_ids, offsets):
-    """Scatter per-token K/V into pool pages. k_t/v_t: [T, H, hd];
-    page_ids/offsets: [T] int32. An out-of-range page id DROPS the write
-    (mode="drop") — the engine routes inactive slots and pad positions to
-    page id == num_pages on purpose. On a quantized pool each row is
-    quantized on the way in and its scale written beside it."""
-    if quantized_pool(pool):
-        k_q, k_s = quantize_kv_rows(k_t)
-        v_q, v_s = quantize_kv_rows(v_t)
-        return {
-            "k": pool["k"].at[page_ids, :, offsets, :].set(
-                k_q, mode="drop"),
-            "v": pool["v"].at[page_ids, :, offsets, :].set(
-                v_q, mode="drop"),
-            "k_scale": pool["k_scale"].at[page_ids, offsets].set(
-                k_s, mode="drop"),
-            "v_scale": pool["v_scale"].at[page_ids, offsets].set(
-                v_s, mode="drop"),
-        }
-    return {
-        "k": pool["k"].at[page_ids, :, offsets, :].set(
-            k_t.astype(pool["k"].dtype), mode="drop"),
-        "v": pool["v"].at[page_ids, :, offsets, :].set(
-            v_t.astype(pool["v"].dtype), mode="drop"),
-    }
+    """Scatter per-token K/V rows into pool pages. k_t/v_t: [T, H, hd]
+    (or [T, H*hd]); page_ids/offsets: [T] int32 index the pool's two
+    leading dims, so each token is one contiguous H*hd row. An
+    out-of-range page id DROPS the write (mode="drop") — the engine
+    routes inactive slots and pad positions to page id == num_pages on
+    purpose. On a quantized pool each row is quantized on the way in and
+    its scale written beside it."""
+    new = {}
+    for name, x in (("k", k_t), ("v", v_t)):
+        x = x.reshape(x.shape[0], -1)
+        if quantized_pool(pool):
+            x, scale = quantize_kv_rows(x)
+            new[name + "_scale"] = pool[name + "_scale"].at[
+                page_ids, offsets].set(scale, mode="drop")
+        new[name] = pool[name].at[page_ids, offsets].set(
+            x.astype(pool[name].dtype), mode="drop")
+    return new
 
 
 def copy_pages(pool, src_ids, dst_ids):
@@ -196,27 +224,19 @@ def _paged_attention_xla(q, k_pages, v_pages, page_table, lengths, scale,
     holds no such temporary, with this path as the positive control).
     k_scale/v_scale [N, ps] dequantize int8 pools on the same gathered
     pages the kernel reads."""
-    s_slots, h, hd = q.shape
-    page_size = k_pages.shape[2]
-    p_max = page_table.shape[1]
-    t = p_max * page_size
-    kg = k_pages[page_table]                   # [S, Pmax, H, ps, hd]
-    vg = v_pages[page_table]
-    if k_scale is not None:
-        kg = dequantize_pages(kg, k_scale[page_table])
-        vg = dequantize_pages(vg, v_scale[page_table])
-    k = jnp.moveaxis(kg, 2, 1).reshape(s_slots, h, t, hd)
-    v = jnp.moveaxis(vg, 2, 1).reshape(s_slots, h, t, hd)
-    scores = jnp.einsum("shd,shtd->sht", q.astype(jnp.float32),
+    h = q.shape[1]
+    k = gather_pages(k_pages, page_table, h, k_scale)   # [S, T, H, hd]
+    v = gather_pages(v_pages, page_table, h, v_scale)
+    scores = jnp.einsum("shd,sthd->sht", q.astype(jnp.float32),
                         k.astype(jnp.float32)) * scale
-    valid = (jnp.arange(t)[None, :] < lengths[:, None])[:, None, :]
+    valid = (jnp.arange(k.shape[1])[None, :] < lengths[:, None])[:, None, :]
     scores = jnp.where(valid, scores, NEG_INF)
     m = jnp.max(scores, axis=-1, keepdims=True)
     # mask p, not just scores: a fully-masked slot (length 0) keeps m at
     # the NEG_INF sentinel where exp(s - m) would be 1
     p = jnp.where(valid, jnp.exp(scores - m), 0.0)
     l = jnp.sum(p, axis=-1, keepdims=True)
-    out = jnp.einsum("sht,shtd->shd", p, v.astype(jnp.float32))
+    out = jnp.einsum("sht,sthd->shd", p, v.astype(jnp.float32))
     out = jnp.where(l > 0, out / jnp.maximum(l, 1e-30), 0.0)
     return out.astype(q.dtype)
 
@@ -226,7 +246,7 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths,
                            scale=None, k_scale=None, v_scale=None):
     """Single-query attention over a paged KV cache (the serving decode
     read). q: [S, H, hd] — one query token per slot; k_pages/v_pages:
-    [N, H, page_size, hd]; page_table: [S, Pmax] int32 with IN-RANGE
+    [N, page_size, H*hd]; page_table: [S, Pmax] int32 with IN-RANGE
     entries everywhere (0 for unallocated); lengths: [S] int32 valid
     token counts (0 = inactive slot -> exactly-zero output).
     k_scale/v_scale: [N, page_size] f32 per-row scales when the pool is
@@ -242,16 +262,15 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths,
     from paddle_tpu.ops.pallas.core import kernel_mode
     scale = (float(scale) if scale is not None
              else 1.0 / (q.shape[-1] ** 0.5))
-    page_size = k_pages.shape[2]
+    page_size, width = k_pages.shape[1:]
     interpret = get_flag("pallas_interpret")
-    shape_ok = (page_size % 8 == 0
-                and (interpret or q.shape[-1] % 64 == 0))
+    shape_ok = page_size % 8 == 0 and (interpret or width % 128 == 0)
     mode = kernel_mode(
         "decode_attention", enable_flag="use_pallas_decode",
         unsupported=None if shape_ok else (
-            f"page_size={page_size} not a multiple of 8 or "
-            f"hd={q.shape[-1]} not a multiple of 64 "
-            "(supported: page_size%8==0, hd%64==0 on silicon)"))
+            f"page_size={page_size} not a multiple of 8 or a token row "
+            f"of H*hd={width} not a multiple of 128 "
+            "(supported: page_size%8==0, H*hd%128==0 on silicon)"))
     if mode is not None:
         from paddle_tpu.ops.pallas.decode_attention import (
             paged_decode_attention_tpu)

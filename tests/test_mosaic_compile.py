@@ -60,10 +60,11 @@ def as_tpu(monkeypatch):
     monkeypatch.setattr(core, "on_tpu", lambda: True)
 
 
-def _compile(one_chip, fn, *shapes):
+def _compile(one_chip, fn, *shapes, donate=()):
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
             for s, d in shapes]
-    return jax.jit(fn).lower(*args).compile().as_text()
+    return jax.jit(fn, donate_argnums=donate).lower(
+        *args).compile().as_text()
 
 
 def _kernels(hlo):
@@ -230,8 +231,8 @@ DECODE = [(dt, ps) for dt in ("f32", "bf16", "int8") for ps in (16, 64, 128)]
 @pytest.mark.parametrize("kv,page", DECODE,
                          ids=[f"{d}_page{p}" for d, p in DECODE])
 def test_paged_decode(one_chip, as_tpu, kv, page):
-    """8 slots x 12 heads of 64 over a 1024-token page table: the score
-    and value products need a real non-contracting lhs dim, and int8
+    """8 slots x 12 heads of 64 over a 1024-token page table: a page is
+    one lane-dense (page, 768) block of the token-major pool, and int8
     scales a block whose last two dims are legal."""
     from paddle_tpu.ops.attention import paged_decode_attention
     slots, p_max = 8, 1024 // page
@@ -239,8 +240,8 @@ def test_paged_decode(one_chip, as_tpu, kv, page):
     pool = {"f32": F32, "bf16": BF16, "int8": I8}[kv]
     q_dt = F32 if kv == "int8" else pool
     shapes = [((slots, HEADS, HD), q_dt),
-              ((n_pages, HEADS, page, HD), pool),
-              ((n_pages, HEADS, page, HD), pool),
+              ((n_pages, page, HEADS * HD), pool),
+              ((n_pages, page, HEADS * HD), pool),
               ((slots, p_max), I32), ((slots,), I32)]
     if kv == "int8":
         shapes += [((n_pages, page), F32)] * 2
@@ -251,6 +252,118 @@ def test_paged_decode(one_chip, as_tpu, kv, page):
 
     assert set(_kernels(_compile(one_chip, fn, *shapes))) == {
         "decode_attention"}
+
+
+# ------------------------------------- the K/V page pool is never relaid
+#
+# gpt2_medium.chat's shapes (benchmark/configs/gpt2_medium.json and the
+# cell's engine): bf16 pools of 1024 pages of 64 tokens, 16 heads of 64,
+# 64 slots, 16 pages a slot, prefill chunks of 128. One layer of each
+# serve program, pools donated as the engine donates them.
+
+POOL_PAGES, POOL_PAGE, POOL_HEADS, POOL_SLOTS, POOL_PMAX, CHUNK = (
+    1024, 64, 16, 64, 16, 128)
+POOL = (POOL_PAGES, POOL_PAGE, POOL_HEADS * HD)
+HEAD_MAJOR_POOL = (POOL_PAGES, POOL_HEADS, POOL_PAGE, HD)
+
+
+def _decode_layer(pk, pv, q, k_t, v_t, table, lengths, pages, offsets):
+    from paddle_tpu.ops.attention import (paged_decode_attention,
+                                          paged_write)
+    pool = paged_write({"k": pk, "v": pv}, k_t, v_t, pages, offsets)
+    return (paged_decode_attention(q, pool["k"], pool["v"], table,
+                                   lengths), pool["k"], pool["v"])
+
+
+def _prefill_layer(pk, pv, k_t, v_t, pages, offsets, rows):
+    from paddle_tpu.ops.attention import gather_pages, paged_write
+    pool = paged_write({"k": pk, "v": pv}, k_t, v_t, pages, offsets)
+    return (gather_pages(pool["k"], rows, POOL_HEADS),
+            gather_pages(pool["v"], rows, POOL_HEADS),
+            pool["k"], pool["v"])
+
+
+def _head_major_prefill_layer(pk, pv, k_t, v_t, pages, offsets, rows):
+    """The positive control: the pool as it was declared before PR 27,
+    [N, H, ps, hd], written by that tree's scatter."""
+    pk = pk.at[pages, :, offsets, :].set(k_t, mode="drop")
+    pv = pv.at[pages, :, offsets, :].set(v_t, mode="drop")
+    return pk[rows], pv[rows], pk, pv
+
+
+def _rows(n):
+    """K and V rows of n tokens, their page ids and offsets."""
+    return [((n, POOL_HEADS, HD), BF16)] * 2 + [((n,), I32)] * 2
+
+
+POOL_LAYERS = {
+    "decode": (_decode_layer, POOL,
+               [((POOL_SLOTS, POOL_HEADS, HD), F32),
+                ((POOL_SLOTS, POOL_HEADS, HD), F32),
+                ((POOL_SLOTS, POOL_HEADS, HD), F32),
+                ((POOL_SLOTS, POOL_PMAX), I32), ((POOL_SLOTS,), I32),
+                ((POOL_SLOTS,), I32), ((POOL_SLOTS,), I32)]),
+    "prefill": (_prefill_layer, POOL,
+                _rows(CHUNK) + [((1, POOL_PMAX), I32)]),
+    "head_major": (_head_major_prefill_layer, HEAD_MAJOR_POOL,
+                   _rows(CHUNK) + [((1, POOL_PMAX), I32)]),
+}
+
+
+def _pool_relayouts(hlo, pool_shape):
+    """(copies, writes, aliased): the instructions of the compiled
+    module that write a whole pool anew (a `copy`, a `transpose`, any
+    fusion but the page write), its in-place page writes (the scatter
+    fusions), and the parameters the module's outputs alias."""
+    shape = "bf16[" + ",".join(map(str, pool_shape)) + "]"
+    copies, writes = [], 0
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = " + re.escape(shape)
+                     + r"\{[^}]*\} (\w[\w-]*)\(", line)
+        if not m or m.group(1) in ("parameter", "scatter",
+                                   "get-tuple-element", "bitcast"):
+            continue
+        if m.group(1) == "fusion" and "/scatter" in line:
+            writes += 1
+            continue
+        copies.append(line.strip()[:120])
+    header = next(l for l in hlo.splitlines() if l.startswith("HloModule"))
+    aliased = {int(p) for p in re.findall(
+        r"\{\d+\}: \((\d+), \{\}, (?:may|must)-alias\)", header)}
+    return copies, writes, aliased
+
+
+def _compile_pool_layer(one_chip, name):
+    fn, pool_shape, rest = POOL_LAYERS[name]
+    hlo = _compile(one_chip, fn, *[(pool_shape, BF16)] * 2 + rest,
+                   donate=(0, 1))
+    return hlo, pool_shape
+
+
+@pytest.mark.parametrize("layer", ["decode", "prefill"])
+def test_pool_layout_no_relayout(one_chip, as_tpu, layer):
+    """The writer, the decode kernel and the prefill gather take the
+    pool as it lies: one layer of `_decode_jit` (write + kernel) and of
+    `_prefill_jit` (write + pool[page_rows] gather) holds no copy of a
+    pool, and the donated pools come back in place. The head-major pool
+    paid two such copies a pool in a prefill chunk and three in a decode
+    round: 92% of gpt2_medium.chat's device time (PERF.md, PR 27)."""
+    hlo, pool_shape = _compile_pool_layer(one_chip, layer)
+    copies, writes, aliased = _pool_relayouts(hlo, pool_shape)
+    assert not copies, copies
+    assert writes == 2 and aliased == {0, 1}
+    if layer == "decode":
+        assert set(_kernels(hlo)) == {"decode_attention"}
+
+
+def test_pool_layout_control_trips(one_chip):
+    """The detector sees what it is for: a head-major pool under the
+    scatter on dims 0 and 2 is relaid before the write and back after
+    it, for each of the two pools."""
+    hlo, pool_shape = _compile_pool_layer(one_chip, "head_major")
+    copies, writes, _ = _pool_relayouts(hlo, pool_shape)
+    assert writes == 2 and len(copies) >= 4, copies
+    assert all(" copy(" in c for c in copies)
 
 
 def test_tile_plan_fits_budget():

@@ -93,9 +93,9 @@ class TestDecodeParity:
         rng = _rs(1)
         s, h, hd, n_pages, page, pmax = 3, 2, 16, 6, 8, 4
         q = jnp.asarray(0.2 * rng.randn(s, h, hd).astype(np.float32))
-        kp = jnp.asarray(0.2 * rng.randn(n_pages, h, page, hd)
+        kp = jnp.asarray(0.2 * rng.randn(n_pages, page, h * hd)
                          .astype(np.float32))
-        vp = jnp.asarray(0.2 * rng.randn(n_pages, h, page, hd)
+        vp = jnp.asarray(0.2 * rng.randn(n_pages, page, h * hd)
                          .astype(np.float32))
         table = jnp.asarray(
             rng.randint(0, n_pages, (s, pmax)).astype(np.int32))
@@ -107,6 +107,49 @@ class TestDecodeParity:
         np.testing.assert_allclose(out, ref, atol=1e-5, rtol=1e-5)
         # inactive slot (length 0): exactly zero, not NaN/softmax-of-all
         assert float(jnp.abs(out[0]).max()) == 0.0
+
+    @pytest.mark.parametrize("page", [16, 64, 128])
+    @pytest.mark.parametrize("kv", ["f32", "bf16", "int8"])
+    def test_token_major_pool_vs_dense_gather(self, flags, kv, page):
+        """The kernel on THE pool layout ([N, ps, H*hd], written by
+        paged_write) against the gather-and-mask oracle: an empty slot,
+        a one-token slot, a slot whose table repeats page 0, a ragged
+        tail; int8 pools carry their per-row scales."""
+        from paddle_tpu.ops.attention import (
+            _paged_attention_xla, init_page_pool, paged_decode_attention,
+            paged_write)
+        rng = _rs(page)
+        s, h, hd, pmax = 4, 4, 16, 3
+        n_pages = s * pmax
+        dtype = {"f32": jnp.float32, "bf16": jnp.bfloat16,
+                 "int8": jnp.float32}[kv]
+        pool = init_page_pool(n_pages, h, page, hd, dtype,
+                              kv_dtype=jnp.int8 if kv == "int8" else None)
+        assert pool["k"].shape == (n_pages, page, h * hd)
+        rows = n_pages * page
+        pool = paged_write(
+            pool, jnp.asarray(rng.randn(rows, h, hd).astype(np.float32)),
+            jnp.asarray(rng.randn(rows, h, hd).astype(np.float32)),
+            jnp.repeat(jnp.arange(n_pages), page),
+            jnp.tile(jnp.arange(page), n_pages))
+        table = rng.permutation(n_pages).reshape(s, pmax).astype(np.int32)
+        table[2] = 0                               # page 0, three times
+        table = jnp.asarray(table)
+        lengths = jnp.asarray([0, 1, 3 * page, page + 3], jnp.int32)
+        q = jnp.asarray(rng.randn(s, h, hd).astype(np.float32))
+        scales = {n: pool[n] for n in ("k_scale", "v_scale") if n in pool}
+        flags({"pallas_interpret": True, "use_pallas_decode": True})
+        before = metrics.counter("pallas.fallback").snapshot().get(
+            "kernel=decode_attention", 0)
+        out = paged_decode_attention(q, pool["k"], pool["v"], table,
+                                     lengths, **scales)
+        assert metrics.counter("pallas.fallback").snapshot().get(
+            "kernel=decode_attention", 0) == before  # the kernel ran
+        ref = _paged_attention_xla(q, pool["k"], pool["v"], table,
+                                   lengths, 1.0 / hd ** 0.5, **scales)
+        np.testing.assert_allclose(out, ref, atol=2e-6, rtol=1e-5)
+        assert float(jnp.abs(out[0]).max()) == 0.0
+        assert float(jnp.abs(out[1:]).min(axis=(1, 2)).max()) > 0.0
 
 
 # --- fused (add+)layer norm -------------------------------------------
@@ -282,7 +325,7 @@ class TestRefusalProtocol:
         flags({"pallas_interpret": True, "use_pallas_decode": True})
         rng = _rs(7)
         q = jnp.asarray(rng.randn(1, 1, 16).astype(np.float32))
-        kp = jnp.asarray(rng.randn(2, 1, 6, 16).astype(np.float32))
+        kp = jnp.asarray(rng.randn(2, 6, 16).astype(np.float32))
         table = jnp.zeros((1, 2), jnp.int32)
         before = self._counter("decode_attention")
         out = paged_decode_attention(q, kp, kp, table,

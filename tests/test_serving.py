@@ -133,7 +133,7 @@ class TestPagedDecodeAttention:
         pool = paged_write(pool, vals, vals,
                            jnp.asarray([1, 4]),    # 4 == num_pages: drop
                            jnp.asarray([3, 0]))
-        assert float(jnp.abs(pool["k"][1, :, 3]).max()) > 0.0
+        assert float(jnp.abs(pool["k"][1, 3]).max()) > 0.0
         assert float(jnp.abs(pool["k"][0]).max()) == 0.0
         assert float(jnp.abs(pool["k"][2:]).max()) == 0.0
 

@@ -18,6 +18,7 @@ from paddle_tpu.nn.layers import (
     GRU,
     GroupNorm,
     GRUUnit,
+    GroupedQueryAttention,
     LSTM,
     LayerNorm,
     Linear,
@@ -35,6 +36,7 @@ from paddle_tpu.nn.layers import (
 )
 
 from paddle_tpu.nn.heads import MultiBoxHead
+from paddle_tpu.nn.mamba import MambaMixer
 from paddle_tpu.nn.scan import ScanLayers
 from paddle_tpu.nn.moe import MoE, top_k_gating
 from paddle_tpu.nn.rnn import (RNN, BeamSearchDecoder, Decoder, GRUCell,

@@ -34,6 +34,15 @@ def _int8_dot(x, q, scale, rhs_axis=0):
     return out * scale.astype(x.dtype)
 
 
+def matmul(x, w):
+    """x @ w with both operands in the WEIGHT's dtype and float32
+    accumulation: with bf16-stored weights one bf16 pass of the MXU and
+    a float32 result, with float32 weights the ordinary product. What a
+    layer whose precision is stated (bf16 operands, f32 accumulation)
+    multiplies with, whatever dtype its activations carry."""
+    return jnp.dot(x.astype(w.dtype), w, preferred_element_type=jnp.float32)
+
+
 class Linear(Module):
     """ref: dygraph/nn.py FC / Linear."""
 
@@ -738,6 +747,107 @@ class MultiHeadAttention(Module):
                         full.astype(ctx.dtype), ctx)
         out = ctx.transpose(0, 2, 1, 3).reshape(b, t, e)
         return self._project(out, "o"), pool
+
+
+class GroupedQueryAttention(Module):
+    """Causal self-attention whose keys and values have a head count of
+    their own: ``num_heads`` query heads of ``head_dim`` over
+    ``num_kv_heads`` K/V heads, each shared by num_heads / num_kv_heads
+    queries (1 = multi-query attention). No biases, no positional
+    encoding of any kind: an architecture that wants positions adds them
+    outside. The paged pool holds the K/V heads as they are,
+    ``[num_pages, page_size, num_kv_heads * head_dim]`` (ops/attention.py);
+    no path copies K/V per query head. Products are float32 (``matmul``
+    rounds the projections' operands to the weights' dtype)."""
+
+    def __init__(self, embed_dim, num_heads, num_kv_heads, head_dim=None,
+                 dtype=jnp.float32):
+        super().__init__()
+        head_dim = head_dim or embed_dim // num_heads
+        assert num_heads % num_kv_heads == 0, (num_heads, num_kv_heads)
+        self.num_heads, self.num_kv_heads = num_heads, num_kv_heads
+        self.head_dim = head_dim
+        self.param("wq", (embed_dim, num_heads * head_dim), I.xavier(), dtype)
+        self.param("wk", (embed_dim, num_kv_heads * head_dim), I.xavier(),
+                   dtype)
+        self.param("wv", (embed_dim, num_kv_heads * head_dim), I.xavier(),
+                   dtype)
+        self.param("wo", (num_heads * head_dim, embed_dim), I.xavier(), dtype)
+
+    def _attend(self, q, k, v, q_pos):
+        """q [B, T, H*hd]; k, v [B, Tk, KVH, hd] with key j at absolute
+        position j; q_pos [B, T]: query t sees keys <= q_pos[b, t].
+        -> [B, T, H*hd] float32."""
+        from paddle_tpu.ops.attention import NEG_INF
+        b, t, _ = q.shape
+        kvh, hd = self.num_kv_heads, self.head_dim
+        q = q.reshape(b, t, kvh, self.num_heads // kvh, hd)
+        scores = jnp.einsum("btkgd,bskd->bkgts", q.astype(jnp.float32),
+                            k.astype(jnp.float32)) / (hd ** 0.5)
+        keep = (jnp.arange(k.shape[1])[None, None, None, None, :]
+                <= q_pos[:, None, None, :, None])
+        scores = jnp.where(keep, scores, NEG_INF)
+        p = jax.nn.softmax(scores, axis=-1)
+        ctx = jnp.einsum("bkgts,bskd->btkgd", p, v.astype(jnp.float32))
+        return ctx.reshape(b, t, self.num_heads * hd)
+
+    def forward(self, x):
+        """Whole sequences, causal. x [B, T, E] -> [B, T, E] float32."""
+        b, t, _ = x.shape
+        shape = (b, t, self.num_kv_heads, self.head_dim)
+        ctx = self._attend(
+            matmul(x, self.p("wq")), matmul(x, self.p("wk")).reshape(shape),
+            matmul(x, self.p("wv")).reshape(shape),
+            jnp.broadcast_to(jnp.arange(t), (b, t)))
+        return matmul(ctx, self.p("wo"))
+
+    def init_page_pool(self, num_pages, page_size, dtype=jnp.float32,
+                       kv_dtype=None):
+        from paddle_tpu.ops.attention import init_page_pool
+        return init_page_pool(num_pages, self.num_kv_heads, page_size,
+                              self.head_dim, dtype, kv_dtype=kv_dtype)
+
+    def paged_decode_step(self, x_t, pool, page_table, att_lengths,
+                          write_pages, write_offsets):
+        """As MultiHeadAttention.paged_decode_step: x_t [S, 1, E], one
+        pending token a slot, its K/V row written first and then read
+        with the slot's live pages by the decode kernel."""
+        from paddle_tpu.ops.attention import (paged_decode_attention,
+                                              paged_write)
+        s = x_t.shape[0]
+        x_t = x_t.reshape(s, -1)
+        pool = paged_write(pool, matmul(x_t, self.p("wk")),
+                           matmul(x_t, self.p("wv")), write_pages,
+                           write_offsets)
+        q = matmul(x_t, self.p("wq")).reshape(s, self.num_heads, -1)
+        ctx = paged_decode_attention(q, pool["k"], pool["v"], page_table,
+                                     att_lengths,
+                                     k_scale=pool.get("k_scale"),
+                                     v_scale=pool.get("v_scale"))
+        return matmul(ctx.reshape(s, -1), self.p("wo"))[:, None], pool
+
+    def paged_prefill_chunk(self, x, pool, page_ids, offsets, page_rows,
+                            q_pos):
+        """A prompt chunk against the paged cache: its K/V rows are
+        scattered to (page_ids, offsets) [B, T] (an out-of-range page id
+        drops a pad position's write), then every query attends the
+        slot's whole table (page_rows [B, Pmax]) gathered densely, keys
+        masked by absolute position (q_pos [B, T]): a first chunk and a
+        continuation are one path, since what a chunk wrote is what it
+        reads back. With one K/V head the gather is [B, Pmax*ps, hd]:
+        admission-rate work. -> (out [B, T, E] float32, new pool)."""
+        from paddle_tpu.ops.attention import gather_pages, paged_write
+        b, t, _ = x.shape
+        pool = paged_write(
+            pool, matmul(x, self.p("wk")).reshape(b * t, -1),
+            matmul(x, self.p("wv")).reshape(b * t, -1),
+            page_ids.reshape(b * t), offsets.reshape(b * t))
+        kf = gather_pages(pool["k"], page_rows, self.num_kv_heads,
+                          pool.get("k_scale"))
+        vf = gather_pages(pool["v"], page_rows, self.num_kv_heads,
+                          pool.get("v_scale"))
+        ctx = self._attend(matmul(x, self.p("wq")), kf, vf, q_pos)
+        return matmul(ctx, self.p("wo")), pool
 
 
 class FC(Linear):

@@ -203,6 +203,12 @@ CATALOG = {
         "KV pages no admission could obtain: pinned by the running "
         "requests (private pages plus the prefix-cache pages they map); "
         "set once a scheduling round, whatever the pool's dtype."),
+    "serve.state_bytes_in_use": MetricSpec(
+        "gauge", (),
+        "Bytes of the per-slot recurrent state held by running requests "
+        "(state layers x running slots; 0 for a model without such "
+        "state); set once a scheduling round. The same number rides on "
+        "the serve.step span's counts beside state_bytes_reserved."),
     "serve.kv_quant_pages": MetricSpec(
         "gauge", (),
         "KV pages currently allocated out of an int8-quantized page "

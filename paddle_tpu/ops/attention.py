@@ -153,7 +153,8 @@ def dequantize_pages(pages, scales):
 
 def gather_pages(pages, page_table, num_heads, scales=None):
     """Every table page of every row, densely: [B, Pmax*ps, H, hd], a
-    reshape of pages[page_table] (the pool is token-major). scales
+    reshape of pages[page_table] (the pool is token-major; ``num_heads``
+    is the POOL's head count, the K/V heads of a grouped layer). scales
     ([N, ps]) dequantize an int8 pool to f32 on the gathered pages.
     Admission-rate and fallback work; the decode hot path reads live
     pages in the kernel."""
@@ -224,9 +225,14 @@ def _paged_attention_xla(q, k_pages, v_pages, page_table, lengths, scale,
     holds no such temporary, with this path as the positive control).
     k_scale/v_scale [N, ps] dequantize int8 pools on the same gathered
     pages the kernel reads."""
-    h = q.shape[1]
-    k = gather_pages(k_pages, page_table, h, k_scale)   # [S, T, H, hd]
-    v = gather_pages(v_pages, page_table, h, v_scale)
+    h, hd = q.shape[1:]
+    kvh = k_pages.shape[2] // hd
+    k = gather_pages(k_pages, page_table, kvh, k_scale)  # [S, T, KVH, hd]
+    v = gather_pages(v_pages, page_table, kvh, v_scale)
+    if kvh != h:
+        # grouped K/V heads: each is shared by H/KVH query heads (the
+        # oracle may repeat them; the kernel never does)
+        k, v = (jnp.repeat(x, h // kvh, axis=2) for x in (k, v))
     scores = jnp.einsum("shd,sthd->sht", q.astype(jnp.float32),
                         k.astype(jnp.float32)) * scale
     valid = (jnp.arange(k.shape[1])[None, :] < lengths[:, None])[:, None, :]
@@ -246,8 +252,9 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths,
                            scale=None, k_scale=None, v_scale=None):
     """Single-query attention over a paged KV cache (the serving decode
     read). q: [S, H, hd] — one query token per slot; k_pages/v_pages:
-    [N, page_size, H*hd]; page_table: [S, Pmax] int32 with IN-RANGE
-    entries everywhere (0 for unallocated); lengths: [S] int32 valid
+    [N, page_size, KVH*hd] (the pool's own head count KVH divides H: 1 is
+    multi-query attention, H the ordinary case); page_table: [S, Pmax]
+    int32 with IN-RANGE entries everywhere (0 for unallocated); lengths: [S] int32 valid
     token counts (0 = inactive slot -> exactly-zero output).
     k_scale/v_scale: [N, page_size] f32 per-row scales when the pool is
     int8 (init_page_pool(kv_dtype=int8)); both paths dequantize the same
@@ -271,6 +278,11 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths,
             f"page_size={page_size} not a multiple of 8 or a token row "
             f"of H*hd={width} not a multiple of 128 "
             "(supported: page_size%8==0, H*hd%128==0 on silicon)"))
+    heads, hd = q.shape[1:]
+    if width % hd or heads % (width // hd):
+        raise ValueError(
+            f"paged_decode_attention: a token row of {width} does not "
+            f"hold a divisor of {heads} heads of {hd}")
     if mode is not None:
         from paddle_tpu.ops.pallas.decode_attention import (
             paged_decode_attention_tpu)

@@ -72,7 +72,8 @@ def kernel_mode(kernel, *, enable_flag=None, unsupported=None,
 
 def kernel_call(kernel_fn, *, name, grid=None, grid_spec=None,
                 in_specs=None, out_specs=None, out_shape=None,
-                scratch_shapes=None, interpret=False):
+                scratch_shapes=None, input_output_aliases=None,
+                interpret=False):
     """The one ``pl.pallas_call`` site in the tree (graft-lint's
     ``raw-pallas-call`` rule rejects any other). Accepts either a plain
     ``grid`` + in/out specs or a prebuilt ``grid_spec`` (e.g. the
@@ -80,8 +81,13 @@ def kernel_call(kernel_fn, *, name, grid=None, grid_spec=None,
     own scratch shapes). ``name`` is the kernel's stable identity: it
     names the Mosaic kernel, so the compiled HLO's ``tpu_custom_call``
     and the device trace's events carry it (what chip_smoke.py's kernel
-    evidence and a trace reduction key on)."""
+    evidence and a trace reduction key on). ``input_output_aliases``
+    ({operand index, scalar-prefetch operands counted: output index})
+    lets a kernel update a donated buffer in place: only the blocks its
+    grid visits are rewritten."""
     kwargs = {"name": name}
+    if input_output_aliases:
+        kwargs["input_output_aliases"] = dict(input_output_aliases)
     if grid_spec is not None:
         kwargs["grid_spec"] = grid_spec
     else:

@@ -31,6 +31,15 @@ nothing beside the page's DMA. fp32 statistics and accumulation
 regardless of the pool dtype (bf16 pools re-read through f32 math — same
 contract as flash_attention).
 
+Grouped K/V heads (``num_kv_heads < num_heads``: a token row holds KVH
+heads of hd, each shared by H/KVH query heads; one K/V head is
+multi-query attention) take the same tile and the same two products: the
+queries arrive as [H, hd], are laid KVH times along the lanes and masked
+to their OWN K/V head's lanes, and the finalize folds the KVH lane
+groups into [H, hd]. No copy of K/V per query head exists anywhere; at
+``num_kv_heads == num_heads`` the kernel is the one described above,
+operation for operation.
+
 Int8 pools ride the same (m, l, acc) pipeline: the per-row scales
 ([N, page_size] beside the pool) come in as two extra gathered blocks —
 the aligned group of ``SCALE_ROWS`` pages that holds the live one — and
@@ -65,16 +74,20 @@ from paddle_tpu.ops.pallas.core import (kernel_call, softmax_finalize,
 SCALE_ROWS = 8
 
 
-def _head_lanes(num_heads, width):
-    """[H, H*hd] bool: lane e of row h belongs to head h."""
-    hd = width // num_heads
+def _head_lanes(num_heads, num_kv_heads, width):
+    """[H, KVH*hd] bool: lane e of row h belongs to query head h's K/V
+    head (head h itself where every head has its own)."""
+    hd = width // num_kv_heads
     lane = jax.lax.broadcasted_iota(jnp.int32, (num_heads, width), 1)
-    lo = jax.lax.broadcasted_iota(jnp.int32, (num_heads, width), 0) * hd
+    row = jax.lax.broadcasted_iota(jnp.int32, (num_heads, width), 0)
+    if num_kv_heads != num_heads:
+        row = row // (num_heads // num_kv_heads)
+    lo = row * hd
     return (lane >= lo) & (lane < lo + hd)
 
 
 def _decode_kernel(ptab_ref, lens_ref, q_ref, k_ref, v_ref, *refs,
-                   scale, page_size, num_heads, quantized):
+                   scale, page_size, num_heads, num_kv_heads, quantized):
     if quantized:
         ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = refs
     else:
@@ -84,6 +97,8 @@ def _decode_kernel(ptab_ref, lens_ref, q_ref, k_ref, v_ref, *refs,
     j = pl.program_id(1)
     nj = pl.num_programs(1)
     width = acc_scr.shape[1]
+    grouped = num_kv_heads != num_heads
+    own = functools.partial(_head_lanes, num_heads, num_kv_heads, width)
 
     @pl.when(j == 0)
     def _init():
@@ -94,9 +109,13 @@ def _decode_kernel(ptab_ref, lens_ref, q_ref, k_ref, v_ref, *refs,
     @pl.when(j * page_size < length)
     def _step():
         # the query row [1, H*hd] laid block-diagonally: row h keeps
-        # head h's lanes, so one NT matmul scores every head
-        q = jnp.where(_head_lanes(num_heads, width),
-                      q_ref[0].astype(jnp.float32), 0.0)       # [H, E]
+        # head h's lanes, so one NT matmul scores every head. Grouped:
+        # the queries come as [H, hd] and row h keeps its K/V head's
+        q = q_ref[0].astype(jnp.float32)
+        if grouped and num_kv_heads > 1:
+            q = jnp.concatenate([q] * num_kv_heads, axis=1)
+        if num_kv_heads > 1:
+            q = jnp.where(own(), q, 0.0)                       # [H, E]
         k = k_ref[0].astype(jnp.float32)                       # [ps, E]
         v = v_ref[0].astype(jnp.float32)
         sc = jax.lax.dot_general(
@@ -123,18 +142,26 @@ def _decode_kernel(ptab_ref, lens_ref, q_ref, k_ref, v_ref, *refs,
     @pl.when(j == nj - 1)
     def _finalize():
         out = softmax_finalize(l_scr[:], acc_scr[:], jnp.float32)
-        o_ref[0] = jnp.sum(
-            jnp.where(_head_lanes(num_heads, width), out, 0.0), axis=0,
-            keepdims=True).astype(o_ref.dtype)
+        if not grouped:
+            o_ref[0] = jnp.sum(jnp.where(own(), out, 0.0), axis=0,
+                               keepdims=True).astype(o_ref.dtype)
+            return
+        # [H, KVH*hd] -> [H, hd]: each row's own lane group
+        if num_kv_heads > 1:
+            out = jnp.where(own(), out, 0.0)
+        hd = width // num_kv_heads
+        o_ref[0] = sum(out[:, g * hd:(g + 1) * hd]
+                       for g in range(num_kv_heads)).astype(o_ref.dtype)
 
 
 def paged_decode_attention_tpu(q, k_pages, v_pages, page_table, lengths,
                                scale, k_scale=None, v_scale=None,
                                interpret=None):
-    """q [S, H, hd]; k_pages/v_pages [N, ps, H*hd]; page_table [S, Pmax]
-    int32 (in-range everywhere); lengths [S] int32; k_scale/v_scale
-    [N, ps] f32 per-row scales for int8 pools (None = unquantized pool).
-    -> [S, H, hd]."""
+    """q [S, H, hd]; k_pages/v_pages [N, ps, KVH*hd] (KVH = the row's
+    width over hd: the pool's own head count, a divisor of H);
+    page_table [S, Pmax] int32 (in-range everywhere); lengths [S] int32;
+    k_scale/v_scale [N, ps] f32 per-row scales for int8 pools (None =
+    unquantized pool). -> [S, H, hd]."""
     if interpret is None:
         from paddle_tpu.core.flags import get_flag
         interpret = get_flag("pallas_interpret")
@@ -142,15 +169,18 @@ def paged_decode_attention_tpu(q, k_pages, v_pages, page_table, lengths,
     s_slots, h, hd = q.shape
     page_size, width = k_pages.shape[1:]
     p_max = page_table.shape[1]
+    kvh = width // hd
     kernel = functools.partial(_decode_kernel, scale=scale,
                                page_size=page_size, num_heads=h,
-                               quantized=quantized)
-    # q/out ride as one lane-dense row per slot ([S, 1, H*hd])
-    q_spec = pl.BlockSpec((1, 1, width), lambda s, j, pt, ln: (s, 0, 0))
+                               num_kv_heads=kvh, quantized=quantized)
+    # q/out ride as one lane-dense row per slot ([S, 1, H*hd]); with
+    # grouped K/V heads as the slot's [H, hd] tile
+    q_block = (1, 1, width) if kvh == h else (1, h, hd)
+    q_spec = pl.BlockSpec(q_block, lambda s, j, pt, ln: (s, 0, 0))
     page_spec = pl.BlockSpec((1, page_size, width),
                              lambda s, j, pt, ln: (pt[s, j], 0, 0))
     in_specs = [q_spec, page_spec, page_spec]
-    operands = [q.reshape(s_slots, 1, width), k_pages, v_pages]
+    operands = [q.reshape(s_slots, *q_block[1:]), k_pages, v_pages]
     if quantized:
         scale_spec = pl.BlockSpec(
             (SCALE_ROWS, page_size),
@@ -172,7 +202,7 @@ def paged_decode_attention_tpu(q, k_pages, v_pages, page_table, lengths,
         kernel,
         name="decode_attention",
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s_slots, 1, width), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((s_slots, *q_block[1:]), q.dtype),
         interpret=interpret,
     )(page_table.astype(jnp.int32), lengths.astype(jnp.int32),
       *operands)

@@ -40,6 +40,16 @@ from paddle_tpu.ops.pallas.core import (INTERPRET, kernel_call, kernel_mode,
                                         pick_block_rows, tail_valid_cols,
                                         tail_zero, tile_spec)
 
+#: what a call's blocks may take of the compiler's scoped VMEM (16 MiB
+#: on a v5e): every operand block double-buffered, and the accumulator.
+#: Raising the limit instead is no way out: the 2560-wide prefill
+#: program hung on the chip with this kernel at 79 MiB (PERF.md
+#: section 6, PR 28)
+_BLOCKS_BUDGET = 15 * 2 ** 20
+#: rows x (x row + activation row + accumulator row) up to which all
+#: rows go into one row tile (pick_block_rows alone stops at 2 MiB)
+_ALL_ROWS_BUDGET = 3 * 2 ** 20
+
 _ACTS = {
     # exact erf gelu — must match ops/activations.py A.gelu for parity
     "gelu": lambda x: jax.nn.gelu(x, approximate=False),
@@ -169,20 +179,36 @@ def _mlp_pallas(x2, w1, b1, w2, b2, wg, bg, act, interpret=False,
         x2, *weights)
 
 
-def _default_mlp_blocks(x2, w1, w2, interpret):
+def _default_mlp_blocks(x2, w1, w2, interpret, has_gate=False):
     R, H = x2.shape
     I, Hout = w1.shape[1], w2.shape[1]
     # per row the kernel holds the x row, one activation row and the
     # accumulator row — budget the row tile for those three
     bn = pick_block_rows(R, H + Hout + 512, 4, copies=1)
+    if bn < R and R * (H + Hout + 512) * 4 <= _ALL_ROWS_BUDGET:
+        # a decode round's or a prefill chunk's rows in ONE row tile:
+        # a second tile reads every weight a second time
+        bn = R
     if not interpret and bn % 8:
         bn = max((bn // 8) * 8, min(R, 8))
     bi = legal_block(min(I, 512), I, interpret)
+    # wide layers (H 2560: three bf16 matrices in 512-wide tiles are
+    # 15.7 MB double-buffered): halve the intermediate tile until the
+    # blocks fit; the shapes that fitted keep their 512
+    bn_ = min(bn, R)
+
+    def blocks_bytes(bi):
+        return (2 * ((2 + has_gate) * H * bi * w1.dtype.itemsize
+                     + bn_ * (H + Hout) * x2.dtype.itemsize)
+                + bn_ * Hout * 4)
+    while not interpret and bi % 256 == 0 and \
+            blocks_bytes(bi) > _BLOCKS_BUDGET:
+        bi //= 2
     return bn, bi
 
 
 def _tuned_mlp_blocks(x2, w1, b1, w2, b2, wg, bg, act, interpret):
-    bn, bi = _default_mlp_blocks(x2, w1, w2, interpret)
+    bn, bi = _default_mlp_blocks(x2, w1, w2, interpret, wg is not None)
     from paddle_tpu.core.flags import get_flag
     if not get_flag("autotune"):
         return bn, bi

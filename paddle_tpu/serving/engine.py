@@ -1,5 +1,33 @@
 """Continuous-batching serving engine over the paged KV cache.
 
+The cache protocol (what a served model implements; GPTDecoder and
+HybridDecoder do, and nothing else here knows a model's insides). The
+engine holds a model's caches as TWO parts and donates them, as one
+pair ``(pools, state)``, to its two step programs, which hand the pair
+back:
+
+  * the page pools, ``model.init_paged_caches(num_pages, page_size,
+    dtype, kv_dtype)``: a list of K/V pools ``{"k", "v"} [num_pages,
+    page_size, KVH*hd]`` (ops/attention.py), one per attention layer.
+    They grow with the context: allocated by pages, duplicated by
+    ``copy_pages``, shared through the prefix cache.
+  * the per-slot state, ``model.init_slot_state(num_slots, dtype)``: a
+    pytree with one entry per slot in every leaf, fixed in size, for
+    layers that carry a recurrent state (models/hybrid.py). It belongs to
+    a slot, not to pages: no page operation sees it. A model without the
+    method has none (``()``) and runs as it always did.
+  * ``model.paged_prefill_chunk(prompt, starts, chunk_lengths, caches,
+    page_rows, write_floor)`` and ``model.paged_decode_step(tokens,
+    caches, page_table, lengths, active)`` -> (logits, new pools). A
+    model WITH state takes ``state=`` (and the chunk's ``slots=``) too
+    and returns (logits, new pools, new state): a chunk at ``starts ==
+    0`` begins its slot's state from zeros inside the program, so
+    admission, release, preemption and recovery need no device work for
+    it; padding and inactive slots leave it alone. What has not been
+    kept cannot be served: the prefix cache (a hit skips positions whose
+    state nobody holds) and speculative decoding (a rejected proposal
+    would have to roll the state back) are refused for such a model.
+
 Architecture (the three serving invariants):
 
   * ONE jitted decode step, fixed slot count, donated page pools — its
@@ -50,7 +78,7 @@ failed):
 
   * chunked prefill — prompts up to max_len are admitted as
     ceil(len / prefill_len) calls of the ONE prefill trace
-    (GPTDecoder.paged_prefill_chunk), page tables grown per chunk; the
+    (the model's paged_prefill_chunk), page tables grown per chunk; the
     long-prompt rejection class is gone (`serve_chunked_prefill` flag).
   * bounded admission — submit() takes optional deadline_s / priority;
     the `serve_queue_limit` flag bounds the queue, and over-limit or
@@ -258,11 +286,25 @@ class Request:
 
 
 class ServingEngine:
-    """submit()/step()/drain() continuous batching for a GPTDecoder."""
+    """submit()/step()/drain() continuous batching for any model that
+    implements the cache protocol (the module's docstring)."""
 
     def __init__(self, model, variables, config=None, clock=time.perf_counter):
-        self.cfg = (config or ServeConfig()).resolve()
+        config = config or ServeConfig()
+        # what the state is follows from the model
+        self._stateful = hasattr(model, "init_slot_state")
+        if self._stateful:
+            enforce(not config.prefix_cache,
+                    "prefix_cache=True cannot serve a model with a "
+                    "per-slot recurrent state: a prefix hit skips the "
+                    "prefill of positions whose state no page holds")
+            config = dataclasses.replace(config, prefix_cache=False)
+        self.cfg = config.resolve()
         cfg = self.cfg
+        enforce(not (self._stateful and cfg.draft),
+                "draft=True cannot serve a model with a per-slot "
+                "recurrent state: a rejected proposal would have to roll "
+                "the state back, and no snapshot of it is kept")
         self._model = model
         self._params = variables["params"]
         self.version = cfg.model_version
@@ -271,6 +313,10 @@ class ServingEngine:
         self._caches = model.init_paged_caches(
             cfg.num_pages, cfg.page_size, dtype=cfg.cache_dtype,
             kv_dtype=cfg.kv_dtype)
+        self._state = self._init_state()
+        self._state_bytes_per_slot = sum(
+            x.nbytes for x in jax.tree_util.tree_leaves(self._state)
+        ) // cfg.num_slots
         # speculative decoding: the draft model keeps its OWN page
         # pools, built with the SAME page count/size so one page table
         # indexes both (draft pages and target pages for a slot live at
@@ -374,7 +420,7 @@ class ServingEngine:
             "serve.prefix_hits", "serve.prefix_misses",
             "serve.cow_copies", "serve.pages_shared",
             "serve.kv_quant_pages", "serve.kv_pages_in_use",
-            "serve.spec_proposed",
+            "serve.state_bytes_in_use", "serve.spec_proposed",
             "serve.spec_accepted", "serve.spec_rollbacks",
             "jit.retraces"])
         self._retired = 0
@@ -434,6 +480,13 @@ class ServingEngine:
         self._sample = _sample
         self._build_jits()
 
+    def _init_state(self):
+        """The per-slot state, zeros (``()`` for a model without one)."""
+        if not self._stateful:
+            return ()
+        return self._model.init_slot_state(self.cfg.num_slots,
+                                           self.cfg.cache_dtype)
+
     def _resolve_draft(self):
         """(draft model, draft params). No draft_spec = self-draft (the
         target model drafts for itself — ~100% acceptance, the plumbing
@@ -474,29 +527,49 @@ class ServingEngine:
                 # compile smokes
                 _metrics.counter("jit.retraces").inc(fn=fn)
 
+        stateful = self._stateful
+
+        # both step programs take the model's caches as ONE donated
+        # argument, the pair (page pools, per-slot state), and hand the
+        # pair back
         def decode(params, caches, tokens, page_table, lengths, active,
                    temps, top_ks, top_ps, seeds, counts):
             _count_trace("decode_traces", "serve.decode")
+            pools, state = caches
 
             def run(tok):
-                logits, new_caches = model.paged_decode_step(
-                    tok, caches, page_table, lengths, active)
+                if stateful:
+                    logits, new_pools, new_state = model.paged_decode_step(
+                        tok, pools, page_table, lengths, active, state)
+                else:
+                    logits, new_pools = model.paged_decode_step(
+                        tok, pools, page_table, lengths, active)
+                    new_state = state
                 return _sample(logits, temps, top_ks, top_ps, seeds,
-                               counts), new_caches
+                               counts), (new_pools, new_state)
 
             return model.apply({"params": params, "state": {}}, tokens,
                                method=run)
 
         def prefill(params, caches, prompt, starts, lengths, page_rows,
-                    floors, temps, top_ks, top_ps, seeds, counts):
+                    floors, slots, temps, top_ks, top_ps, seeds, counts):
             _count_trace("prefill_traces", "serve.prefill")
+            pools, state = caches
 
             def run(pr):
-                logits, new_caches = model.paged_prefill_chunk(
-                    pr, starts, lengths, caches, page_rows,
-                    write_floor=floors)
+                if stateful:
+                    # no prefix cache with a state: nothing to floor
+                    logits, new_pools, new_state = \
+                        model.paged_prefill_chunk(
+                            pr, starts, lengths, pools, page_rows,
+                            state=state, slots=slots)
+                else:
+                    logits, new_pools = model.paged_prefill_chunk(
+                        pr, starts, lengths, pools, page_rows,
+                        write_floor=floors)
+                    new_state = state
                 return _sample(logits, temps, top_ks, top_ps, seeds,
-                               counts), new_caches
+                               counts), (new_pools, new_state)
 
             return model.apply({"params": params, "state": {}}, prompt,
                                method=run)
@@ -803,8 +876,9 @@ class ServingEngine:
                         spec = self._spec_round()
                     else:
                         with phase("serve.decode"):
-                            toks_dev, self._caches = self._decode_jit(
-                                self._params, self._caches,
+                            toks_dev, (self._caches, self._state) = \
+                                self._decode_jit(
+                                self._params, (self._caches, self._state),
                                 self._last_tokens, self._page_table,
                                 self._lengths, self._active, self._temps,
                                 self._top_ks, self._top_ps, self._seeds,
@@ -834,6 +908,13 @@ class ServingEngine:
             _metrics.gauge("serve.kv_pages_in_use").set(in_use)
             sp.count(pages_in_use=in_use, pages_cached=self.pages_cached(),
                      num_pages=self.cfg.num_pages)
+            if self._stateful:
+                # every running slot holds its recurrent state whole
+                state_bytes = len(self._running) * self._state_bytes_per_slot
+                _metrics.gauge("serve.state_bytes_in_use").set(state_bytes)
+                sp.count(state_slots=len(self._running),
+                         state_bytes=state_bytes,
+                         state_bytes_reserved=self.state_bytes())
             wall_s = self._clock() - t0
             if self._run_log is not None:
                 rec = {
@@ -968,7 +1049,7 @@ class ServingEngine:
         self._aot_trace = True    # a deliberate extra trace, not a retrace
         try:
             return self._decode_jit.lower(
-                self._params, self._caches,
+                self._params, (self._caches, self._state),
                 np.zeros(s, np.int32), self._page_table,
                 np.zeros(s, np.int32), np.zeros(s, bool),
                 np.zeros(s, np.float32), np.zeros(s, np.int32),
@@ -985,11 +1066,11 @@ class ServingEngine:
         self._aot_trace = True    # a deliberate extra trace, not a retrace
         try:
             return self._prefill_jit.lower(
-                self._params, self._caches,
+                self._params, (self._caches, self._state),
                 np.zeros((1, cfg.prefill_len), np.int32),
                 np.zeros(1, np.int32), np.zeros(1, np.int32),
                 self._page_table[:1], np.zeros(1, np.int32),
-                np.zeros(1, np.float32), np.zeros(1, np.int32),
+                np.zeros(1, np.int32), np.zeros(1, np.float32), np.zeros(1, np.int32),
                 np.zeros(1, np.float32), np.zeros(1, np.uint32),
                 np.zeros(1, np.int32)).compile()
         finally:
@@ -1045,6 +1126,10 @@ class ServingEngine:
         host scheduler only rewrites the tiny page_table/lengths/active
         inputs between steps)."""
         from paddle_tpu.io.inference import save_train_program
+        enforce(not self._stateful,
+                "export_decode() exports (params, page pools) as the fed-"
+                "back state; a model with a per-slot recurrent state is "
+                "not exported yet")
         model = self._model
         cfg = self.cfg
 
@@ -1078,6 +1163,11 @@ class ServingEngine:
         shape/dtype metadata only, never a device sync."""
         return int(sum(arr.nbytes for pool in list(self._caches)
                        for arr in pool.values()))
+
+    def state_bytes(self):
+        """Device bytes reserved for the per-slot recurrent state of all
+        slots (0 for a model without one): metadata only, no sync."""
+        return self._state_bytes_per_slot * self.cfg.num_slots
 
     def goodput(self):
         """Fraction of retired requests that met every configured SLO
@@ -1434,10 +1524,12 @@ class ServingEngine:
             floors = np.asarray([matched], np.int32)
             try:
                 fault_point("serve.prefill")
-                tok_dev, self._caches = self._prefill_jit(
-                    self._params, self._caches, req.device_prompt[ci],
-                    starts, lens, self._page_table[slot][None, :],
-                    floors, *self._sampling_rows(req))
+                tok_dev, (self._caches, self._state) = self._prefill_jit(
+                    self._params, (self._caches, self._state),
+                    req.device_prompt[ci], starts, lens,
+                    self._page_table[slot][None, :], floors,
+                    np.asarray([slot], np.int32),
+                    *self._sampling_rows(req))
                 if self._spec_on:
                     # mirror the chunk into the draft pools (same pages,
                     # same write floor — shared prefix pages keep their
@@ -1641,6 +1733,9 @@ class ServingEngine:
         self._caches = self._model.init_paged_caches(
             cfg.num_pages, cfg.page_size, dtype=cfg.cache_dtype,
             kv_dtype=cfg.kv_dtype)
+        # the per-slot state was donated with them; the replay's first
+        # chunk starts every slot from zeros anyway
+        self._state = self._init_state()
         if self._spec_on:
             # the draft pools were donated to the same failed round
             self._draft_caches = self._draft_model.init_paged_caches(

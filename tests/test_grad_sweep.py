@@ -172,6 +172,13 @@ NON_DIFF = {
                               "cache — inference-only (no training path "
                               "holds a page pool); parity vs the dense "
                               "oracle in tests/test_serving.py",
+    "causal_conv1d": "serving op over the per-slot conv window of a "
+                     "state-space layer — inference-only (no training "
+                     "path carries a slot state); parity vs the plain "
+                     "reference in tests/test_hybrid_model.py",
+    "selective_scan": "serving op over the per-slot recurrent state — "
+                      "inference-only, forward Pallas kernel; parity vs "
+                      "the plain recurrence in tests/test_selective_scan.py",
     "ssd_loss": COMPOSITE,  # drives checked primitives + discrete matching
     "data_norm": COMPOSITE,
     "batch_norm": "stateful (running stats); grad covered in "

@@ -366,6 +366,78 @@ def test_pool_layout_control_trips(one_chip):
     assert all(" copy(" in c for c in copies)
 
 
+# ------------------------------------------ the hybrid decoder's kernels
+#
+# jamba2_3b.chat_1k's shapes (benchmark/configs/jamba2_3b.json): d_inner
+# 5120, 16 states, 128 slots, prefill chunks of 128; 20 query heads of
+# 128 over ONE K/V head in pools of 4096 pages of 64; a gated MLP of
+# 2560 x 8192 in bf16.
+
+SCAN_D, SCAN_N, SCAN_SLOTS = 5120, 16, 128
+
+
+@pytest.mark.parametrize("batch,t,name", [
+    (1, 128, "selective_scan"), (SCAN_SLOTS, 1, "ssm_state_update")])
+def test_selective_scan_updates_the_state_in_place(one_chip, as_tpu, batch,
+                                                   t, name):
+    """Both lengths of the scan kernel compile, the donated state is the
+    output's buffer, and nothing of the state's shape is copied."""
+    from paddle_tpu.ops.mamba import selective_scan
+
+    def fn(state, x, dt, b, c, z, a_log, d, slots, lengths):
+        y, new = selective_scan(x, dt, b, c, z, a_log, d, state, slots,
+                                lengths, lengths > 0, name=name)
+        return new, y
+
+    seq = ((batch, t, SCAN_D), F32)
+    bc = ((batch, t, SCAN_N), F32)
+    state = (SCAN_SLOTS, SCAN_N, SCAN_D)
+    hlo = _compile(one_chip, fn, (state, F32), seq, seq, bc, bc, seq,
+                   ((SCAN_D, SCAN_N), BF16), ((SCAN_D,), BF16),
+                   ((batch,), I32), ((batch,), I32), donate=(0,))
+    assert set(_kernels(hlo)) == {name}
+    copies, _, aliased = _pool_relayouts(hlo, state)
+    header = next(l for l in hlo.splitlines() if l.startswith("HloModule"))
+    assert "{0}: (0, {}, may-alias)" in header or 0 in aliased, header[:300]
+    shape = "f32[" + ",".join(map(str, state)) + "]"
+    assert not [l for l in hlo.splitlines()
+                if re.search(re.escape(shape) + r"\{[^}]*\} (copy|transpose)\(",
+                             l)]
+
+
+def test_paged_decode_with_one_kv_head(one_chip, as_tpu):
+    from paddle_tpu.ops.attention import paged_decode_attention
+    hlo = _compile(
+        one_chip, paged_decode_attention, ((128, 20, 128), F32),
+        ((4096, 64, 128), BF16), ((4096, 64, 128), BF16),
+        ((128, 32), I32), ((128,), I32))
+    assert set(_kernels(hlo)) == {"decode_attention"}
+
+
+def test_fused_mlp_wide_gated_fits_the_default_vmem(one_chip, as_tpu):
+    """2560 x 8192 with a gate: 512-wide tiles of three bf16 matrices
+    pass the 16 MiB scoped VMEM limit, so the intermediate tile is
+    halved until the blocks fit (a RAISED limit compiled too, and the
+    28-layer prefill program then hung on the chip: PERF.md section 6,
+    PR 28); the accepted cells' shapes keep their tiles
+    (test_fused_mlp_forward)."""
+    from paddle_tpu.ops.pallas import mlp
+
+    def fn(x, w1, w2, wg):
+        return mlp.fused_mlp(x, w1, None, w2, None, wg=wg, act="silu")
+    shapes = [((128, 2560), F32), ((2560, 8192), BF16),
+              ((8192, 2560), BF16), ((2560, 8192), BF16)]
+    hlo = _compile(one_chip, fn, *shapes)
+    assert 0 < _kernels(hlo)["mlp"] <= 16 * 2 ** 20
+    x, w1, w2, _ = (jax.ShapeDtypeStruct(s, d) for s, d in shapes)
+    assert mlp._default_mlp_blocks(x, w1, w2, False, True) == (128, 256)
+    # gpt2_medium's and bert_large's layers: as before
+    for rows, dt in ((64, F32), (128, F32), (8192, BF16)):
+        x, w1, w2 = (jax.ShapeDtypeStruct(s, dt) for s in (
+            (rows, 1024), (1024, 4096), (4096, 1024)))
+        assert mlp._default_mlp_blocks(x, w1, w2, False)[1] == 512
+
+
 def test_tile_plan_fits_budget():
     """pick_rv_blocks is the plan the compiles above check: at every
     width it is asked for, what it plans fits its own budget."""

@@ -488,8 +488,9 @@ def serve_smoke(positive_control=True, update_snapshots=False):
             wide = np.concatenate(
                 [engine._page_table,
                  np.zeros((s, 1), engine._page_table.dtype)], axis=1)
-            _, engine._caches = engine._decode_jit(
-                engine._params, engine._caches, np.zeros(s, np.int32),
+            _, (engine._caches, engine._state) = engine._decode_jit(
+                engine._params, (engine._caches, engine._state),
+                np.zeros(s, np.int32),
                 wide, np.zeros(s, np.int32), np.zeros(s, bool),
                 np.zeros(s, np.float32), np.zeros(s, np.int32),
                 np.zeros(s, np.float32), np.zeros(s, np.uint32),

@@ -442,40 +442,59 @@ class ServingEngine:
             """Per-request masked sampling, one trace for every mix of
             greedy / temperature / top-k / top-p rows. logits [B, V];
             the knobs are traced [B] VALUES (batch-size-shaped, so
-            admissions never retrace). Row b's key is
-            fold(fold(base, seeds[b]), counts[b]) — counts[b] is how
-            many tokens request b has generated, so token i of a
-            request always draws with the same key, making sampled
-            replay (preemption / recovery / re-route) deterministic.
-            temperature == 0 rows take jnp.argmax, bit-exact with the
-            pre-sampling greedy path."""
+            admissions never retrace). temperature == 0 rows take
+            jnp.argmax, bit-exact with the pre-sampling greedy path.
+
+            The work follows the rows, inside the one program: a
+            lax.cond on the traced value any(temps > 0).
+              * no row samples (released slots are reset to zeroed
+                knobs, so an all-greedy round reads all zeros): the
+                argmax is the answer; no sort of the vocabulary, no
+                softmax, cumsum, key or draw runs.
+              * some row samples: the law below for EVERY row, then
+                the greedy rows take their argmax back, so a mixed
+                round answers bit for bit as the straight-line law.
+                Row b's key is fold(fold(base, seeds[b]), counts[b])
+                (counts[b] is how many tokens request b has generated),
+                so token i of a request always draws with the same
+                key, making sampled replay (preemption / recovery /
+                re-route) deterministic."""
             greedy = jnp.argmax(logits, -1).astype(jnp.int32)
-            v = logits.shape[-1]
-            scaled = (logits.astype(jnp.float32)
-                      / jnp.maximum(temps, 1e-6)[:, None])
-            desc = -jnp.sort(-scaled, axis=-1)              # descending
-            k_eff = jnp.where(top_ks > 0,
-                              jnp.minimum(top_ks, v), v).astype(jnp.int32)
-            kth = jnp.take_along_axis(desc, (k_eff - 1)[:, None], axis=1)
-            probs = jax.nn.softmax(desc, axis=-1)
-            cum = jnp.cumsum(probs, axis=-1)
-            p_eff = jnp.where((top_ps > 0.0) & (top_ps < 1.0),
-                              top_ps.astype(jnp.float32), 1.0)
-            # smallest set of top rows whose mass reaches p (the nucleus
-            # always keeps at least the argmax row)
-            n_keep = jnp.maximum(
-                jnp.sum((cum - probs) < p_eff[:, None], axis=-1), 1)
-            pth = jnp.take_along_axis(desc, (n_keep - 1)[:, None], axis=1)
-            masked = jnp.where((scaled >= kth) & (scaled >= pth),
-                               scaled, -1e30)
 
-            def row_key(s, c):
-                return jax.random.fold_in(
-                    jax.random.fold_in(base_key, s), c)
+            def sampled():
+                v = logits.shape[-1]
+                scaled = (logits.astype(jnp.float32)
+                          / jnp.maximum(temps, 1e-6)[:, None])
+                desc = -jnp.sort(-scaled, axis=-1)          # descending
+                k_eff = jnp.where(top_ks > 0,
+                                  jnp.minimum(top_ks, v),
+                                  v).astype(jnp.int32)
+                kth = jnp.take_along_axis(desc, (k_eff - 1)[:, None],
+                                          axis=1)
+                probs = jax.nn.softmax(desc, axis=-1)
+                cum = jnp.cumsum(probs, axis=-1)
+                p_eff = jnp.where((top_ps > 0.0) & (top_ps < 1.0),
+                                  top_ps.astype(jnp.float32), 1.0)
+                # smallest set of top rows whose mass reaches p (the
+                # nucleus always keeps at least the argmax row)
+                n_keep = jnp.maximum(
+                    jnp.sum((cum - probs) < p_eff[:, None], axis=-1), 1)
+                pth = jnp.take_along_axis(desc, (n_keep - 1)[:, None],
+                                          axis=1)
+                masked = jnp.where((scaled >= kth) & (scaled >= pth),
+                                   scaled, -1e30)
 
-            keys = jax.vmap(row_key)(seeds, counts)
-            drawn = jax.vmap(jax.random.categorical)(keys, masked)
-            return jnp.where(temps > 0.0, drawn.astype(jnp.int32), greedy)
+                def row_key(s, c):
+                    return jax.random.fold_in(
+                        jax.random.fold_in(base_key, s), c)
+
+                keys = jax.vmap(row_key)(seeds, counts)
+                drawn = jax.vmap(jax.random.categorical)(keys, masked)
+                return jnp.where(temps > 0.0, drawn.astype(jnp.int32),
+                                 greedy)
+
+            return jax.lax.cond(jnp.any(temps > 0.0), sampled,
+                                lambda: greedy)
 
         self._sample = _sample
         self._build_jits()
@@ -887,9 +906,15 @@ class ServingEngine:
                             toks = np.asarray(toks_dev)  # graft-lint: disable=hot-path-sync (the one deliberate sync per decode round: the python scheduler needs this step's tokens to advance/free slots)
                 except Exception as e:
                     self._recover("serve.step", e)
+            sampled_rows = 0
             if spec is not None or toks is not None:
                 self._retry_budget.success()   # consecutive-failure reset
                 self.target_steps += 1
+                # which branch of _sample the round took (0 = the argmax
+                # alone), from the host's own copy of the knobs, read
+                # before _advance releases slots: a released slot's
+                # temperature is 0, so the rows over 0 are running requests
+                sampled_rows = int(np.count_nonzero(self._temps > 0.0))
                 with phase("serve.advance"):
                     if spec is not None:
                         new_tokens, spec_proposed, spec_accepted = \
@@ -907,7 +932,7 @@ class ServingEngine:
             in_use = self.pages_in_use()
             _metrics.gauge("serve.kv_pages_in_use").set(in_use)
             sp.count(pages_in_use=in_use, pages_cached=self.pages_cached(),
-                     num_pages=self.cfg.num_pages)
+                     num_pages=self.cfg.num_pages, sampled_rows=sampled_rows)
             if self._stateful:
                 # every running slot holds its recurrent state whole
                 state_bytes = len(self._running) * self._state_bytes_per_slot

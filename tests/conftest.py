@@ -74,6 +74,21 @@ def fresh_store(monkeypatch):
 
 
 @pytest.fixture
+def new_step_counts(fresh_store):
+    """``read(key)``: that count of every ``serve.step`` span recorded
+    since the last call (spans are recorded under a profiler session)."""
+    seen = 0
+
+    def read(key):
+        nonlocal seen
+        steps = [r["counts"][key] for r in fresh_store.records()
+                 if r["name"] == "serve.step"]
+        new, seen = steps[seen:], len(steps)
+        return new
+    return read
+
+
+@pytest.fixture
 def profiler_session(tmp_path):
     """A context manager that holds a real profiler session open."""
     import contextlib

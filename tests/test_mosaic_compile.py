@@ -459,3 +459,30 @@ def test_tile_plan_fits_budget():
         == 1024
     assert pick_rv_blocks(8176, 32000, 768, 2, "rows", 3, "rows")[0] == 512
     assert np.prod(pick_rv_blocks(19, 133, 48, 4)) == 19 * 133
+
+
+# ------------------------------------------------------------ the sampler
+
+@pytest.mark.parametrize("rows,vocab", [(64, 50257), (128, 65536)])
+def test_sampler_keeps_its_conditional_on_the_chip(one_chip, rows, vocab):
+    """The chip's compiler leaves the sampler's lax.cond a conditional
+    (it does not flatten it into a select that runs both sides): at the
+    serving cells' shapes the sort of the vocabulary stands in a branch
+    computation and not in the entry computation, so a round whose rows
+    are all greedy does not run it (PERF.md section 6, PR 29)."""
+    from paddle_tpu.models.gpt import GPTConfig, GPTDecoder
+    from paddle_tpu.serving import ServeConfig, ServingEngine
+    cfg = GPTConfig.tiny()
+    model = GPTDecoder(cfg)
+    eng = ServingEngine(model, model.init(jax.random.key(0)), ServeConfig(
+        num_slots=2, page_size=8, max_len=24, prefill_len=8, num_pages=6,
+        metrics_port=0))
+    hlo = _compile(one_chip, eng._sample, ((rows, vocab), F32),
+                   ((rows,), F32), ((rows,), I32), ((rows,), F32),
+                   ((rows,), jnp.uint32), ((rows,), I32))
+    eng.close()
+    entry = hlo[hlo.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    assert " conditional(" in entry and 'op_name="jit(_sample)/cond"' in entry
+    assert " sort(" in hlo and " sort(" not in entry
+    assert "rng-bit-generator" not in entry and "cumsum" not in entry

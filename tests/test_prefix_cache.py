@@ -7,6 +7,8 @@ per-request sampling stays deterministic and traced-once through it
 all. The oracle everywhere is the uncached path: per-request
 generate() for greedy, a cache-off engine for seeded sampling."""
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -354,24 +356,44 @@ class TestEnginePrefixCache:
                                           _reference(model, v, p, 6))
         eng.close()
 
-    def test_sampling_mixed_batch_single_trace(self):
+    def test_sampling_mixed_batch_single_trace(self, new_step_counts,
+                                               profiler_session):
         """Greedy, temperature, top-k and top-p rows in ONE running
-        batch: a single decode trace, greedy rows bit-exact with
-        generate()."""
+        batch, between an all-greedy wave before it and one after it
+        (the sampler's short branch, its long one, its short one
+        again, as the ``serve.step`` span's ``sampled_rows`` tells): a
+        single decode trace and a single prefill trace through every
+        change of mix, greedy rows bit-exact with generate()."""
         model, v, cfg = _tiny_decoder()
         rng = np.random.RandomState(12)
         prompts = [rng.randint(0, cfg.vocab_size, (L,), np.int32)
-                   for L in (5, 7, 4, 6)]
+                   for L in (5, 7, 4, 6, 3, 6)]
         eng = _engine(model, v, num_slots=4, page_size=8, max_len=24,
                       prefill_len=8, num_pages=16)
-        eng.submit(prompts[0], max_new=6)                 # greedy
-        eng.submit(prompts[1], max_new=6, temperature=0.8)
-        eng.submit(prompts[2], max_new=6, temperature=0.9, top_k=5)
-        eng.submit(prompts[3], max_new=6, temperature=0.7, top_p=0.9)
-        done = {r.id: r for r in eng.drain()}
+        retraces = _metrics.counter("jit.retraces").total()
+        done = {}
+
+        def wave():
+            """Drain; the sampled rows of each decode round it ran."""
+            done.update({r.id: r for r in eng.drain()})
+            return new_step_counts("sampled_rows")
+
+        with profiler_session():
+            eng.submit(prompts[4], max_new=5)             # greedy wave
+            assert wave() == [0] * 4
+            eng.submit(prompts[0], max_new=6)             # greedy
+            eng.submit(prompts[1], max_new=6, temperature=0.8)
+            eng.submit(prompts[2], max_new=6, temperature=0.9, top_k=5)
+            eng.submit(prompts[3], max_new=6, temperature=0.7, top_p=0.9)
+            assert wave() == [3] * 5
+            eng.submit(prompts[5], max_new=5)             # greedy again
+            assert wave() == [0] * 4
         assert eng.decode_traces == 1 and eng.prefill_traces == 1
-        np.testing.assert_array_equal(
-            done[0].output, _reference(model, v, prompts[0], 6))
+        assert _metrics.counter("jit.retraces").total() == retraces
+        for rid, p, mn in ((0, prompts[4], 5), (1, prompts[0], 6),
+                           (5, prompts[5], 5)):
+            np.testing.assert_array_equal(
+                done[rid].output, _reference(model, v, p, mn))
         eng.close()
 
     def test_top_k_one_equals_greedy(self):
@@ -421,3 +443,221 @@ class TestEnginePrefixCache:
             return out
 
         assert run(False) == run(True)
+
+
+# --- the sampler does only the work its rows ask for (ISSUE 29) ---
+
+def _straight_line_law(base_key, logits, temps, top_ks, top_ps, seeds,
+                       counts):
+    """The sampler as it stood before ISSUE 29, kept here as the
+    reference: one straight-line law for every mix of rows, which sorts,
+    masks and draws for every row and selects on its last line."""
+    greedy = jnp.argmax(logits, -1).astype(jnp.int32)
+    v = logits.shape[-1]
+    scaled = (logits.astype(jnp.float32)
+              / jnp.maximum(temps, 1e-6)[:, None])
+    desc = -jnp.sort(-scaled, axis=-1)              # descending
+    k_eff = jnp.where(top_ks > 0,
+                      jnp.minimum(top_ks, v), v).astype(jnp.int32)
+    kth = jnp.take_along_axis(desc, (k_eff - 1)[:, None], axis=1)
+    probs = jax.nn.softmax(desc, axis=-1)
+    cum = jnp.cumsum(probs, axis=-1)
+    p_eff = jnp.where((top_ps > 0.0) & (top_ps < 1.0),
+                      top_ps.astype(jnp.float32), 1.0)
+    n_keep = jnp.maximum(
+        jnp.sum((cum - probs) < p_eff[:, None], axis=-1), 1)
+    pth = jnp.take_along_axis(desc, (n_keep - 1)[:, None], axis=1)
+    masked = jnp.where((scaled >= kth) & (scaled >= pth),
+                       scaled, -1e30)
+
+    def row_key(s, c):
+        return jax.random.fold_in(
+            jax.random.fold_in(base_key, s), c)
+
+    keys = jax.vmap(row_key)(seeds, counts)
+    drawn = jax.vmap(jax.random.categorical)(keys, masked)
+    return jnp.where(temps > 0.0, drawn.astype(jnp.int32), greedy)
+
+
+_LAW_ROWS, _LAW_VOCAB = 6, 97      # a vocabulary that is no power of two
+
+# (temperature, top_k, top_p) a row; None = a released slot's zeroed
+# knobs (temperature, top-k, top-p, seed and count all 0)
+_LAW_MIXES = {
+    "all_greedy": [(0.0, 0, 0.0)] * 6,
+    "all_greedy_knobs_left_set": [(0.0, 5, 0.9), (0.0, 0, 0.5),
+                                  (0.0, 3, 0.0)] * 2,
+    "one_sampled_among_greedy": [(0.0, 0, 0.0)] * 3 + [(0.8, 0, 0.0)]
+    + [(0.0, 0, 0.0)] * 2,
+    "all_sampled_no_cut": [(0.7, 0, 0.0), (1.0, 0, 1.0), (1.3, 0, 0.0)] * 2,
+    "all_sampled_top_k": [(0.7, 1, 0.0), (1.0, 5, 0.0), (1.3, 40, 0.0),
+                          (0.9, 97, 0.0), (0.9, 500, 0.0), (2.0, 2, 0.0)],
+    "all_sampled_top_p": [(0.7, 0, 0.1), (1.0, 0, 0.5), (1.3, 0, 0.9),
+                          (0.9, 0, 0.99), (0.5, 0, 1e-6), (2.0, 0, 0.3)],
+    "all_sampled_top_k_and_top_p": [(0.7, 5, 0.9), (1.0, 40, 0.5),
+                                    (1.3, 2, 0.99), (0.9, 10, 0.1),
+                                    (0.5, 3, 0.7), (2.0, 20, 0.95)],
+    "every_kind_in_one_round": [(0.0, 0, 0.0), (0.8, 0, 0.0),
+                                (0.9, 5, 0.0), (0.7, 0, 0.9),
+                                (1.1, 7, 0.8), (0.0, 4, 0.6)],
+    "inactive_rows_zeroed_among_sampled": [None, (0.8, 5, 0.9), None,
+                                           None, (1.2, 0, 0.7), None],
+    "inactive_rows_zeroed_among_greedy": [None, (0.0, 0, 0.0), None,
+                                          (0.0, 3, 0.5), None, None],
+    "a_prefill_row_greedy": [(0.0, 0, 0.0)],
+    "a_prefill_row_sampled": [(0.9, 8, 0.9)],
+}
+
+
+def _law_logits(rows, kind, seed):
+    """[rows, 97] float32 logits. ``ties``: values on a grid of halves,
+    so that every row holds its maximum several times and both cuts fall
+    inside runs of equal logits; ``spread``: a smooth draw."""
+    rng = np.random.RandomState(seed)
+    if kind == "ties":
+        x = rng.randint(-6, 7, (rows, _LAW_VOCAB)).astype(np.float32) / 2
+        x[:, rng.randint(0, _LAW_VOCAB, 4)] = 3.0    # the maximum, tied
+        return x
+    return (rng.randn(rows, _LAW_VOCAB) * 3.0).astype(np.float32)
+
+
+@pytest.fixture(scope="module")
+def law_engine():
+    model, v, _ = _tiny_decoder()
+    eng = _engine(model, v, num_slots=2, page_size=8, max_len=24,
+                  prefill_len=8, num_pages=6, seed=3)
+    yield eng
+    eng.close()
+
+
+@pytest.mark.parametrize("kind", ["ties", "spread"])
+@pytest.mark.parametrize("mix", sorted(_LAW_MIXES))
+def test_sampler_answers_as_the_straight_line_law(law_engine, mix, kind):
+    """Token for token, for every mix of rows: the engine's sampler,
+    which chooses its work from the values of its knobs, against the
+    straight-line law that does all of it for every row."""
+    rows = _LAW_MIXES[mix]
+    n = len(rows)
+    live = np.array([r is not None for r in rows])
+    knobs = [r or (0.0, 0, 0.0) for r in rows]
+    temps = np.array([k[0] for k in knobs], np.float32)
+    top_ks = np.array([k[1] for k in knobs], np.int32)
+    top_ps = np.array([k[2] for k in knobs], np.float32)
+    seeds = np.where(live, 1000 + 17 * np.arange(n), 0).astype(np.uint32)
+    counts = np.where(live, 3 * np.arange(n) + 1, 0).astype(np.int32)
+    logits = _law_logits(n, kind, seed=len(mix))
+    if kind == "ties":
+        assert ((logits == logits.max(-1, keepdims=True)).sum(-1) > 1).all()
+    sample = jax.jit(law_engine._sample)
+    law = jax.jit(functools.partial(_straight_line_law,
+                                    law_engine._base_key))
+    knobs = (logits, temps, top_ks, top_ps, seeds)
+    got = np.asarray(sample(*knobs, counts))
+    assert got.dtype == np.int32 and got.shape == (n,)
+    np.testing.assert_array_equal(got, np.asarray(law(*knobs, counts)))
+    greedy = temps == 0.0
+    np.testing.assert_array_equal(got[greedy],
+                                  logits.argmax(-1)[greedy])
+    if not greedy.all():
+        # the sampled rows are really drawn: another count, another key
+        again = np.asarray(sample(*knobs, counts + 1))
+        np.testing.assert_array_equal(again[greedy], got[greedy])
+        np.testing.assert_array_equal(
+            again, np.asarray(law(*knobs, counts + 1)))
+
+
+# what only a sampled row needs: the sort of the vocabulary, the
+# nucleus' cumulative sum, the keys and the draw's random bits
+_SAMPLED_ONLY = {"sort", "cumsum", "cumlogsumexp", "random_bits",
+                 "random_fold_in", "random_wrap", "random_unwrap",
+                 "random_seed", "threefry2x32"}
+
+
+def _equations(jaxpr, branch=()):
+    """(primitive name, branch) of every equation under ``jaxpr``;
+    ``branch`` is the chain of (cond equation, index) it lies under."""
+    for eqn in jaxpr.eqns:
+        yield eqn, branch
+        if eqn.primitive.name == "cond":
+            for i, br in enumerate(eqn.params["branches"]):
+                yield from _equations(br.jaxpr, branch + ((id(eqn), i),))
+            continue
+        for val in eqn.params.values():
+            for sub in (val if isinstance(val, (tuple, list)) else (val,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _equations(sub, branch)
+
+
+def _step_args(eng, program):
+    cfg = eng.cfg
+    s, w = cfg.num_slots, cfg.spec_k + 1
+    knobs = lambda n: (np.zeros(n, np.float32), np.zeros(n, np.int32),
+                       np.zeros(n, np.float32), np.zeros(n, np.uint32),
+                       np.zeros(n, np.int32))
+    if program == "decode":
+        return eng._decode_jit, (
+            eng._params, (eng._caches, eng._state), np.zeros(s, np.int32),
+            eng._page_table, np.zeros(s, np.int32), np.zeros(s, bool),
+            *knobs(s))
+    if program == "prefill":
+        return eng._prefill_jit, (
+            eng._params, (eng._caches, eng._state),
+            np.zeros((1, cfg.prefill_len), np.int32), np.zeros(1, np.int32),
+            np.zeros(1, np.int32), eng._page_table[:1],
+            np.zeros(1, np.int32), np.zeros(1, np.int32), *knobs(1))
+    if program == "draft":
+        return eng._draft_jit, (
+            eng._draft_params, eng._draft_caches, np.zeros(s, np.int32),
+            eng._page_table, np.zeros(s, np.int32), np.zeros(s, bool),
+            *knobs(s))
+    assert program == "verify"
+    return eng._verify_jit, (
+        eng._params, eng._caches, np.zeros((s, w), np.int32),
+        np.zeros(s, np.int32), np.zeros(s, np.int32), eng._page_table,
+        *knobs(s))
+
+
+@pytest.mark.parametrize("program,samplers", [
+    ("decode", 1), ("prefill", 1), ("draft", 1), ("verify", 4)])
+def test_sampled_rows_work_stands_only_in_the_conds_taken_branch(
+        program, samplers):
+    """In each step program's jaxpr, the sort, the cumulative sum and
+    the random bits stand nowhere but inside the taken branch of the
+    sampler's lax.cond (one sampler a program, spec_k + 1 in verify);
+    the other branch holds none of them, and the argmax stays outside."""
+    model, v, _ = _tiny_decoder()
+    eng = _engine(model, v, num_slots=2, page_size=8, max_len=24,
+                  prefill_len=8, num_pages=6,
+                  draft=program in ("draft", "verify"), spec_k=3)
+    fn, args = _step_args(eng, program)
+    eng._aot_trace = True          # a deliberate trace, as compiled_*()
+    try:
+        jaxpr = jax.make_jaxpr(fn)(*args)
+    finally:
+        eng._aot_trace = False
+    eng.close()
+    eqns = list(_equations(jaxpr.jaxpr))
+    names = {e.primitive.name for e, _ in eqns}
+    assert {"sort", "cumsum", "cond", "argmax"} <= names
+    assert names & {"random_bits", "threefry2x32"}
+    conds = set()
+    for eqn, branch in eqns:
+        if eqn.primitive.name in _SAMPLED_ONLY:
+            assert branch, f"{eqn.primitive.name} outside any cond"
+            assert all(i == 1 for _, i in branch), (
+                f"{eqn.primitive.name} in a branch not taken")
+            conds.add(branch[0][0])
+    assert len(conds) == samplers
+    for eqn, branch in eqns:
+        if id(eqn) in conds:
+            assert not branch                # the sampler's cond: top level
+            skipped, taken = eqn.params["branches"]
+            assert len(skipped.jaxpr.eqns) <= 1      # hands greedy back
+            assert [a.aval.dtype for a in eqn.outvars] == [jnp.int32]
+            assert eqn.outvars[0].aval.shape == \
+                taken.jaxpr.outvars[0].aval.shape
+    # the greedy answer is taken outside the cond, once a sampler
+    argmaxes = [b for e, b in eqns if e.primitive.name == "argmax"
+                and not b]
+    assert len(argmaxes) >= samplers

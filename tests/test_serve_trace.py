@@ -318,7 +318,9 @@ class TestEngineSpans:
                                       "serve.advance"])
             decoded += len(names) == 5
             c = s["counts"]
-            assert set(c) == {"pages_in_use", "pages_cached", "num_pages"}
+            assert set(c) == {"pages_in_use", "pages_cached", "num_pages",
+                              "sampled_rows"}
+            assert c["sampled_rows"] == 0            # every request greedy
             assert c["num_pages"] == 12
             assert 0 <= c["pages_in_use"] <= 12 - c["pages_cached"]
         assert decoded >= 4

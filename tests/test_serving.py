@@ -296,6 +296,36 @@ class TestServingEngine:
                           method=lambda m: model.generate(m, 5))
         np.testing.assert_array_equal(o.output, np.asarray(ref)[0])
 
+    @pytest.mark.parametrize("sampled_new", [0, 4],
+                             ids=["all_greedy", "mixed"])
+    def test_step_span_counts_the_sampled_rows(
+            self, rng, new_step_counts, profiler_session, sampled_new):
+        """Which branch of the sampler each decode round took, read from
+        the host's copy of the knobs: ``sampled_rows`` on the
+        ``serve.step`` span (0 = the argmax alone). A greedy request of
+        9 tokens (8 decode rounds) runs beside a sampled one of
+        ``sampled_new`` tokens (none, or 3 rounds of the 8); the last
+        step decodes nothing."""
+        from paddle_tpu.serving import ServeConfig, ServingEngine
+        model, v, cfg = _tiny_decoder()
+        eng = ServingEngine(model, v, ServeConfig(
+            num_slots=2, page_size=8, max_len=32, prefill_len=16,
+            num_pages=8, metrics_port=0))
+        prompts = [rng.randint(0, cfg.vocab_size, (L,)).astype(np.int32)
+                   for L in (5, 7)]
+        with profiler_session():
+            eng.submit(prompts[0], max_new=9)
+            if sampled_new:
+                eng.submit(prompts[1], max_new=sampled_new,
+                           temperature=0.8, top_p=0.9, seed=5)
+            eng.drain()
+            eng.step()                       # an idle round: no decode
+        sampled_rounds = max(sampled_new - 1, 0)
+        assert new_step_counts("sampled_rows") == \
+            [1] * sampled_rounds + [0] * (9 - sampled_rounds)
+        assert eng.decode_traces == 1 and eng.prefill_traces == 1
+        eng.close()
+
     def test_page_exhaustion_stalls_then_recovers(self, rng):
         """With a pool too small for both requests' full growth, a slot
         stalls (counter fires) but decoding still completes correctly

@@ -202,6 +202,43 @@ class TestSpeculativeDecoding:
         plain.close()
         spec.close()
 
+    def test_one_trace_a_program_through_greedy_mixed_greedy(
+            self, new_step_counts, profiler_session):
+        """With speculation on, an all-greedy wave, a wave that mixes
+        greedy and sampled rows and a greedy wave again (the sampler's
+        short branch, its long one, its short one, as the ``serve.step``
+        span's ``sampled_rows`` tells) run through ONE draft, ONE verify
+        and ONE prefill program."""
+        from paddle_tpu.observability import metrics as _metrics
+        eng, model, variables, cfg = _engine(draft=True, spec_k=3)
+        rng = np.random.RandomState(9)
+        prompts = [rng.randint(0, cfg.vocab_size, (L,), np.int32)
+                   for L in (6, 9, 20, 5)]
+        retraces = _metrics.counter("jit.retraces").total()
+
+        def wave():
+            """Drain; the sampled rows of each round that ran."""
+            eng.drain()
+            return new_step_counts("sampled_rows")
+
+        with profiler_session():
+            first = eng.submit(prompts[0], max_new=8)
+            assert set(wave()) == {0}
+            eng.submit(prompts[1], max_new=8)
+            eng.submit(prompts[2], max_new=8, temperature=0.8, top_k=30,
+                       seed=11)
+            assert 1 in wave()
+            last = eng.submit(prompts[3], max_new=8)
+            assert set(wave()) == {0}
+        assert eng.draft_traces == 1 and eng.verify_traces == 1
+        assert eng.prefill_traces == 1 and eng.draft_prefill_traces == 1
+        assert eng.decode_traces == 0        # every round speculated
+        assert _metrics.counter("jit.retraces").total() == retraces
+        for rid, p in ((first, prompts[0]), (last, prompts[3])):
+            assert np.array_equal(eng.requests[rid].output,
+                                  _generate_ref(model, variables, p, 8))
+        eng.close()
+
     def test_recovery_mid_speculation_token_exact(self, fast_retry):
         """An injected serve.step crash mid-stream on a speculative
         engine quarantines BOTH page pools (target + draft) and

@@ -169,12 +169,14 @@ def window_facts(rounds, clients, work, cfg_shapes, chunk, lo, hi):
             "decode_slot_steps": slot_steps}
 
 
-def measure(engine, ctx, cell, seconds):
+def measure(engine, ctx, cell, seconds, clock=time.perf_counter,
+            sleep=time.sleep):
     """Warm-up traffic, then one window of ``seconds`` at the cell's fixed
     rate on ``engine``, then the drain: ``serve_window.measure`` with the
     counting handed to ``window_facts`` above (the module's docstring
     says why it is a copy). Returns the end-to-end numbers, the facts
-    for the per-layer readers and the finished requests."""
+    for the per-layer readers and the finished requests.
+    ``clock`` and ``sleep`` are the tests' way in, as in ``drive``."""
     config, traffic, log = ctx["config"], ctx["traffic"], ctx["log"]
     shapes = config["shapes"]
     work = importlib.import_module(config["work"])
@@ -190,20 +192,26 @@ def measure(engine, ctx, cell, seconds):
         built.append(ctx["compiles"].compiles)
         if ctx["trace"]:
             tracer.start()
-        seen.append(time.perf_counter())
+        seen.append(clock())
 
     def on_close():
-        seen.append(time.perf_counter())
+        seen.append(clock())
         built.append(ctx["compiles"].compiles)
         if ctx["trace"]:
-            tracer.stop()
+            tracer.close_window()
 
-    t0 = time.perf_counter() + 0.05
+    t0 = clock() + 0.05
     t_open, t_close = t0 + warm, t0 + warm + seconds
     rounds = drive(engine, schedule, t0, t_open, t_close,
                    cell["drain_limit_s"], log, on_open=on_open,
-                   on_close=on_close)
-    t_end = time.perf_counter()
+                   on_close=on_close, clock=clock, sleep=sleep)
+    t_end = clock()
+    if ctx["trace"]:
+        # the profiler's stop takes seconds: only now, with no request
+        # running. The trace holds the drain too; the readers cut at
+        # the window's close
+        tracer.stop()
+        log(f"profiler stopped in {clock() - t_end:.2f} s")
 
     measured = [c for c in schedule if c.measured and c.rid is not None]
     done = [c for c in measured if c.req.status == "done"

@@ -156,7 +156,12 @@ def served_gap(ctx, sample, precision=None):
     ref = importlib.import_module(config["reference"])
     _, params = weights.model_and_params(config, ctx["seed"])
     max_len = config["engine"]["max_len"]
-    n_out = traffic["answer"]["max"]
+    # the reference scores ``n_out`` positions from a start that must
+    # leave them inside ``max_len`` (a dynamic slice would clamp it in
+    # silence): where the longest answer allowed is as long as the
+    # engine's ``max_len`` it starts before the prompt's end, and the
+    # served tokens' rows begin ``skip`` rows down
+    n_out = min(traffic["answer"]["max"], max_len)
     heads = config["shapes"]["num_heads"]
 
     @jax.jit
@@ -171,12 +176,14 @@ def served_gap(ctx, sample, precision=None):
         ids = np.zeros(max_len, np.int32)
         ids[:c.prompt.size] = c.prompt
         ids[c.prompt.size:c.prompt.size + toks.size] = toks
-        first = np.int32(c.prompt.size - 1)
+        first = np.int32(min(c.prompt.size - 1, max_len - n_out))
+        skip = c.prompt.size - 1 - int(first)
+        rows = slice(skip, skip + toks.size)
         logits = ref.logits_at(params, jnp.asarray(ids), first,
                                num_heads=heads, n_out=n_out)
         padded = np.zeros(n_out, np.int32)
-        padded[:toks.size] = toks
-        g = np.asarray(gaps(logits, jnp.asarray(padded)))[:toks.size]
+        padded[rows] = toks
+        g = np.asarray(gaps(logits, jnp.asarray(padded)))[rows]
         worst = max(worst, float(g.max()))
         compared += toks.size
         if precision:
@@ -184,7 +191,7 @@ def served_gap(ctx, sample, precision=None):
                                 num_heads=heads, n_out=n_out,
                                 precision=precision)
             g = np.asarray(gaps(logits, jnp.argmax(low, -1).astype(
-                jnp.int32)))[:toks.size]
+                jnp.int32)))[rows]
             worst_control = max(worst_control, float(g.max()))
     return worst, compared, worst_control
 
@@ -265,12 +272,14 @@ def by_quarter(rounds, lo, hi, col):
     return out
 
 
-def measure(engine, ctx, cell, seconds):
+def measure(engine, ctx, cell, seconds, clock=time.perf_counter,
+            sleep=time.sleep):
     """Warm-up traffic, then one window of ``seconds`` at the cell's fixed
     rate on ``engine``, then the drain. ``cell`` is the cell's own file
     (``rate_per_s``, ``warmup_seconds``, ``drain_limit_s``). Returns the
     end-to-end numbers, the facts for the per-layer readers and the
-    finished requests."""
+    finished requests.
+    ``clock`` and ``sleep`` are the tests' way in, as in ``drive``."""
     config, traffic, log = ctx["config"], ctx["traffic"], ctx["log"]
     shapes = config["shapes"]
     warm, rate = cell["warmup_seconds"], cell["rate_per_s"]
@@ -285,20 +294,26 @@ def measure(engine, ctx, cell, seconds):
         built.append(ctx["compiles"].compiles)
         if ctx["trace"]:
             tracer.start()
-        seen.append(time.perf_counter())
+        seen.append(clock())
 
     def on_close():
-        seen.append(time.perf_counter())
+        seen.append(clock())
         built.append(ctx["compiles"].compiles)
         if ctx["trace"]:
-            tracer.stop()
+            tracer.close_window()
 
-    t0 = time.perf_counter() + 0.05
+    t0 = clock() + 0.05
     t_open, t_close = t0 + warm, t0 + warm + seconds
     rounds = drive(engine, schedule, t0, t_open, t_close,
                    cell["drain_limit_s"], log, on_open=on_open,
-                   on_close=on_close)
-    t_end = time.perf_counter()
+                   on_close=on_close, clock=clock, sleep=sleep)
+    t_end = clock()
+    if ctx["trace"]:
+        # the profiler's stop takes seconds: only now, with no request
+        # running. The trace holds the drain too; the readers cut at
+        # the window's close
+        tracer.stop()
+        log(f"profiler stopped in {clock() - t_end:.2f} s")
 
     measured = [c for c in schedule if c.measured and c.rid is not None]
     done = [c for c in measured if c.req.status == "done"
@@ -331,12 +346,23 @@ def measure(engine, ctx, cell, seconds):
     inside = [r for r in rounds if t_open <= r[0] < t_close]
     if len(inside) > 1:
         longest = max(inside, key=lambda r: r[1] - r[0])
+        quiet = [r[1] - r[0] for r in inside if not r[5]]
+        # the host's level is what moves gap_p90_ms between runs (PERF.md
+        # section 2): by quarter, to tell a slow run from a slow stretch
+        quiet_by_quarter = [
+            [r[1] - r[0] for r in inside if not r[5]
+             and k <= 4 * (r[0] - t_open) / seconds < k + 1]
+            for k in range(4)]
         pause, at = max((b[0] - a[1], a[1])
                         for a, b in zip(inside, inside[1:]))
         log(f"longest engine step {1e3 * (longest[1] - longest[0]):.1f} ms "
             f"({longest[5]} admitted in it), {longest[0] - t_open:.1f} s "
             f"into the window, the median "
             f"{1e3 * stats.percentile([r[1] - r[0] for r in inside], 50):.1f}"
+            f" (of the steps that admitted nothing "
+            f"{1e3 * stats.percentile(quiet or [0.0], 50):.3f}, by quarter "
+            + "/".join(f"{1e3 * stats.percentile(q or [0.0], 50):.2f}"
+                       for q in quiet_by_quarter) + ")"
             f"; longest pause of the loop between two steps "
             f"{1e3 * pause:.1f} ms, {at - t_open:.1f} s into the window")
 
@@ -353,8 +379,8 @@ def measure(engine, ctx, cell, seconds):
         + f"; over {len(ttft)} requests, {len(gaps)} gaps")
     for name, xs in (("ttft", ttft), ("gap", gaps)):
         log(f"{name} ms: mean {sum(xs) / max(len(xs), 1):.1f}; " + ", ".join(
-            f"p{q:g} {tail(xs, q):.1f}"
-            for q in (50, 75, 80, 85, 90, 92.5, 95, 97.5, 99)))
+            f"p{q:g} {tail(xs, q):.2f}"
+            for q in (50, 75, 80, 85, 87.5, 90, 91.25, 92.5, 95, 97.5, 99)))
     slots, pages = engine.cfg.num_slots, engine.cfg.num_pages
     # the loop learns of the open and the close between two engine steps:
     # the facts (and the trace) cover the window as the loop saw it

@@ -51,24 +51,33 @@ def find_xplane(trace_dir):
 
 
 def load_xplane(path, keep_host=re.compile(r"^bench\.|^(ingest|stage|step)$")):
-    """The neutral form of one ``.xplane.pb``. Device planes keep every
-    event of their ops and modules lines; host planes keep only the
-    benchmark's and the program's span annotations (``keep_host``)."""
+    """The neutral form of one ``.xplane.pb``. Device planes keep the
+    events of their ops and modules lines that begin before the end of
+    ``bench.window`` (all of them in a trace without one); host planes
+    keep only the benchmark's and the program's span annotations
+    (``keep_host``)."""
     from jax.profiler import ProfileData
     data = ProfileData.from_file(path)
-    planes = []
-    for plane in data.planes:
+    # the host planes first: a serving run's trace goes on through the
+    # drain (``Tracer.close_window``), every reader cuts at the end of
+    # ``bench.window``, and most of loading is the device events' names
+    planes, close_ns = [], None
+    for plane in ([p for p in data.planes if p.name.startswith("/host:")]
+                  + [p for p in data.planes if DEVICE_PLANE.match(p.name)]):
         is_dev = bool(DEVICE_PLANE.match(plane.name))
-        if not is_dev and not plane.name.startswith("/host:"):
-            continue
         lines = []
         for line in plane.lines:
             if is_dev and line.name not in (OPS_LINE, MODULES_LINE):
                 continue
             events = []
             for ev in line.events:
-                if not is_dev and not keep_host.search(ev.name):
+                if is_dev:
+                    if close_ns is not None and ev.start_ns > close_ns:
+                        continue
+                elif not keep_host.search(ev.name):
                     continue
+                elif ev.name == "bench.window":
+                    close_ns = ev.start_ns + ev.duration_ns
                 events.append([ev.name[:NAME_CHARS], int(ev.start_ns),
                                int(ev.duration_ns)])
             if events:

@@ -27,10 +27,30 @@ class Tracer:
         self._window = jax.profiler.TraceAnnotation("bench.window")
         self._window.__enter__()
 
+    def close_window(self):
+        """End ``bench.window``, where every reader cuts the trace
+        (``trace_reduce.window_of``). Microseconds: the serving loop calls
+        it between two engine steps, with requests still running."""
+        if self._window is not None:
+            self._window.__exit__(None, None, None)
+            self._window = None
+
     def stop(self):
+        """Close the window if it is still open and stop the profiler.
+        That takes seconds to minutes (it collects the trace of every
+        device event since ``start``): a loop that serves requests calls
+        it only once it has returned, or every request then running gets
+        one gap of that length."""
         import jax
-        self._window.__exit__(None, None, None)
-        jax.profiler.stop_trace()
+        self.close_window()
+        xspace = stop_session()
+        if xspace is None:
+            jax.profiler.stop_trace()
+            return
+        out = os.path.join(self.dir, "plugins", "profile", "run")
+        os.makedirs(out, exist_ok=True)
+        with open(os.path.join(out, "host.xplane.pb"), "wb") as f:
+            f.write(xspace)
 
     def load(self):
         path = trace_reduce.find_xplane(self.dir)
@@ -38,6 +58,24 @@ class Tracer:
         if not self.keep:
             shutil.rmtree(self.dir, ignore_errors=True)
         return trace
+
+
+def stop_session():
+    """Stop JAX's profiler session and hand back the trace as the bytes
+    of an ``.xplane.pb``, or None where this JAX keeps its session
+    somewhere else (``stop`` then falls back to ``stop_trace``).
+    ``jax.profiler.stop_trace()`` itself also converts the whole trace
+    into a ``trace.json.gz`` that nothing here reads, which is most of
+    the time it takes (PERF.md section 6, PR 31)."""
+    from jax._src import profiler as impl
+    state = getattr(impl, "_profile_state", None)
+    session = getattr(state, "profile_session", None)
+    if session is None or not hasattr(session, "stop"):
+        return None
+    with state.lock:
+        xspace = session.stop()
+        state.reset()
+    return xspace
 
 
 def annotate(name):

@@ -14,7 +14,7 @@ belong to the traffic and to no configuration:
     base_seed         fixes the arrival times and the lengths of every run
 
 Arrivals are Poisson (exponential gaps) at the rate of the CELL
-(``benchmark/cells/<cell>.json``): the rate is four fifths of one
+(``benchmark/cells/<cell>.json``): the rate lies inside 0.7-0.8 of one
 configuration's knee and belongs to no mix.
 
 The MIX fixes when each request is due and how long its prompt and its

@@ -11,8 +11,8 @@ every span and lifecycle event in a bounded in-memory store:
 ``start`` / ``end`` are ``time.perf_counter`` seconds, ``parent`` the id
 of the enclosing span, ``rid`` the request's id, ``counts`` what the span
 counted at its boundary; an event has ``path`` None and ``start == end``.
-The store empties itself when a session
-begins, so after a traced window it holds that window's records and
+The store empties itself when a session begins, so after a traced run
+it holds that session's records (the window's, then the drain's) and
 nothing else. The readers run in the benchmark's process after the
 window and take ``spans.records()``; against a program that has no such
 store (the parent of PR 25) they find nothing and return None.
@@ -36,15 +36,19 @@ and the events ``submitted``, ``admitted``, ``resumed``,
 What is left of ``serve.step`` after its children is its self time:
 gauges, RunLog, watchdog.
 
-**The clock.** ``serve_window.drive`` opens and closes the profiler
-session between two engine steps, so the store's ``serve.step`` records
-and the trace's ``bench.step`` annotations inside ``bench.window`` are
-the same rounds, one to one, in order. ``pair_clocks`` pairs the k-th
-with the k-th, takes the median of ``start(serve.step) -
-start(bench.step)`` as the offset between ``perf_counter`` and the
-trace's clock (ns from the start of the trace), and refuses if the
-counts differ or any pair lies more than ``PAIR_TOLERANCE_S`` from that
-offset: the records are then not this window's. Every reader here reads
+**The clock.** ``serve_window.drive`` opens the profiler session and
+closes ``bench.window`` between two engine steps (the session itself
+ends after the drain: stopping the profiler takes seconds), so the
+store's first ``serve.step`` records and the trace's ``bench.step``
+annotations inside ``bench.window`` are the same rounds, one to one, in
+order; the records after the window's close are the drain's and are
+left out. ``pair_clocks`` pairs the k-th with the k-th, takes the
+median of ``start(serve.step) - start(bench.step)`` as the offset
+between ``perf_counter`` and the trace's clock (ns from the start of
+the trace), and refuses if the store holds fewer steps than the window
+has rounds, if a surplus step begins before the window's close, or if
+any pair lies more than ``PAIR_TOLERANCE_S`` from that offset: the
+records are then not this window's. Every reader here reads
 only records that passed it, so a run without a device trace (a
 rehearsal on the CPU) reads nothing.
 
@@ -127,14 +131,20 @@ def named(records, name):
 def pair_clocks(records, trace):
     """Seconds to take from a record's ``perf_counter`` time to stand on
     the trace's clock, or None (and why, on standard error) where the
-    records cannot be this trace's window."""
-    steps = named(records, "serve.step")
+    records cannot be this trace's window. The session outlives the
+    window (the profiler stops after the drain, ``Tracer.close_window``),
+    so the store may hold more steps than the window has rounds: its
+    first steps are then the window's, and the others have to begin
+    after the window's close."""
     lo, hi = trace_reduce.window_of(trace)
     bench = [s for s in trace_reduce.host_spans(trace, r"^bench\.step$")
              if s[1] >= lo and s[2] <= hi]
+    every = named(records, "serve.step")
+    steps, drain = every[:len(bench)], every[len(bench):]
+    counts = (f"{len(every)} serve.step record(s) against {len(bench)} "
+              f"bench.step annotation(s) in the window")
     if not steps or len(steps) != len(bench):
-        say(f"no pairing: {len(steps)} serve.step record(s) against "
-            f"{len(bench)} bench.step annotation(s) in the window")
+        say(f"no pairing: {counts}")
         return None
     diffs = [r["start"] - b[1] / 1e9 for r, b in zip(steps, bench)]
     offset = statistics.median(diffs)
@@ -142,6 +152,10 @@ def pair_clocks(records, trace):
     if worst > PAIR_TOLERANCE_S:
         say(f"no pairing: a serve.step lies {1e3 * worst:.3f} ms from the "
             f"median offset of {len(diffs)} pairs")
+        return None
+    if drain and (drain[0]["start"] - offset) * 1e9 < hi:
+        say(f"no pairing: {counts}, and the next record begins before "
+            f"the window's close")
         return None
     say(f"{len(diffs)} serve.step records paired with bench.step: offset "
         f"{offset:.6f} s, widest departure {1e6 * worst:.1f} us")
@@ -164,7 +178,16 @@ def read_session(trace):
         say("the program's span store is empty")
         return None
     offset = pair_clocks(records, trace)
-    return None if offset is None else (records, offset)
+    if offset is None:
+        return None
+    # the window's records alone: the loop closes ``bench.window``
+    # between two engine steps, so no span straddles the close
+    close = trace_reduce.window_of(trace)[1] / 1e9 + offset
+    inside = [r for r in records if r["end"] <= close]
+    if len(inside) < len(records):
+        say(f"{len(records) - len(inside)} record(s) after the window's "
+            f"close (the drain) left out")
+    return inside, offset
 
 
 def median_ms(seconds):

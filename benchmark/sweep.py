@@ -3,9 +3,11 @@
 Run once, by hand, on the chip, when a cell is defined (and again when an
 optimisation has moved the knee): one engine, one window per rate, a
 table out. The cell's own file (``benchmark/cells/<cell>.json``) then
-fixes its rate at four fifths of the knee; the benchmark itself never
-searches. Windows of the cell's own length: a request lives for tens of
-seconds, and a shorter sweep measures the warm-up.
+fixes its rate inside 0.7-0.8 of the knee (where, the ladder of the gaps
+on each window's standard error decides; README.md); the benchmark
+itself never searches. Windows of the cell's own length: the longest
+request lives for tens of seconds, and a shorter sweep measures the
+warm-up.
 
     python3 benchmark/sweep.py --workload gpt2_medium.chat \\
         --rates 2.0,2.3,2.6,2.9 --seconds 51 [--seed 1]
@@ -77,14 +79,14 @@ def main(argv=None):
     ok = [r["rate_per_s"] for r in rows if r["sustained"]]
     knee = max(ok) if ok else None
     summary = {"workload": args.workload, "knee_per_s": knee,
-               "four_fifths": None if knee is None else 0.8 * knee,
+               "band": None if knee is None else [0.7 * knee, 0.8 * knee],
                "device": device.describe(devices), "rows": rows}
     if args.out:
         os.makedirs(os.path.dirname(args.out), exist_ok=True)
         with open(args.out, "w") as f:
             json.dump(summary, f, indent=1)
     print(json.dumps({k: summary[k] for k in
-                      ("workload", "knee_per_s", "four_fifths")}))
+                      ("workload", "knee_per_s", "band")}))
 
 
 if __name__ == "__main__":

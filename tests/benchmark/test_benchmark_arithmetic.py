@@ -141,6 +141,27 @@ def test_schedule_repeats_for_a_seed_and_differs_across_seeds():
     assert (answers == 256).mean() == pytest.approx(0.303, abs=0.01)
 
 
+def test_answers_capped_by_the_context_alone_never_pass_it():
+    """The ``chat`` mix since PR 31: the answers' cap is the 1024
+    positions of the engine's ``max_len`` itself, so the schedule's clip
+    (prompt + answer <= ``max_len``) is what ends the longest ones."""
+    mix = dict(MIX, answer=dict(MIX["answer"], max=1024))
+    s = traffic.serve_schedule(mix, 200.0, 50257, 1024, 2 ** 31 + 5, 100.0)
+    assert len(s) > 15000
+    assert all(4 <= x["max_new"] and x["prompt"].size + x["max_new"] <= 1024
+               for x in s)
+    touched = [x for x in s if x["prompt"].size + x["max_new"] == 1024]
+    assert len(touched) / len(s) == pytest.approx(0.0126, abs=0.004)
+    assert max(x["max_new"] for x in s) > 900
+    # by the law: 0.85% reach 1024 and the mean is 213.3; the clip by the
+    # prompt takes it to 212.4
+    answers = traffic.lengths(np.random.default_rng(2), mix["answer"], 200000)
+    assert (answers == 1024).mean() == pytest.approx(0.0085, abs=0.002)
+    assert answers.mean() == pytest.approx(213.3, rel=0.02)
+    assert np.mean([x["max_new"] for x in s]) == pytest.approx(212.4,
+                                                               rel=0.04)
+
+
 def test_shared_prefix_is_data_not_code():
     mix = dict(MIX, shared_prefix={"share": 1.0, "length": 64, "groups": 1})
     s = [x for x in traffic.serve_schedule(mix, 10.0, 50257, 1024, 3, 5.0)

@@ -148,6 +148,44 @@ def test_records_of_another_window_are_refused(store, capsys):
     assert read_all(hand_trace()) == {}
 
 
+def drain_records():
+    """A third round after the window's close at 100 ms, as the store
+    holds it since the profiler stops after the drain (PR 31): a step
+    105-130 with its phases, and request 6 admitted in it."""
+    return [
+        rec(31, "admitted", 105.5, 105.5, parent=32, rid=6, event=True),
+        rec(32, "serve.admit", 105, 106, parent=30),
+        rec(33, "serve.decode", 106, 108, parent=30),
+        rec(34, "serve.fetch", 108, 126, parent=30),
+        rec(35, "serve.advance", 126, 129, parent=30),
+        rec(30, "serve.step", 105, 130, pages_in_use=900, pages_cached=0,
+            num_pages=1000),
+    ]
+
+
+def test_the_drains_records_after_the_close_are_left_out(store, capsys):
+    """The session outlives the window: what the store holds of the
+    drain changes no reading (the trace's own events are cut at
+    ``bench.window`` by ``trace_reduce``)."""
+    sound = read_all(hand_trace())
+    assert set(sound) == set(NAMES)
+    trace = hand_trace()
+    trace["planes"][1]["lines"][0]["events"].append(
+        ["bench.step", 105 * MS, 26 * MS])
+    trace["planes"][0]["lines"][0]["events"].append(
+        ["%fusion.2 = f32[8] fusion(...)", 107 * MS, 18 * MS])
+    store["records"] = hand_records() + drain_records()
+    capsys.readouterr()
+    assert read_all(trace) == pytest.approx(sound)
+    assert "6 record(s) after the window's close" in capsys.readouterr().err
+    # a surplus step INSIDE the window is no drain: the records are not
+    # this window's rounds
+    inside = hand_records() + [rec(40, "serve.step", 85, 95)]
+    assert es.pair_clocks(inside, hand_trace()) is None
+    assert "3 serve.step record(s) against 2 bench.step" in \
+        capsys.readouterr().err
+
+
 def test_idle_time_goes_to_the_innermost_span_that_covers_it(store):
     table = es.idle_by_span(store["records"], OFFSET, hand_trace())
     ms = {k: 1e3 * v for k, v in table.items()}

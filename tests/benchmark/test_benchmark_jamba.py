@@ -104,22 +104,27 @@ def test_the_program_builds_that_configuration_with_one_kv_head(real):
 def test_the_mix_is_chat_with_the_named_keys_changed_and_no_others(real):
     chat, mine = read("benchmark", "traffic", "chat.json"), real[3]
     changed = {k for k in chat if chat[k] != mine[k]}
-    assert changed == {"kind", "answer", "cut", "base_seed"}
+    # since PR 31 ``chat`` too lets its answers run to 1024
+    assert changed == {"kind", "cut", "base_seed"}
     assert mine["kind"] == "serve_model" and mine["base_seed"] == 20261002
-    assert mine["answer"] == dict(chat["answer"], max=1024)
+    assert mine["answer"] == chat["answer"] and mine["answer"]["max"] == 1024
     assert set(mine) == set(chat)
 
 
+@pytest.mark.parametrize("cell", ["gpt2_medium.chat", CELL])
 def test_the_cells_own_file_states_its_rate_and_limits_with_their_origin(
-        real):
-    own = real[4]
+        cell):
+    """Both serving cells are held to the same: a rate inside 0.7-0.8 of a
+    knee that was swept, and every number with where it comes from."""
+    own = read("benchmark", "cells", cell + ".json")
     assert 0.7 * own["knee_per_s"] <= own["rate_per_s"] <= \
         0.8 * own["knee_per_s"] + 1e-9
     assert own["drain_limit_s"] == 60 and own["warmup_seconds"] > 0
     assert own["limits"]["never_answered"] == 0
     assert own["limits"]["served_gap"] > 0
-    for key in ("knee_from", "warmup_from", "limits_from"):
+    for key in ("knee_from", "rate_from", "warmup_from", "limits_from"):
         assert len(own[key]) > 40, key
+    assert "NOT" in own["knee_from"]       # bracketed from above
 
 
 @pytest.mark.parametrize("metric", [

@@ -79,6 +79,41 @@ def test_serving_window_through_the_real_engine(tiny_root):
             "serve.gap_mean_ms", "serve.kv_pool_live"} == set(line["metrics"])
 
 
+@pytest.fixture(scope="module")
+def tiny_long_root(tiny_root, tmp_path_factory):
+    """The tiny root with ``chat``'s answers allowed to run to the
+    engine's ``max_len`` (128 here), as the real mix lets them run to
+    GPT-2's 1024 positions since PR 31."""
+    import shutil
+    root = str(tmp_path_factory.mktemp("tiny_long") / "root")
+    shutil.copytree(tiny_root, root)
+    path = os.path.join(root, "benchmark", "traffic", "chat.json")
+    with open(path) as f:
+        mix = json.load(f)
+    mix["answer"] = {"mean": 40, "min": 2, "max": 128}
+    with open(path, "w") as f:
+        json.dump(mix, f)
+    return root
+
+
+def test_answers_as_long_as_the_context_are_compared_row_for_row(
+        tiny_long_root, monkeypatch):
+    """Where the longest answer allowed is ``max_len`` itself the
+    reference cannot score that many positions FROM the prompt's end (a
+    dynamic slice would clamp the start in silence and every row would
+    be another position's): it starts earlier, and the comparison reads
+    the served tokens' own rows. Sound tokens read as in the short mix;
+    an altered token still fails."""
+    line = run_tiny(tiny_long_root, "gpt2_medium.chat", seconds=2.0)
+    assert line["failed"] == 0 and line["attempted"] >= 10
+    assert line["correct"] is True
+    row = line["compared"]["served_gap"]
+    assert row["value"] <= row["limit"]
+    token_altered(monkeypatch)
+    line = run_tiny(tiny_long_root, "gpt2_medium.chat", seconds=2.0)
+    assert line["correct"] is False
+
+
 def state_unchanged(monkeypatch):
     import jax
     import jax.numpy as jnp

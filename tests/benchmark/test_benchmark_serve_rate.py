@@ -3,10 +3,14 @@ tokens OF THE REQUESTS DUE IN THE WINDOW that appeared inside it. A model
 of the engine (``submit`` / ``step`` / ``requests`` / ``cfg``; a step
 costs ``chunks x (chunk + host) + (round + host)`` on a stepped clock,
 its tokens seen at its end) is driven by the real ``drive()`` over the
-cell's own schedule (``benchmark/traffic/chat.json`` at the rate and the
-warm-up of ``benchmark/cells/gpt2_medium.chat.json``, a window of
-BENCHMARK.json's ``run_seconds``). No JAX, no chip: the readings are a
-replay of the schedule, never a device number."""
+schedule that PR 26 reasoned about, kept here as constants (the ``chat``
+mix with its answers capped at 256, 1.84 requests/s, 30 s of warm-up, a
+window of 51 s: the cell has moved on since, PR 31, and what these cases
+prove is the definition of the count, not the cell). No JAX, no chip: the
+readings are a replay of the schedule, never a device number.
+
+Also here, on the same model engine and stepped clock: a traced run's
+window closes without holding the loop (PR 31)."""
 
 import functools
 import os
@@ -24,9 +28,18 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 from benchmark import run as bench_run  # noqa: E402
-from benchmark.harness import serve_window, traffic  # noqa: E402
+from benchmark.harness import (serve_model_window,  # noqa: E402
+                               serve_window, traffic)
 
 CELL = "gpt2_medium.chat"
+
+#: the schedule of PR 26: ``benchmark/traffic/chat.json`` and
+#: ``benchmark/cells/gpt2_medium.chat.json`` as they stood then
+PR26_MIX = {"kind": "serve", "base_seed": 20260941,
+            "prompt": {"mean": 69.5, "min": 4, "max": 768},
+            "answer": {"mean": 214.5, "min": 4, "max": 256}}
+PR26_CELL = {"rate_per_s": 1.84, "warmup_seconds": 30, "drain_limit_s": 60}
+PR26_SECONDS, VOCAB, MAX_LEN = 51, 50257, 1024
 
 #: (decode round, prefill chunk) in ms, from today's (ledger, PR 25:
 #: 117.1 / 71.1) down to what PERF.md section 7 forecasts for the engine
@@ -107,13 +120,13 @@ def tokens_of_all_clients(clients, t_open, t_close):
 
 @functools.lru_cache(maxsize=None)
 def replay(round_ms, chunk_ms):
-    """(the new count, the count before PR 26) in tokens/s over the
-    cell's own schedule and window."""
-    bench, _, config, mix, own = bench_run.find_cell(ROOT, CELL)
-    warm, seconds = own["warmup_seconds"], bench["run_seconds"]
-    items = traffic.serve_schedule(
-        mix, own["rate_per_s"], config["shapes"]["vocab_size"],
-        config["engine"]["max_len"], 1, warm + seconds)
+    """(the new count, the count before PR 26) in tokens/s over PR 26's
+    schedule and window."""
+    mix, own = PR26_MIX, PR26_CELL
+    warm, seconds = own["warmup_seconds"], PR26_SECONDS
+    items = traffic.serve_schedule(mix, own["rate_per_s"], VOCAB, MAX_LEN,
+                                   1, warm + seconds)
+    assert sum(it["max_new"] for it in items if it["due"] >= warm) == 14164
     schedule = [serve_window.Client(it, measured=it["due"] >= warm)
                 for it in items]
     clock = SteppedClock()
@@ -219,3 +232,106 @@ def test_measure_reports_the_new_count_and_logs_the_old():
     assert out["failed"] == 0 and out["attempted"] > 20
     assert out["e2e"]["serve_tokens_per_s"] == pytest.approx(own_tokens / 0.4)
     assert 0 < own_tokens < all_tokens
+
+
+class SlowTracer:
+    """A profiler whose stop takes 3 s of the stepped clock, as
+    ``jax.profiler.stop_trace`` takes seconds of the real one.
+    ``holds_the_loop`` is the harness before PR 31: the whole stop at the
+    window's close, inside the loop."""
+
+    def __init__(self, clock, holds_the_loop=False):
+        self.clock, self.holds_the_loop = clock, holds_the_loop
+        self.calls = []
+
+    def start(self):
+        self.calls.append(("start", self.clock()))
+
+    def close_window(self):
+        self.calls.append(("close_window", self.clock()))
+        if self.holds_the_loop:
+            self.clock.work(3.0)
+
+    def stop(self):
+        self.calls.append(("stop", self.clock()))
+        if not self.holds_the_loop:
+            self.clock.work(3.0)
+
+
+def traced_window(window, cell_name, holds_the_loop):
+    """``measure()`` of ``window`` in a traced run on the stepped clock:
+    rounds of 2 ms, chunks of 1 ms, 200 requests/s. Returns (the run, the
+    tracer, the drain's end on the clock)."""
+    _, _, config, mix, _ = bench_run.find_cell(ROOT, cell_name)
+    mix = dict(mix, prompt={"mean": 24, "min": 4, "max": 96},
+               answer={"mean": 10, "min": 2, "max": 32})
+    clock = SteppedClock()
+    tracer = SlowTracer(clock, holds_the_loop)
+    ctx = {"config": config, "traffic": mix, "log": lambda msg: None,
+           "seed": 1, "trace": True, "tracer": tracer,
+           "compiles": SimpleNamespace(compiles=0)}
+    cell = {"rate_per_s": 200.0, "warmup_seconds": 0.1, "drain_limit_s": 5}
+    engine = ModelEngine(clock.work, 2e-3, 1e-3, 0.0)
+    out = window.measure(engine, ctx, cell, 0.4, clock=clock,
+                         sleep=clock.sleep)
+    return out, tracer
+
+
+@pytest.mark.parametrize("window, cell_name", [
+    (serve_window, "gpt2_medium.chat"),
+    (serve_model_window, "jamba2_3b.chat_1k")])
+def test_a_traced_window_closes_without_holding_the_loop(window, cell_name):
+    """The profiler's stop (3 s here) comes after the drain: no request
+    running at the window's close waits it out, so a traced run's mean gap
+    stays a round's (2 ms, and 1 ms a chunk admitted before it). The
+    control is the harness before PR 31, whose stop held the loop: every
+    request then running got one gap of 3 s."""
+    out, tracer = traced_window(window, cell_name, holds_the_loop=False)
+    assert out["failed"] == 0 and out["attempted"] > 20
+    assert [c[0] for c in tracer.calls] == ["start", "close_window", "stop"]
+    assert out["facts"]["gap_mean_ms"] < 2 * 2.0 + 1.0
+    # the facts' window is what the loop saw: 0.4 s, not 3.4
+    assert out["facts"]["window_s"] == pytest.approx(0.4, abs=0.01)
+    # the drain was over when the profiler stopped: the stop is the last
+    # thing on the clock
+    assert tracer.calls[2][1] > tracer.calls[1][1]
+    held, _ = traced_window(window, cell_name, holds_the_loop=True)
+    assert held["failed"] == 0
+    assert held["facts"]["gap_mean_ms"] > 4 * out["facts"]["gap_mean_ms"]
+
+
+def test_the_tracer_closes_its_window_apart_from_the_profilers_stop(
+        tmp_path, monkeypatch):
+    """``Tracer.close_window`` ends ``bench.window`` and touches no
+    profiler; ``stop`` closes it if it is still open (the training window
+    calls ``stop`` alone) and stops the profiler once."""
+    import jax
+    from benchmark.harness import tracing
+    events = []
+    monkeypatch.setattr(jax.profiler, "start_trace",
+                        lambda *a, **k: events.append("start_trace"))
+    monkeypatch.setattr(jax.profiler, "stop_trace",
+                        lambda: events.append("stop_trace"))
+
+    class Annotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            events.append("open " + self.name)
+
+        def __exit__(self, *exc):
+            events.append("close " + self.name)
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    t = tracing.Tracer(str(tmp_path), "cell")
+    t.start()
+    t.close_window()
+    assert events == ["start_trace", "open bench.window",
+                      "close bench.window"]
+    t.stop()
+    assert events[3:] == ["stop_trace"]
+    del events[:]
+    t.start()
+    t.stop()
+    assert events == ["start_trace", "open bench.window",
+                      "close bench.window", "stop_trace"]

@@ -115,3 +115,45 @@ def test_recorded_v5e_trace_reduces_to_what_the_chip_run_read():
         assert 0 < read(name) < 100
     assert read("device.idle_share.train") < 1.0
     assert tr.top_device_ops(t)[0][0] == "mlp bf16[8192,1024]"
+
+
+def test_loading_leaves_out_the_device_events_after_the_windows_close(
+        monkeypatch):
+    """A serving run's profiler stops after the drain (PR 31), so its
+    ``.xplane.pb`` goes on past ``bench.window``. ``load_xplane`` keeps
+    the device events that begin before the window's end, whatever the
+    order of the planes in the file, and the reductions read what they
+    read from the window alone."""
+    from types import SimpleNamespace as NS
+    import jax.profiler
+
+    def plane(p):
+        return NS(name=p["name"], lines=[
+            NS(name=line["name"], events=[
+                NS(name=n, start_ns=s, duration_ns=d)
+                for n, s, d in line["events"]])
+            for line in p["lines"]])
+    trace = hand_made()
+    whole = tr.busy_and_window(trace), tr.top_device_ops(trace)
+    dev, host = trace["planes"]
+    # the drain: a round after the close at 10 ms, and host work there
+    dev["lines"][0]["events"].append(
+        ["%mlp.2 = bf16[8,8] custom-call(...)", 12 * MS, 2 * MS])
+    dev["lines"][1]["events"].append(["jit_step(1)", 12 * MS, 2 * MS])
+    host["lines"][0]["events"].append(["bench.step", 11 * MS, 4 * MS])
+    host["lines"][0]["events"].append(["not.kept", 1 * MS, 1 * MS])
+    other = {"name": "/host:metadata", "lines": []}
+    monkeypatch.setattr(
+        jax.profiler.ProfileData, "from_file",
+        staticmethod(lambda path: NS(planes=[
+            plane(dev), NS(name="Task Environment", lines=[]), plane(host),
+            plane(other)])))
+    got = tr.load_xplane("any.xplane.pb")
+    assert [p["name"] for p in got["planes"]] == [
+        "/host:CPU", "/host:metadata", "/device:TPU:0"]
+    ops, mods = (line["events"] for line in got["planes"][2]["lines"])
+    assert len(ops) == 4 and len(mods) == 2           # the drain's are out
+    names = [e[0] for e in got["planes"][0]["lines"][0]["events"]]
+    assert names == ["bench.window", "bench.sleep", "bench.step",
+                     "bench.step"]                    # the host's stay
+    assert (tr.busy_and_window(got), tr.top_device_ops(got)) == whole

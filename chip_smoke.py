@@ -224,13 +224,13 @@ def bert_batches(cfg, batch, seq, steps, seed):
     return out
 
 
-def bert_loss_fn(model):
+def bert_loss_fn(model, **loss_kwargs):
     def loss_fn(p, ids, mlm_labels, nsp_labels, mlm_mask, mask_pos,
                 attn_mask):
         return model.apply(
             {"params": p, "state": {}}, ids, mlm_labels, nsp_labels,
             mlm_mask, attention_mask=attn_mask, mask_positions=mask_pos,
-            method="loss"), 0.0
+            method="loss", **loss_kwargs), 0.0
     return loss_fn
 
 
@@ -249,7 +249,8 @@ def gpt_loss_fn(model, **loss_kwargs):
 
 def make_train_step(opt, loss_fn):
     """The Trainer's step contract: ``step(state, *batch) -> (loss,
-    state)`` around ``opt.minimize`` (bench.py's train_step)."""
+    state)`` around ``opt.minimize``. tools/compile_smoke.py compiles
+    this same step for its CPU HLO contracts."""
     def train_step(state, *batch):
         loss, params, opt_state, _ = opt.minimize(
             loss_fn, state["params"], state["opt"], *batch)
@@ -351,7 +352,7 @@ def run_train_phase(name, model_cls, cfg, batches, loss_fn_of,
 
 def phase_train(cfg=None, batch=64, seq=512, steps=5, seed=SEED):
     """BERT-base pretraining: masked flash, bf16 policy, scan over
-    layers (as bench.py sets it), the fused-xent ``.loss()`` entry."""
+    layers, the fused-xent ``.loss()`` entry."""
     from paddle_tpu.models.bert import BertConfig, BertForPretraining
     cfg = cfg or BertConfig.base()
     cfg.dropout = 0.0
@@ -365,8 +366,8 @@ def phase_train(cfg=None, batch=64, seq=512, steps=5, seed=SEED):
 
 
 def phase_train_causal(cfg=None, batch=16, seq=512, steps=3, seed=SEED):
-    """GPT-small causal LM, the step bench_gpt builds: causal flash
-    forward and backward, fused xent over rows = batch * (seq - 1)."""
+    """GPT-small causal LM: causal flash forward and backward, fused
+    xent over rows = batch * (seq - 1)."""
     from paddle_tpu.models.gpt import GPT, GPTConfig
     cfg = cfg or GPTConfig.small()
     cfg.dropout = 0.0
@@ -516,9 +517,8 @@ def phase_serve(cfg=None, kv="bf16", slots=8, page=64, prefill_len=128,
 # ---------------------------------------------------- four-chip phase
 
 def phase_mesh(cfg=None, batch=16, seq=512, steps=3, seed=SEED):
-    """The dp2 x tp2 GPT-small fused sharded ``.loss()`` step (bench.py
-    ``--mesh dp2,tp2``) against the one-chip step: same weights, same
-    global batch."""
+    """The dp2 x tp2 GPT-small fused sharded ``.loss()`` step against
+    the one-chip step: same weights, same global batch."""
     import jax
     from jax.sharding import NamedSharding, PartitionSpec
 

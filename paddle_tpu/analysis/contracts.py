@@ -489,8 +489,8 @@ class ShardedCase:
     """Compile shapes for one model's dp x tp contract run. The depth
     fields (layers/heads/intermediate/max_position) are only filled for
     models with priced budget rows — they must mirror the tiny config
-    bench.py compiles (a drift-guard test in tests/test_lint.py pins the
-    gpt row to GPTConfig.tiny)."""
+    tools/compile_smoke.py ``train_program`` compiles (a drift-guard test
+    in tests/test_lint.py pins the gpt row to GPTConfig.tiny)."""
     batch: int
     seq: int
     vocab: int
@@ -769,7 +769,7 @@ def serve_decode_int8_contracts():
 CONTRACTS = {
     "train.gpt@dp2,tp2": (sharded_train_contracts("gpt")
                           + train_budget_contracts("gpt")),
-    # autoplan-resolved mesh (bench --mesh auto on 4 virtual devices):
+    # autoplan-resolved mesh (mesh="auto" on 4 virtual devices):
     # the planner may pick any dp in {1, 2, 4}; dp=4 gives the smallest
     # per-shard row count, so this row is the strictest of the three
     "train.gpt@auto": sharded_train_contracts("gpt", dp=4),

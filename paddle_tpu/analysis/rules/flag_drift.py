@@ -57,7 +57,7 @@ class FlagDrift(Rule):
 
     DEFAULT_FLAGS_PATH = "paddle_tpu/core/flags.py"
     DEFAULT_README_PATH = "README.md"
-    DEFAULT_SCOPE = ("paddle_tpu/**/*.py", "paddle_tpu/*.py", "bench.py",
+    DEFAULT_SCOPE = ("paddle_tpu/**/*.py", "paddle_tpu/*.py",
                      "tools/*.py", "examples/*.py", "tests/*.py")
 
     def __init__(self, flags_path=None, readme_path=None, scope=None):

@@ -287,7 +287,7 @@ class TracerLeak(Rule):
     help = ("python if/while/bool() over traced values inside functions "
             "staged by jax.jit / lax.scan / shard_map / pl.pallas_call")
 
-    DEFAULT_SCOPE = ("paddle_tpu/**/*.py", "paddle_tpu/*.py", "bench.py",
+    DEFAULT_SCOPE = ("paddle_tpu/**/*.py", "paddle_tpu/*.py",
                      "tools/*.py", "examples/*.py")
 
     def __init__(self, scope=None):

@@ -1,7 +1,7 @@
 """Where the persistent XLA compilation cache lives.
 
 One rule, for every entry point that compiles at real size
-(chip_smoke.py, bench.py, the examples): where the environment names a
+(chip_smoke.py, benchmark/run.py, the examples): where the environment names a
 directory (``JAX_COMPILATION_CACHE_DIR``), JAX has already read it and
 this module sets none in code; otherwise the cache is
 ``<checkout>/.jax_cache`` — a fixed path, because the path is part of

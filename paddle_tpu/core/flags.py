@@ -57,11 +57,6 @@ def all_flags():
 # --- framework flags (counterparts cited to reference flags.cc) ---
 # ref flags.cc:44 FLAGS_check_nan_inf — validate op outputs for NaN/Inf
 define_flag("check_nan_inf", False, "Check outputs of every op for NaN/Inf.")
-# ref flags.cc:308 allocator_strategy — PJRT owns allocation on TPU; kept for
-# host-staging arena selection
-define_flag("host_pinned_staging", True, "Use pinned host staging buffers.")
-# default compute dtype for AMP-less training
-define_flag("default_dtype", "float32", "Default floating point dtype.")
 # matmul precision on TPU MXU: 'default' | 'high' | 'highest'
 define_flag("matmul_precision", "default", "jax.lax matmul precision.")
 # conv2d fast backward (physically-transposed dgrad kernels, ~3x on TPU).

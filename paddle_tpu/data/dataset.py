@@ -3,7 +3,7 @@
 Ref: /root/reference/python/paddle/fluid/dataset.py (InMemoryDataset /
 QueueDataset for PS training over files) and python/paddle/dataset/* builtin
 dataset loaders. Here: a light InMemoryDataset with global-shuffle semantics
-plus synthetic generators used by tests and bench.py (no network egress).
+plus synthetic generators used by tests and examples (no network egress).
 """
 
 import numpy as np

@@ -22,8 +22,8 @@ Reference-framework ancestry (what each piece re-architects):
                 session is on, the bounded in-memory span store
                 (start, end, parent, request id, counts).
   perf.py       peak-FLOPs table + XLA cost-analysis + device memory
-                stats (moved from bench.py so bench rows, step records,
-                and tools/run_report.py share one MFU arithmetic).
+                stats (step records, the autoplan calibration and
+                tools/run_report.py share one MFU arithmetic).
   telemetry.py  TelemetryConfig/StepTelemetry — opt-in per-step records
                 (wall time, tokens/s, MFU, trailing-fetch loss, HBM
                 peaks) emitted from static/trainer.py with no device
@@ -101,18 +101,3 @@ def __getattr__(name):
     globals()[name] = val   # cache: subsequent accesses skip __getattr__
     return val
 
-
-def bench_telemetry():
-    """The self-describing `telemetry` field for bench.py JSON rows:
-    the registry's counter snapshot plus step-time p50/p95 (ms) from the
-    `bench.step_time_s` histogram `_timed_steps` feeds."""
-    snap = metrics.snapshot()
-    out = {"counters": snap.get("counters", {})}
-    h = metrics.registry().get("bench.step_time_s")
-    st = h.stats() if h is not None else None
-    if st:
-        out["step_time_ms"] = {
-            "p50": round(st["p50"] * 1e3, 3),
-            "p95": round(st["p95"] * 1e3, 3),
-            "n": st["count"]}
-    return out

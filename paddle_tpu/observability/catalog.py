@@ -40,9 +40,6 @@ CATALOG = {
         "Optimizer updates the loss scaler skipped on non-finite "
         "gradients (delta-published from the scaler state's cumulative "
         "skip count)."),
-    # bench.py
-    "bench.step_time_s": MetricSpec(
-        "histogram", (), "Per-step wall time of a timed bench window."),
     # io/checkpoint.py
     "checkpoint.corrupt_leaves": MetricSpec(
         "counter", (),

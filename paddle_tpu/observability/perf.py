@@ -1,8 +1,9 @@
-"""Hardware-peak and cost-analysis helpers shared by bench.py and the
-Trainer's step telemetry.
+"""Hardware-peak and cost-analysis helpers for the Trainer's step
+telemetry.
 
-MFU arithmetic has ONE home: the bench rows, the per-step RunLog records,
-and tools/run_report.py all compute achieved/peak from the same table
+MFU arithmetic has ONE home: the per-step RunLog records, the autoplan
+calibration and tools/run_report.py all compute achieved/peak from the
+same table
 (autoplan/topology.py's chip table, keyed by the chip JAX reports). jax
 is imported lazily.
 """

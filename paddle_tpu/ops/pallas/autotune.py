@@ -12,8 +12,7 @@ check.
 
 Inside a jit trace there is nothing to time, so a cache miss under
 tracing quietly returns the static defaults — sweeps happen eagerly
-(first un-jitted call, ``tools/autotune.py``, or ``bench.py
---autotune``).
+(first un-jitted call, or ``tools/autotune.py``).
 
 The cache doubles as the cost model's measurement feed: entries record
 the candidate's achieved time and, when the caller supplies it, the

@@ -17,7 +17,7 @@ collective cost model, pick the argmin):
 
 Entry points elsewhere: ``fleet.auto_plan(...)`` +
 ``distributed_optimizer(strategy="auto")``, ``Trainer(mesh_plan=...)``,
-``bench.py --mesh auto``, and the ``tools/autoplan.py`` CLI.
+``tools/compile_smoke.py --autoplan``, and the ``tools/autoplan.py`` CLI.
 """
 
 from paddle_tpu.parallel.autoplan.costmodel import (  # noqa: F401

@@ -12,9 +12,10 @@ planner refused a mesh, not just that it did.
 The winning MeshPlan is the one object the rest of the framework
 consumes: `fleet.distributed_optimizer(strategy="auto")`,
 `Trainer(mesh_plan=...)`, model `.loss(mesh_plan=...)`, and
-`bench.py --mesh auto` all resolve mesh axes, per-param PartitionSpecs
-(via the DistributionPlanner emission layer -> autoplan/layouts.py),
-and loss sharding kwargs from it. JSON-serializable end to end.
+`tools/compile_smoke.py --autoplan` all resolve mesh axes, per-param
+PartitionSpecs (via the DistributionPlanner emission layer ->
+autoplan/layouts.py), and loss sharding kwargs from it.
+JSON-serializable end to end.
 
 Stdlib-only at import; jax enters lazily through build_mesh()/place().
 """
@@ -257,7 +258,7 @@ class MeshPlan:
 
     # -- inspection ---------------------------------------------------
     def summary(self):
-        """Compact record for bench rows / run logs."""
+        """Compact record for reports / run logs."""
         out = {"axes": dict(self.axes), "schedule": self.schedule,
                "microbatches": self.microbatches,
                "topology": self.topology.name,
@@ -337,7 +338,7 @@ def plan(spec, topology=None, devices=None, allow_pp=True,
     """Search dp x tp x pp factorizations of the device count and return
     the argmin-predicted-step-time :class:`MeshPlan`.
 
-    `devices` overrides the topology's chip count (e.g. bench planning
+    `devices` overrides the topology's chip count (e.g. planning
     over the live `jax.devices()` while a preset supplies per-chip
     characteristics). `allow_pp=False` prunes pipeline candidates with
     a recorded reason — for callers whose train step has no pipeline

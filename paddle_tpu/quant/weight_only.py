@@ -13,7 +13,7 @@ GPT tied head): the int8 tensor stays resident in HBM and feeds a
 mixed-dtype `lax.dot_general` (or a gathered-row dequant for lookups),
 so weight HBM traffic drops 2x vs bf16 / 4x vs f32 — the lever for
 weight-bandwidth-bound serving (KV-cache decode reads every parameter
-once per token; see bench.py gpt_decode).
+once per token).
 
 Scale axes are chosen so the dequant is algebraically EXACT on the
 consuming contraction (no fake-quant round trip at serve time):

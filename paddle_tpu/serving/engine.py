@@ -399,7 +399,7 @@ class ServingEngine:
                 from paddle_tpu.observability.runlog import RunLog
                 self._run_log = RunLog(cfg.run_log)
                 self._own_run_log = True
-            else:                      # an already-open RunLog (bench.py)
+            else:                      # an already-open RunLog: the caller's
                 self._run_log = cfg.run_log
         if self._run_log is not None:
             # wall/monotonic anchor: the fleet-trace merge rebases this

@@ -280,14 +280,15 @@ class TestConsumption:
 
 @pytest.mark.perf
 def test_autoplan_mesh_hlo_contract():
-    """Acceptance gate: the planner-resolved mesh (bench --mesh auto on
-    the cpu4 topology) compiles AND its per-device HLO passes the
+    """Acceptance gate: the planner-resolved mesh (mesh="auto" on the
+    cpu4 topology) compiles AND its per-device HLO passes the
     train.gpt@auto CONTRACTS row — same NoTemporary / no-vocab-all-gather
     judgments as the hand-picked dp2,tp2 row."""
     import tools.compile_smoke as cs
-    out = cs.autoplan_check(model="gpt", topology="cpu4", timeout=420)
+    out = cs.autoplan_check(model="gpt", topology="cpu4")
     assert out["clean"], out["violations"]
     assert out["plan"]["topology"] == "cpu4"
+    assert out["mesh"] == out["plan"]["axes"]
     n = 1
     for v in out["plan"]["axes"].values():
         n *= v

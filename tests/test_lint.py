@@ -410,7 +410,7 @@ def test_budget_rows_are_priced_by_the_cost_model():
 def test_sharded_case_gpt_matches_tiny_config():
     """Drift guard: the budget pricing reuses the gpt ShardedCase depth
     fields as the cost-model spec, so they must mirror GPTConfig.tiny
-    (what bench.py --tiny actually compiles)."""
+    (what tools/compile_smoke.py train_program compiles)."""
     from paddle_tpu.models.gpt import GPTConfig
     cfg = GPTConfig.tiny()
     case = contracts.SHARDED_TRAIN_CASES["gpt"]

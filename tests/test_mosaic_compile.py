@@ -4,8 +4,8 @@ Every other kernel test runs the Pallas interpreter, which cannot see a
 tile the Mosaic compiler refuses or a kernel that wants too much VMEM.
 The TPU compiler is installed beside jax and compiles for a chip that is
 described, not attached (``on-chip-measurement`` guide, section 2), so
-these cases lower each kernel family at the widths chip_smoke.py and
-bench.py run — BERT-base / GPT-small: hidden 768, 12 heads of 64, FFN
+these cases lower each kernel family at the widths chip_smoke.py
+runs — BERT-base / GPT-small: hidden 768, 12 heads of 64, FFN
 3072, vocab 30522 / 32000 — and assert the compiled module really holds
 the Mosaic kernel. Each would have caught a refusal this tree once had:
 erfc inside the fused MLP, a row-less batched dot and (1, page) scale

@@ -1,5 +1,5 @@
 """Fused layer-norm: numpy-golden parity + gradient correctness (the Pallas
-TPU path itself is exercised by bench.py on hardware; CPU runs the XLA twin
+TPU path itself is exercised by chip_smoke.py on hardware; CPU runs the XLA twin
 of the same single implementation behind ops.nn.layer_norm)."""
 
 import jax

@@ -316,8 +316,7 @@ class TestWeightOnlyInt8:
 
     def test_gpt_decode_int8_matches_float(self):
         """End-to-end: GPT decode with int8-resident weights — logits
-        within ~2% and identical greedy continuations (the bench.py
-        PT_BENCH_INT8_DECODE path)."""
+        within ~2% and identical greedy continuations."""
         from paddle_tpu.models.gpt import GPTConfig, GPTDecoder
         cfg = GPTConfig.tiny()
         cfg.dropout = 0.0

@@ -175,8 +175,8 @@ class TestServeReport:
 
 
 def _fleet_records():
-    """A PT_BENCH_FLEET_RAMP-style row (ops_log + version_stats +
-    curve) plus one raw ops event record."""
+    """A ramp row (ops_log + version_stats + curve) plus one raw ops
+    event record."""
     ops = [
         {"event": "deploy_start", "t": 10.0, "at_step": 3,
          "version": "v1", "canary": False, "targets": [0, 1]},

@@ -5,9 +5,9 @@ on a topology, no accelerator (and no jax) required.
 The search+cost-model live in paddle_tpu/parallel/autoplan/; this tool
 is the operator front door: a human ranked-candidate table (every
 pruned factorization with its recorded reason) plus the repo-standard
-last-line JSON row for scripting. `bench.py --mesh auto` consumes the
-same plan at run time; this tool answers "what would it pick, and why"
-ahead of time.
+last-line JSON row for scripting. `Trainer(mesh_plan=...)` and
+`tools/compile_smoke.py --autoplan` consume the same plan at run time;
+this tool answers "what would it pick, and why" ahead of time.
 
 Usage:
   python tools/autoplan.py --model gpt --topology cpu4

@@ -1,238 +1,256 @@
 #!/usr/bin/env python
 """CI smoke: prove the fused train step jit-compiles — on the CPU backend.
 
-Runs ``python bench.py --compile-only --model <m>`` on the CPU backend and
-asserts the compile-marker row lands. This is the tier-1 guard for the
-step-fusion layer: the chunked fused cross-entropy (custom VJP), the
-scan-over-layers + remat encoders, and the fused add+LN path all have to
-lower and compile inside one jitted train step — a regression in any of
-them trips here, not in the next chip run. CPU-only: it pins
-JAX_PLATFORMS=cpu for itself and its children (what the chip's compiler
-accepts is tests/test_mosaic_compile.py's business).
+``train_program`` builds one model's tiny train step in this process,
+from the builders chip_smoke.py runs on the chip (``make_train_step``
+over the model's ``.loss()`` binder, the bf16-policy Adam), and compiles
+it for the CPU. This is the tier-1 guard for the step-fusion layer: the
+chunked fused cross-entropy (custom VJP), the scan-over-layers + remat
+encoders, and the fused add+LN path all have to lower and compile
+inside one jitted train step — a regression in any of them trips here,
+not in the next chip run (what the chip's compiler accepts is
+tests/test_mosaic_compile.py's business).
 
-The sharded mode (--mesh dp2,tp2) additionally compiles the dp x tp GSPMD
-train step on fake CPU devices and evaluates the model's CONTRACTS row
-(paddle_tpu/analysis/contracts.py) against the compiled (post-SPMD,
+With a mesh (``dp2,tp2``) it compiles the dp x tp GSPMD step on virtual
+CPU devices, and ``sharded_vocab_check`` evaluates the model's CONTRACTS
+row (paddle_tpu/analysis/contracts.py) against the compiled (post-SPMD,
 per-device shapes) HLO: no [rows, V]-scale temporary, no all-gather of
-the vocab-sharded projection weight, no f64, no host callback.
-`sharded_vocab_check` wraps the full contract — the fused run must be
-clean, a PT_FUSED_XENT=0 positive-control run must trip the detector
-(proving the judge actually detects full-vocab logits). This tool
-compiles; the contract engine judges.
+the vocab-sharded projection weight, no f64, no host callback. The
+fused run must be clean, and a ``fused_xent=False`` positive-control run
+must trip the detector (proving the judge actually detects full-vocab
+logits). This tool compiles; the contract engine judges.
 
 Usage:
-  python tools/compile_smoke.py                  # gpt, full-size config
-  python tools/compile_smoke.py --tiny           # tiny config (CI budget)
+  python tools/compile_smoke.py --model gpt --tiny
   python tools/compile_smoke.py --model bert --tiny
   python tools/compile_smoke.py --model gpt --tiny --mesh dp2,tp2 --hlo-check
+  python tools/compile_smoke.py --model gpt --autoplan cpu4
 """
 
 import argparse
+import contextlib
 import json
 import math
 import os
 import re
-import subprocess
 import sys
-import tempfile
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if REPO not in sys.path:           # CLI use; in-suite runs already see it
+    sys.path.insert(0, REPO)
+
+_DEVICE_COUNT_FLAG = "--xla_force_host_platform_device_count"
 
 
-def _mesh_devices(mesh):
-    """Device count a '--mesh dp2,tp2' spec needs (explicit sizes only)."""
-    n = 1
-    for part in mesh.split(","):
-        m = re.fullmatch(r"([a-z]+)(\d+)", part.strip())
+def want_cpu_devices(n):
+    """Hold JAX to the CPU with ``n`` virtual devices. For a command's
+    entry point: it only takes effect before the first ``import jax``
+    (``train_program`` raises when the devices are not there)."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    flags = [f for f in os.environ.get("XLA_FLAGS", "").split()
+             if not f.startswith(_DEVICE_COUNT_FLAG)]
+    os.environ["XLA_FLAGS"] = " ".join(
+        flags + [f"{_DEVICE_COUNT_FLAG}={n}"])
+
+
+def _parse_mesh(spec):
+    """'dp2,tp2' -> {"dp": 2, "tp": 2}; 'auto' and None pass through."""
+    if spec is None or spec == "auto":
+        return spec
+    axes = {}
+    for part in spec.split(","):
+        m = re.fullmatch(r"(dp|tp)(\d+)", part.strip())
         if not m:
-            raise SystemExit(f"--mesh {mesh!r}: compile_smoke needs "
-                             "explicit sizes (e.g. dp2,tp2)")
-        n *= int(m.group(2))
-    return n
+            raise ValueError(f"mesh {spec!r}: want dp and tp with "
+                             "explicit sizes (e.g. dp2,tp2) or 'auto'")
+        axes[m.group(1)] = int(m.group(2))
+    return {"dp": 1, "tp": 1, **axes}
 
 
-def run(model="gpt", tiny=False, timeout=600, extra_env=None, mesh=None,
-        batch=None, seq=None, dump_hlo=None, devices=None):
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"            # a CPU-HLO contract tool
-    if mesh:
-        # '--mesh auto' has no explicit sizes; the caller must say how
-        # many fake devices to fabricate (devices=)
-        n = devices if devices is not None else _mesh_devices(mesh)
-        flags = " ".join(
-            f for f in env.get("XLA_FLAGS", "").split()
-            if not f.startswith("--xla_force_host_platform_device_count"))
-        env["XLA_FLAGS"] = (flags + " --xla_force_host_platform_device_"
-                            f"count={n}").strip()
-    env.update(extra_env or {})
-    args = [sys.executable, os.path.join(REPO, "bench.py"),
-            "--compile-only", "--model", model]
-    if tiny:
-        args.append("--tiny")
-    if mesh:
-        args += ["--mesh", mesh]
-    if batch:
-        args += ["--batch", str(batch)]
-    if seq:
-        args += ["--seq", str(seq)]
-    if dump_hlo:
-        args += ["--dump-hlo", dump_hlo]
-    proc = subprocess.run(args, stdout=subprocess.PIPE, text=True,
-                          timeout=timeout, env=env, cwd=REPO)
-    lines = proc.stdout.strip().splitlines()
-    if not lines:
-        raise SystemExit(f"no bench output (rc={proc.returncode})")
-    row = json.loads(lines[-1])
-    if not str(row.get("metric", "")).endswith("_compile_only"):
-        raise SystemExit(f"fused step failed to compile: {row}")
-    return row
+def _topology_devices(topology):
+    m = re.fullmatch(r"(?:\d+x)?[a-z0-9]+?-?(\d+)", topology)
+    if not m:
+        raise SystemExit(f"unparseable topology {topology!r}")
+    return int(m.group(1))
 
 
-# The HLO judgments live in paddle_tpu/analysis/contracts.py now; this
-# tool keeps thin same-signature wrappers (and the compile plumbing).
-# The engine is loaded straight from its file so the subprocess-only
-# paths never pay the jax import in paddle_tpu/__init__.
-_contracts_mod = None
+def _transformer_loss_fn(model, **loss_kwargs):
+    def loss_fn(p, src, tgt_in, tgt_out):
+        return model.apply({"params": p, "state": {}}, src, tgt_in, tgt_out,
+                           method="loss", **loss_kwargs), 0.0
+    return loss_fn
 
 
-def _contracts():
-    global _contracts_mod
-    if _contracts_mod is None:
-        mod = sys.modules.get("paddle_tpu.analysis.contracts")
-        if mod is None:
-            import importlib.util
-            path = os.path.join(REPO, "paddle_tpu", "analysis",
-                                "contracts.py")
-            spec = importlib.util.spec_from_file_location(
-                "paddle_tpu.analysis.contracts", path)
-            mod = importlib.util.module_from_spec(spec)
-            spec.loader.exec_module(mod)
-        _contracts_mod = mod
-    return _contracts_mod
+def _tiny_model(name, batch, seq, remat):
+    """(model, its vocab-sharded dimension, loss binder, one host batch)
+    at the tiny config, set up as the chip phases set theirs: no dropout,
+    scan over layers, positions to cover ``seq``."""
+    import numpy as np
+
+    import chip_smoke
+    if name == "transformer_big":
+        from paddle_tpu.models.transformer import (Transformer,
+                                                   TransformerConfig)
+        cfg = TransformerConfig.tiny()
+        cfg.dropout, cfg.max_len = 0.0, max(cfg.max_len, seq)
+        rng = np.random.RandomState(chip_smoke.SEED)
+        return (Transformer(cfg), cfg.tgt_vocab, _transformer_loss_fn, tuple(
+            rng.randint(1, vocab, (batch, seq)).astype(np.int32)
+            for vocab in (cfg.src_vocab, cfg.tgt_vocab, cfg.tgt_vocab)))
+    if name == "gpt":
+        from paddle_tpu.models.gpt import GPT as cls, GPTConfig as cfg_cls
+        binder, batches = chip_smoke.gpt_loss_fn, chip_smoke.gpt_batches
+    else:
+        from paddle_tpu.models.bert import (BertConfig as cfg_cls,
+                                            BertForPretraining as cls)
+        binder, batches = chip_smoke.bert_loss_fn, chip_smoke.bert_batches
+    cfg = cfg_cls.tiny()
+    cfg.dropout, cfg.scan_layers = 0.0, True
+    cfg.max_position = max(cfg.max_position, seq)
+    if remat:
+        cfg.remat = remat
+    return (cls(cfg), cfg.vocab_size, binder,
+            batches(cfg, batch, seq, 1, chip_smoke.SEED)[0])
 
 
-def vocab_temporaries(hlo_text, vocab, tp, min_rows):
-    """Materialized [rows, vocab]-scale logits temporaries (global V or
-    the V/tp shard) — thin caller of the NoTemporary contract; min_rows
-    sits ABOVE the model width so the [V/tp, H] weight shard (a
-    legitimate vocab-axis resident) never trips it."""
-    c = _contracts()
-    return c.NoTemporary({vocab, vocab // tp}, min_rows).temporaries(
-        hlo_text)
+def train_program(model="gpt", mesh=None, remat=None, flags=None,
+                  devices=None):
+    """Compile ``model``'s tiny fused train step for the CPU, in this
+    process, and return what the judges read: ``{"hlo": the compiled
+    (per-device) module's text, "cost": its normalised cost analysis,
+    "mesh": the resolved axes or None, "plan": the autoplan summary for
+    mesh="auto"}``. Batch and sequence come from
+    ``contracts.SHARDED_TRAIN_CASES[model]``; ``mesh`` is None, explicit
+    sizes ('dp2,tp2') or 'auto' (the planner picks over ``devices``
+    devices of the ``autoplan_topology`` flag's topology); ``remat``
+    goes on the config; ``flags`` hold for the build only."""
+    import jax
 
-
-def weight_all_gathers(hlo_text, vocab, hidden):
-    """all-gather ops whose result carries the full global-vocab dim at
-    weight scale (GSPMD re-assembled the vocab-sharded projection
-    weight) — thin caller of the NoOpMatching contract."""
-    c = _contracts()
-    return c.NoOpMatching(
-        "all-gather",
-        shape_test=lambda shp: (vocab in shp
-                                and math.prod(shp) >= vocab * hidden),
-    ).matches(hlo_text)
+    import chip_smoke
+    import paddle_tpu as pt
+    from paddle_tpu.analysis import contracts as c
+    case = c.SHARDED_TRAIN_CASES[model]
+    axes = _parse_mesh(mesh)
+    if axes == "auto":
+        need = devices or len(jax.devices())
+    else:
+        need = math.prod(axes.values()) if axes else 1
+    if need > len(jax.devices()):
+        raise RuntimeError(
+            f"mesh {mesh!r} needs {need} devices and this process has "
+            f"{len(jax.devices())}: set XLA_FLAGS={_DEVICE_COUNT_FLAG}="
+            f"{need} (and JAX_PLATFORMS=cpu) before jax is imported")
+    devs = jax.devices()[:need]
+    # one chunk for every train program, far below the contract table's
+    # row thresholds
+    with chip_smoke.flag_scope({"xent_chunk": 64, **(flags or {})}):
+        net, vocab, loss_fn_of, batch = _tiny_model(
+            model, case.batch, case.seq, remat)
+        opt = chip_smoke._amp_optimizer()
+        params = net.init(jax.random.key(chip_smoke.SEED))["params"]
+        loss_kwargs, plan = {}, None
+        if axes == "auto":
+            from paddle_tpu.parallel import autoplan
+            plan = autoplan.plan(
+                autoplan.ModelSpec.from_config(
+                    net.cfg, batch=case.batch, seq=case.seq),
+                topology=autoplan.get_topology(), devices=need,
+                allow_pp=False)
+            axes = {k: int(v) for k, v in plan.axes.items()}
+        if axes:
+            dp, tp = axes.get("dp", 1), axes.get("tp", 1)
+            if case.batch % dp or vocab % tp:
+                raise ValueError(
+                    f"mesh {axes}: train.{model}'s batch {case.batch} "
+                    f"must divide over dp={dp} and its vocab {vocab} "
+                    f"over tp={tp}")
+            if plan:
+                grid = plan.build_mesh(devs)
+                params = plan.place(params)
+            else:
+                grid = pt.parallel.make_mesh(axes, devices=devs)
+                params = pt.parallel.tp_lm_sharding(grid, params)
+            loss_kwargs = {"vocab_axis": "tp" if tp > 1 else None,
+                           "batch_axis": "dp" if dp > 1 else None,
+                           "mesh": grid}
+            batch = pt.parallel.shard_batch(grid, batch)
+        state = {"params": params, "opt": opt.init(params)}
+        step = jax.jit(chip_smoke.make_train_step(
+            opt, loss_fn_of(net, **loss_kwargs)), donate_argnums=(0,))
+        with grid if axes else contextlib.nullcontext():
+            compiled = step.lower(state, *batch).compile()
+    return {"hlo": compiled.as_text(),
+            "cost": c.normalize_cost(compiled.cost_analysis()),
+            "mesh": axes, "plan": plan.summary() if plan else None}
 
 
 def dense_score_temporaries(hlo_text, tmax, min_rows):
     """f32/bf16 temporaries spanning the PADDED slot capacity Tmax —
     the gathered-dense K/V or score tensor the paged Pallas decode path
     must never materialize. Thin caller of the NoTemporary contract."""
-    c = _contracts()
+    from paddle_tpu.analysis import contracts as c
     return c.NoTemporary({tmax}, min_rows).temporaries(hlo_text)
 
 
-def sharded_vocab_check(model="gpt", mesh="dp2,tp2", timeout=600,
-                        positive_control=True, update_snapshots=False):
+def sharded_vocab_check(model="gpt", mesh="dp2,tp2", positive_control=True,
+                        update_snapshots=False):
     """Compile the dp x tp fused train step and evaluate the model's
     full CONTRACTS row (no [rows, V] temporary, no vocab-weight
     all-gather, no f64, no host callback, and — where the row carries
     budget contracts — the XLA cost_analysis flops/bytes priced against
     the autoplan cost model) against its per-device HLO; optionally also
-    compile the PT_FUSED_XENT=0 reference step and require the
-    NoTemporary detector to TRIP on it (positive control). The budget
-    detectors get their own positive control: at tolerance=0 every real
-    compile must exceed a zero budget. When the model has a registered
-    HloSnapshot the compiled op histogram is judged against the blessed
-    record too (``update_snapshots=True`` re-blesses instead)."""
-    c = _contracts()
-    case = c.SHARDED_TRAIN_CASES[model]
-    vocab, hidden = case.vocab, case.hidden
-    min_rows = case.min_rows(dp=2)
-    row_contracts = c.CONTRACTS[f"train.{model}@dp2,tp2"]
-    chunk_env = {"PT_FLAGS_xent_chunk": "64"}
-    out = {"model": model, "mesh": mesh}
-    with tempfile.TemporaryDirectory() as td:
-        fused_hlo = os.path.join(td, "fused.hlo")
-        row = run(model=model, tiny=True, timeout=timeout, mesh=mesh,
-                  batch=case.batch, seq=case.seq, dump_hlo=fused_hlo,
-                  extra_env=chunk_env)
-        text = open(fused_hlo).read()
-        cost = None
-        try:
-            with open(fused_hlo + ".cost.json") as f:
-                cost = c.normalize_cost(json.load(f))
-        except (OSError, ValueError):
-            pass
-        ctx = c.ContractContext(hlo_text=text, cost=cost)
-        violations = c.evaluate(row_contracts, ctx)
-        snap = c.CONTRACT_SNAPSHOTS.get(f"train.{model}@{mesh}")
-        if snap is not None:
-            if update_snapshots:
-                out["snapshot_blessed"] = snap.bless(text)["hash"]
-            else:
-                violations += snap.violations(ctx)
-        out.update(row=row, cost=cost,
-                   vocab_temporaries=vocab_temporaries(
-                       text, vocab, 2, min_rows),
-                   weight_all_gathers=weight_all_gathers(
-                       text, vocab, hidden),
-                   violations=[v.format() for v in violations],
-                   clean=not violations)
-        budgets = [b for b in row_contracts
-                   if isinstance(b, c.MaxHloCost)]
-        if positive_control:
-            ref_hlo = os.path.join(td, "reference.hlo")
-            run(model=model, tiny=True, timeout=timeout, mesh=mesh,
-                batch=case.batch, seq=case.seq, dump_hlo=ref_hlo,
-                extra_env={**chunk_env, "PT_FUSED_XENT": "0"})
-            ref_temps = vocab_temporaries(open(ref_hlo).read(), vocab, 2,
-                                          min_rows)
-            out["positive_control_trips"] = bool(ref_temps)
-            if budgets and cost is not None:
-                out["budget_control_trips"] = all(
-                    b.with_tolerance(0).check(ctx) for b in budgets)
+    compile the ``fused_xent=False`` reference step and require the
+    row's NoTemporary detector to TRIP on it (positive control). The
+    budget detectors get their own positive control: at tolerance=0
+    every real compile must exceed a zero budget. When the row has a
+    registered HloSnapshot the compiled op histogram is judged against
+    the blessed record too (``update_snapshots=True`` re-blesses
+    instead)."""
+    from paddle_tpu.analysis import contracts as c
+    name = f"train.{model}@{mesh}"
+    row = c.CONTRACTS[name]
+    prog = train_program(model, mesh=mesh)
+    ctx = c.ContractContext(hlo_text=prog["hlo"], cost=prog["cost"])
+    violations = c.evaluate(row, ctx)
+    out = {"model": model, "mesh": prog["mesh"], "cost": prog["cost"]}
+    snap = c.CONTRACT_SNAPSHOTS.get(name)
+    if snap is not None:
+        if update_snapshots:
+            out["snapshot_blessed"] = snap.bless(prog["hlo"])["hash"]
+        else:
+            violations += snap.violations(ctx)
+    out.update(violations=[v.format() for v in violations],
+               clean=not violations)
+    if positive_control:
+        detector = next(r for r in row if isinstance(r, c.NoTemporary))
+        ref = train_program(model, mesh=mesh, flags={"fused_xent": False})
+        out["positive_control_trips"] = bool(
+            detector.temporaries(ref["hlo"]))
+        budgets = [b for b in row if isinstance(b, c.MaxHloCost)]
+        if budgets and prog["cost"] is not None:
+            out["budget_control_trips"] = all(
+                b.with_tolerance(0).check(ctx) for b in budgets)
     return out
 
 
-def autoplan_check(model="gpt", topology="cpu4", timeout=600):
-    """Compile ``bench.py --mesh auto`` — the autoplan search resolves
-    the mesh from the named topology on fake CPU devices — and evaluate
-    the model's ``train.<model>@auto`` CONTRACTS row against the
-    compiled per-device HLO. The acceptance gate for the planner: its
-    winning mesh must not just compile, it must compile CLEAN under the
-    same NoTemporary/no-vocab-all-gather judgments as the hand-picked
+def autoplan_check(model="gpt", topology="cpu4"):
+    """Compile the train step on the mesh the autoplan search resolves
+    from the named topology, on virtual CPU devices, and evaluate the
+    model's ``train.<model>@auto`` CONTRACTS row against the compiled
+    per-device HLO. The acceptance gate for the planner: its winning
+    mesh must not just compile, it must compile CLEAN under the same
+    NoTemporary/no-vocab-all-gather judgments as the hand-picked
     dp2,tp2 row."""
-    c = _contracts()
-    case = c.SHARDED_TRAIN_CASES[model]
-    m = re.fullmatch(r"(?:\d+x)?[a-z0-9]+?-?(\d+)", topology)
-    if not m:
-        raise SystemExit(f"unparseable topology {topology!r}")
-    devices = int(m.group(1))
-    env = {"PT_FLAGS_autoplan_topology": topology,
-           "PT_FLAGS_xent_chunk": "64"}
-    out = {"model": model, "topology": topology, "devices": devices}
-    with tempfile.TemporaryDirectory() as td:
-        hlo = os.path.join(td, "auto.hlo")
-        row = run(model=model, tiny=True, timeout=timeout, mesh="auto",
-                  batch=case.batch, seq=case.seq, dump_hlo=hlo,
-                  extra_env=env, devices=devices)
-        text = open(hlo).read()
-        violations = c.evaluate(c.CONTRACTS[f"train.{model}@auto"],
-                                c.ContractContext(hlo_text=text))
-        out.update(row=row, plan=row.get("autoplan"),
-                   violations=[v.format() for v in violations],
-                   clean=not violations)
-    return out
+    from paddle_tpu.analysis import contracts as c
+    devices = _topology_devices(topology)
+    prog = train_program(model, mesh="auto", devices=devices,
+                         flags={"autoplan_topology": topology})
+    violations = c.evaluate(c.CONTRACTS[f"train.{model}@auto"],
+                            c.ContractContext(hlo_text=prog["hlo"]))
+    return {"model": model, "topology": topology, "devices": devices,
+            "mesh": prog["mesh"], "plan": prog["plan"],
+            "violations": [v.format() for v in violations],
+            "clean": not violations}
 
 
 # serve-probe shapes: every dim distinct from TMAX=48 (vocab 512, hidden
@@ -241,7 +259,7 @@ def autoplan_check(model="gpt", topology="cpu4", timeout=600):
 # catches even the [S, H, 1, Tmax] score row of the dense fallback.
 # Canonical values live with the contract table.
 def _serve_dims():
-    c = _contracts()
+    from paddle_tpu.analysis import contracts as c
     return c.SERVE_TMAX, c.SERVE_MIN_ROWS
 
 
@@ -296,12 +314,10 @@ def serve_smoke(positive_control=True, update_snapshots=False):
        the row's TracedOnce.
     """
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    if REPO not in sys.path:       # CLI use; in-suite runs already see it
-        sys.path.insert(0, REPO)
     import numpy as np
     from paddle_tpu.core.flags import all_flags, set_flags
 
-    c = _contracts()
+    from paddle_tpu.analysis import contracts as c
     tmax, min_rows = _serve_dims()
     out = {}
     saved = all_flags()
@@ -533,14 +549,12 @@ def mlp_smoke(positive_control=True):
     under the same judgment.
     """
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    if REPO not in sys.path:       # CLI use; in-suite runs already see it
-        sys.path.insert(0, REPO)
     import jax
     import jax.numpy as jnp
     import numpy as np
     from paddle_tpu.core.flags import all_flags, set_flags
 
-    c = _contracts()
+    from paddle_tpu.analysis import contracts as c
     rows, h, inter = c.MLP_ROWS, c.MLP_HIDDEN, c.MLP_INTER
     rng = np.random.RandomState(0)
 
@@ -584,21 +598,22 @@ def mlp_smoke(positive_control=True):
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--model", default="gpt")
-    ap.add_argument("--tiny", action="store_true")
-    ap.add_argument("--timeout", type=float, default=600)
+    ap.add_argument("--model", default="gpt",
+                    choices=["gpt", "bert", "transformer_big"])
+    ap.add_argument("--tiny", action="store_true",
+                    help="accepted for older command lines: every train "
+                         "program here is the tiny config")
     ap.add_argument("--mesh", default=None,
-                    help="compile the dp x tp sharded step on fake CPU "
+                    help="compile the dp x tp sharded step on virtual CPU "
                          "devices, e.g. dp2,tp2")
     ap.add_argument("--hlo-check", action="store_true",
                     help="with --mesh: enforce the sharded-HLO contract "
                          "(no [rows, V] temporary, no vocab-weight "
                          "all-gather) with a positive control")
     ap.add_argument("--autoplan", metavar="TOPOLOGY", default=None,
-                    help="autoplan probe: resolve the mesh via "
-                         "--mesh auto on the named topology (e.g. cpu4) "
-                         "and enforce the train.<model>@auto HLO "
-                         "contract")
+                    help="autoplan probe: let the planner resolve the "
+                         "mesh on the named topology (e.g. cpu4) and "
+                         "enforce the train.<model>@auto HLO contract")
     ap.add_argument("--mlp", action="store_true",
                     help="fused GLU/MLP probe: the compiled forward "
                          "holds no [rows, 4H] activation temporary "
@@ -610,7 +625,8 @@ def main():
                          "temporary (positive control included)")
     args = ap.parse_args()
     if args.autoplan:
-        out = autoplan_check(args.model, args.autoplan, args.timeout)
+        want_cpu_devices(_topology_devices(args.autoplan))
+        out = autoplan_check(args.model, args.autoplan)
         print(json.dumps(out))
         if not out["clean"]:
             raise SystemExit("autoplan-mesh HLO contract violated")
@@ -627,16 +643,22 @@ def main():
         if not out["ok"]:
             raise SystemExit("serve-step contract violated")
         return
+    if args.mesh == "auto":
+        raise SystemExit("--mesh auto: say --autoplan TOPOLOGY")
+    want_cpu_devices(math.prod((_parse_mesh(args.mesh) or {}).values()))
     if args.hlo_check:
         if not args.mesh:
             raise SystemExit("--hlo-check needs --mesh")
-        out = sharded_vocab_check(args.model, args.mesh, args.timeout)
+        out = sharded_vocab_check(args.model, args.mesh)
         print(json.dumps(out))
         if not out["clean"] or not out.get("positive_control_trips", True):
             raise SystemExit("sharded-HLO contract violated")
         return
-    row = run(args.model, args.tiny, args.timeout, mesh=args.mesh)
-    print(json.dumps(row))
+    prog = train_program(args.model, mesh=args.mesh)
+    print(json.dumps({"model": args.model, "compiled": True,
+                      "mesh": prog["mesh"],
+                      "flops": prog["cost"]["flops"],
+                      "bytes accessed": prog["cost"]["bytes accessed"]}))
 
 
 if __name__ == "__main__":

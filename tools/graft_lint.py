@@ -105,17 +105,21 @@ def _run_contracts(spec, update_snapshots=False):
     models — minutes, and imports jax). Returns findings-shaped dicts.
     ``update_snapshots`` re-blesses the HloSnapshot records instead of
     judging them."""
-    sys.modules.pop("paddle_tpu", None)   # drop the stub: real jax now
     import tools.compile_smoke as cs
-    c = cs._contracts()
+    cs.want_cpu_devices(4)                # the train rows' dp2 x tp2 mesh
+    sys.modules.pop("paddle_tpu", None)   # drop the stub: real jax now
+    from paddle_tpu.analysis import contracts as c
     names = _parse_contract_names(spec, c.CONTRACTS)
     out = []
     for name in names:
         if name.startswith("train."):
-            model = name[len("train."):].split("@")[0]
-            res = cs.sharded_vocab_check(
-                model=model, positive_control=False,
-                update_snapshots=update_snapshots)
+            model, mesh = name[len("train."):].split("@")
+            if mesh == "auto":
+                res = cs.autoplan_check(model=model)
+            else:
+                res = cs.sharded_vocab_check(
+                    model=model, mesh=mesh, positive_control=False,
+                    update_snapshots=update_snapshots)
         else:
             res = cs.serve_smoke(update_snapshots=update_snapshots)
         if "snapshot_blessed" in res:

@@ -27,9 +27,10 @@ step, and per-request speculative-vs-plain accounting (the spec_tokens
 field each retirement carries).
 
 `--fleet` renders the fleet live-ops view: the deploy/scale/canary
-timeline from FleetRouter ops events (raw records, a dumped telemetry
-snapshot's `ops_log`, or a PT_BENCH_FLEET_RAMP=1 bench row), the
-per-version goodput table, and the goodput-vs-offered-load curve.
+timeline from FleetRouter ops events (raw records or a dumped telemetry
+snapshot's `ops_log`), the per-version goodput table, and, from a
+record with a `curve` list, the goodput-vs-offered-load curve (nothing
+in the tree writes such a record now: ROADMAP D11 decides the view).
 
 `--fleet-trace` takes SEVERAL RunLogs (one per replica) and renders the
 distributed-tracing view: the logs merge into one causally ordered
@@ -541,12 +542,11 @@ _FLEET_EVENTS = frozenset((
 def render_fleet_report(records, width=64):
     """The live-ops story of a fleet: the deploy/scale/canary timeline
     (FleetRouter.ops_log events, taken either as raw records or from any
-    record carrying an `ops_log` list — e.g. a dumped telemetry snapshot
-    or a `bench.py gpt_serve_fleet` ramp row) plus the per-version
-    goodput table (`version_stats` snapshot when present, else
-    reconstructed from engine trace `retired` events that carry a
-    version tag) and, when a ramp row is present, the goodput-vs-
-    offered-load curve."""
+    record carrying an `ops_log` list — e.g. a dumped telemetry
+    snapshot) plus the per-version goodput table (`version_stats`
+    snapshot when present, else reconstructed from engine trace
+    `retired` events that carry a version tag) and, when a record
+    carries a `curve` list, the goodput-vs-offered-load curve."""
     ops = [r for r in records if r.get("event") in _FLEET_EVENTS]
     vstats, curve = None, None
     for r in records:
@@ -573,8 +573,7 @@ def render_fleet_report(records, width=64):
     lines = ["=" * 72, "FLEET REPORT", "=" * 72]
     if not ops and vstats is None and curve is None:
         lines.append("\n(no fleet ops events in this RunLog — dump "
-                     "router.telemetry() as a record, or feed a "
-                     "PT_BENCH_FLEET_RAMP=1 bench row)")
+                     "router.telemetry() as a record)")
         return "\n".join(lines + ["=" * 72])
 
     if ops:

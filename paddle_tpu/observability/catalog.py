@@ -210,6 +210,13 @@ CATALOG = {
         "gauge", (),
         "KV pages currently allocated out of an int8-quantized page "
         "pool (0 / absent when serve_kv_dtype is f32)."),
+    "serve.late_rows": MetricSpec(
+        "counter", (),
+        "Decode rows computed for a request that had already ended at "
+        "its EOS: the engine launches round n+1 before it reads round n, "
+        "so an EOS is learnt one round late and that row's token is "
+        "discarded. The same number rides on the serve.step span's "
+        "counts."),
     "serve.page_stalls": MetricSpec(
         "counter", ("where",),
         "Admissions or decode growths that waited on a free KV page."),
@@ -240,6 +247,12 @@ CATALOG = {
         "Request lifecycle tallies (status: submitted | adopted | "
         "completed | rejected | shed | cancelled | failed; adopted = "
         "fleet dispatch / failover replay into an engine)."),
+    "serve.rounds_overlapped": MetricSpec(
+        "counter", (),
+        "Decode rounds launched before the round before them was read "
+        "(the device never waited for the host between the two); every "
+        "round but the first after an empty engine, a speculative round "
+        "and a recovery. `overlapped` on the serve.step span's counts."),
     "serve.shed": MetricSpec(
         "counter", ("cause",),
         "Queued requests shed by deadline expiry or watchdog-driven "

@@ -16,6 +16,9 @@ back:
     layers that carry a recurrent state (models/hybrid.py). It belongs to
     a slot, not to pages: no page operation sees it. A model without the
     method has none (``()``) and runs as it always did.
+  * the slots' pending tokens, ONE device array ``[num_slots]`` int32
+    that both step programs take and hand back (below, "the round"):
+    the engine's own, no model sees it.
   * ``model.paged_prefill_chunk(prompt, starts, chunk_lengths, caches,
     page_rows, write_floor)`` and ``model.paged_decode_step(tokens,
     caches, page_table, lengths, active)`` -> (logits, new pools). A
@@ -28,6 +31,49 @@ back:
     state nobody holds) and speculative decoding (a rejected proposal
     would have to roll the state back) are refused for such a model.
 
+The round: the fetch trails the launch by one round. step() number n
+(1) admits: every admitted request's prefill chunks are LAUNCHED, none
+waited for, queued on the device behind round n-1, which is still
+running; (2) grows pages and launches decode round n. Each slot's
+pending token comes from the device's token array: the decode program
+returns it (its samples ARE the next round's input; an inactive slot
+keeps its token) and the prefill program writes the admitted slot's
+first token into it, so no token value crosses to the host between two
+launches; (3) only then waits for round n-1's tokens and this step's
+first tokens (`_fetch`) and advances the requests that sat in those
+slots WHEN THE ROUND WAS LAUNCHED (`_Flight.rows`, not `_running`). What
+a launch needs it knows without the token's value: a slot's length and
+its count of generated tokens grow by one a round whatever is sampled,
+the page it needs next follows from the length, the next draw's key is
+fold(seed, count), and max_new is reached by count; so `_lengths`,
+`_gen_counts` and the max_new test live at the launch, and only the
+token itself (`req.tokens`, EOS, the lifecycle events) waits for the
+read. Consequences, all held here:
+
+  * EOS is learnt one round late: a request whose round n-1 token is
+    its eos_id has a row in round n already. That row's token is
+    discarded (`late_rows`), its K/V write lands in a page the request
+    still owned at the launch, and the slot is released at the read. A
+    request that ends by max_new gets no surplus row. Output tokens are
+    the serial engine's, token for token, greedy and sampled alike.
+  * a slot or page released at the read may be taken by the next
+    step's admission while the round in flight still touches it: safe
+    by the device's program order (the later prefill is queued behind
+    that round; a model's per-slot state is zeroed inside the prefill
+    program).
+  * a failure surfaces at the trailing fetch: `_recover` drops the
+    rounds in flight and every request replays from the tokens READ.
+  * the host arrays a launch takes are copies (`_launch_round`).
+  * a speculative engine reads before it launches (after a speculative
+    round a slot's length depends on what was accepted): `step()` reads
+    everything in flight before `_spec_round`, which is the only round
+    that waits for its own tokens. That follows from `_spec_on`, not
+    from a switch: there is no serial plain round.
+  * cancel(), preemption and recovery take a request's rows out of the
+    flights; close() lets the round in flight go unread; drain() ends
+    when nothing is queued, running or in flight; export_inflight()
+    reads only tokens already read.
+
 Architecture (the three serving invariants):
 
   * ONE jitted decode step, fixed slot count, donated page pools — its
@@ -39,16 +85,18 @@ Architecture (the three serving invariants):
     A finished request frees its pages between steps; an admitted one
     takes pages for its prompt and grows one page at a time as it
     decodes. The page table / length / active arrays are tiny host
-    numpy state, re-fed to the step each call (values change, shapes
+    numpy state, copied to the step each call (values change, shapes
     don't).
   * Prefill-on-admit — a second fixed-shape jit (prompts padded to
     `prefill_len`) runs once per admission, writes the prompt K/V into
-    the request's pages and samples the first token, so time-to-first-
-    token is one forward, not `prompt_len` decode steps.
+    the request's pages and samples the first token into the device's
+    token array, so time-to-first-token is one forward, not
+    `prompt_len` decode steps.
 
 Telemetry (PR-4 registry): serve.queue_depth / serve.active_slots
 gauges, serve.ttft_s + serve.token_latency_s histograms, serve.tokens +
-serve.requests{status} + serve.page_stalls counters; optional per-step
+serve.requests{status} + serve.page_stalls + serve.rounds_overlapped +
+serve.late_rows counters; optional per-step
 RunLog records (`ServeConfig.run_log`) that tools/run_report.py renders.
 
 Live observability plane (this layer's serving half):
@@ -285,6 +333,18 @@ class Request:
                                np.asarray(self.tokens, np.int32)])
 
 
+@dataclasses.dataclass
+class _Flight:
+    """Tokens a launched program computes that the host has not read:
+    the device's ``[slots]`` token array as that program returned it,
+    and which request sat in which slot WHEN IT WAS LAUNCHED (the
+    lagged read must not ask ``_running``, which has moved on)."""
+    toks: typing.Any              # device int32 [slots]
+    rows: dict                    # slot -> Request, active at the launch
+    first: bool = False           # an admission's prefill: the row's
+    #                               token is its request's first
+
+
 class ServingEngine:
     """submit()/step()/drain() continuous batching for any model that
     implements the cache protocol (the module's docstring)."""
@@ -335,7 +395,20 @@ class ServingEngine:
         self._page_table = np.zeros((s, self._pages_per_slot), np.int32)
         self._lengths = np.zeros(s, np.int32)
         self._active = np.zeros(s, bool)
-        self._last_tokens = np.zeros(s, np.int32)
+        # every slot's pending token (the one its next decode row feeds)
+        # lives ON THE DEVICE: the decode program hands its samples back
+        # as this array and the prefill program writes an admitted
+        # slot's first token into it, so no token value crosses to the
+        # host between two launches (the module's docstring, "the round")
+        self._tokens_dev = jnp.zeros(s, jnp.int32)
+        # launched and not yet read, oldest first: at most one decode
+        # round between two step() calls. Rows are dropped as their
+        # slots are freed, so a flight only ever names running requests
+        self._inflight = []                 # graft-guard: self._lock
+        self.rounds_overlapped = 0    # rounds launched before the round
+        #                               before them was read
+        self.late_rows = 0            # rows computed for a request that
+        #                               had already ended at EOS
         self._free_slots = list(range(s))
         self._free_pages = collections.deque(range(cfg.num_pages))
         # per-slot sampling state: traced [slots] VALUES of the one
@@ -422,6 +495,7 @@ class ServingEngine:
             "serve.kv_quant_pages", "serve.kv_pages_in_use",
             "serve.state_bytes_in_use", "serve.spec_proposed",
             "serve.spec_accepted", "serve.spec_rollbacks",
+            "serve.rounds_overlapped", "serve.late_rows",
             "jit.retraces"])
         self._retired = 0
         self._retired_ok = 0
@@ -550,7 +624,11 @@ class ServingEngine:
 
         # both step programs take the model's caches as ONE donated
         # argument, the pair (page pools, per-slot state), and hand the
-        # pair back
+        # pair back. Both take the slots' pending tokens as a device
+        # array and return the array the NEXT program takes: a decode
+        # round's samples are the next round's input (an inactive slot
+        # keeps its pending token), a prefill writes the admitted
+        # slot's first token into its row
         def decode(params, caches, tokens, page_table, lengths, active,
                    temps, top_ks, top_ps, seeds, counts):
             _count_trace("decode_traces", "serve.decode")
@@ -564,14 +642,17 @@ class ServingEngine:
                     logits, new_pools = model.paged_decode_step(
                         tok, pools, page_table, lengths, active)
                     new_state = state
-                return _sample(logits, temps, top_ks, top_ps, seeds,
-                               counts), (new_pools, new_state)
+                sampled = _sample(logits, temps, top_ks, top_ps, seeds,
+                                  counts)
+                return (jnp.where(active, sampled, tok),
+                        (new_pools, new_state))
 
             return model.apply({"params": params, "state": {}}, tokens,
                                method=run)
 
-        def prefill(params, caches, prompt, starts, lengths, page_rows,
-                    floors, slots, temps, top_ks, top_ps, seeds, counts):
+        def prefill(params, caches, tokens, prompt, starts, lengths,
+                    page_rows, floors, slots, temps, top_ks, top_ps, seeds,
+                    counts):
             _count_trace("prefill_traces", "serve.prefill")
             pools, state = caches
 
@@ -587,8 +668,10 @@ class ServingEngine:
                         pr, starts, lengths, pools, page_rows,
                         write_floor=floors)
                     new_state = state
-                return _sample(logits, temps, top_ks, top_ps, seeds,
-                               counts), (new_pools, new_state)
+                sampled = _sample(logits, temps, top_ks, top_ps, seeds,
+                                  counts)
+                return (tokens.at[slots].set(sampled),
+                        (new_pools, new_state))
 
             return model.apply({"params": params, "state": {}}, prompt,
                                method=run)
@@ -807,9 +890,11 @@ class ServingEngine:
         """Replica-side export of every non-terminal request's durable
         host state — the fleet router's failover mirror, refreshed each
         healthy round so a later kill replays token-exact from the last
-        synced point. Host-only reads (no device sync): the prompt stays
-        with the router, so entries carry ids, token mirrors, and the
-        accounting clocks `adopt()` preserves."""
+        synced point. Host-only reads (no device sync) of the tokens
+        already READ: the round in flight is not waited for, and is
+        recomputed, token-exact, by whoever adopts the request. The
+        prompt stays with the router, so entries carry ids, token
+        mirrors, and the accounting clocks `adopt()` preserves."""
         out = []
         with self._lock:
             live = list(self._queue) + sorted(self._running.values(),
@@ -827,7 +912,8 @@ class ServingEngine:
     def cancel(self, request_id):
         """Client-initiated cancellation: a first-class terminal status.
         A queued request leaves the queue; a running one frees its slot
-        and pages immediately. Returns True if cancelled, False when the
+        and pages immediately, and its rows in the round in flight are
+        dropped unread. Returns True if cancelled, False when the
         id is unknown or already terminal. Cancelled requests do not
         count against goodput (the client walked away; the engine did
         not fail them)."""
@@ -849,80 +935,101 @@ class ServingEngine:
             return True
 
     def step(self):
-        """One scheduling round: free finished slots happened last round;
-        admit queued prompts into free slots (prefill-on-admit), grow
-        page tables where the next token opens a page, run ONE jitted
-        decode step over all slots, and retire requests that hit EOS or
-        their token budget. Returns the requests finished this round."""
+        """One scheduling round, launched BEFORE the round before it is
+        read (the module's docstring, "the round"): admit queued prompts
+        into free slots (their prefill chunks are launched, their first
+        tokens not read), grow page tables where the next token opens a
+        page, launch ONE jitted decode step over all slots, and only
+        then wait for the tokens of the round the step before launched
+        and of this step's admissions, append them and retire what hit
+        EOS or its token budget. Returns the requests whose last token
+        was READ this round (and those shed or failed in it)."""
         with self._lock, span("serve.step") as sp:
             t0 = self._clock()
             finished = []
+            late0 = self.late_rows
             with phase("serve.admit"):
                 self._shed_expired(finished)
                 self._admit(finished)
-            with phase("serve.grow"):
-                stalled = self._grow_pages()
-                while stalled and not self._active.any():
-                    # pool deadlock: every live slot needs a fresh page
-                    # and none is free. Preempt the lowest-priority /
-                    # latest-deadline stalled request (free its pages,
-                    # requeue it for re-prefill) so higher-value work
-                    # always makes progress — with all-default requests
-                    # this reduces to the youngest. Greedy decoding
-                    # regenerates the dropped tokens exactly; sampled
-                    # runs re-draw (recompute preemption).
-                    victim = min((self._running[s] for s in stalled),
-                                 key=self._victim_key)
-                    self._preempt(victim)
-                    stalled = self._grow_pages()
-            new_tokens = 0
-            toks = None
+            new_tokens = sampled_rows = overlapped = 0
             spec = None
             spec_proposed = spec_accepted = None
-            if self._active.any():
-                use_spec = self._spec_on
-                if use_spec:
-                    try:
-                        fault_point("spec.verify")
-                    except Exception:
-                        # chaos degrade: this round runs as ONE plain
-                        # decode step — token-exact either way (the
-                        # emitted token follows the same sample law)
-                        use_spec = False
-                try:
-                    fault_point("serve.step")
+            try:
+                with phase("serve.grow"):
+                    stalled = self._grow_pages()
+                    while stalled and not self._active.any():
+                        # pool deadlock: every live slot needs a fresh
+                        # page and none is free, so no round can be
+                        # launched. First read what is in flight (a
+                        # request that ended there gives its pages
+                        # back; nothing could have overlapped it).
+                        # Then preempt the lowest-priority / latest-
+                        # deadline stalled request (free its pages,
+                        # requeue it for re-prefill) so higher-value
+                        # work always makes progress — with all-default
+                        # requests this reduces to the youngest. Greedy
+                        # decoding regenerates the dropped tokens
+                        # exactly; sampled runs re-draw with the same
+                        # keys (recompute preemption).
+                        if self._inflight:
+                            new_tokens += self._read(finished, t0)
+                        else:
+                            victim = min((self._running[s] for s in stalled),
+                                         key=self._victim_key)
+                            self._preempt(victim)
+                        stalled = self._grow_pages()
+                if self._spec_on:
+                    # after a speculative round a slot's length depends
+                    # on how many proposals were accepted, so such an
+                    # engine reads before it launches: this step's
+                    # first tokens (and a degraded plain round) now
+                    new_tokens += self._read(finished, t0)
+                launched = None
+                if self._active.any():
+                    use_spec = self._spec_on
                     if use_spec:
+                        try:
+                            fault_point("spec.verify")
+                        except Exception:
+                            # chaos degrade: this round runs as ONE plain
+                            # decode step — token-exact either way (the
+                            # emitted token follows the same sample law)
+                            use_spec = False
+                    fault_point("serve.step")
+                    # sampled_rows: which branch of _sample the round
+                    # takes (0 = the argmax alone), from the host's own
+                    # copy of the knobs as the program gets them
+                    if use_spec:
+                        sampled_rows = int(
+                            np.count_nonzero(self._temps > 0.0))
                         spec = self._spec_round()
+                        self._round_read()
                     else:
+                        sampled_rows = int(np.count_nonzero(
+                            self._temps[self._active] > 0.0))
+                        overlapped = int(any(
+                            not fl.first for fl in self._inflight))
                         with phase("serve.decode"):
-                            toks_dev, (self._caches, self._state) = \
-                                self._decode_jit(
-                                self._params, (self._caches, self._state),
-                                self._last_tokens, self._page_table,
-                                self._lengths, self._active, self._temps,
-                                self._top_ks, self._top_ps, self._seeds,
-                                self._gen_counts)
-                        with phase("serve.fetch"):
-                            toks = np.asarray(toks_dev)  # graft-lint: disable=hot-path-sync (the one deliberate sync per decode round: the python scheduler needs this step's tokens to advance/free slots)
-                except Exception as e:
-                    self._recover("serve.step", e)
-            sampled_rows = 0
-            if spec is not None or toks is not None:
-                self._retry_budget.success()   # consecutive-failure reset
-                self.target_steps += 1
-                # which branch of _sample the round took (0 = the argmax
-                # alone), from the host's own copy of the knobs, read
-                # before _advance releases slots: a released slot's
-                # temperature is 0, so the rows over 0 are running requests
-                sampled_rows = int(np.count_nonzero(self._temps > 0.0))
+                            launched = self._launch_round()
+                # only now wait for the device: the round the step
+                # before launched and this step's first tokens, with
+                # round n queued behind them
+                new_tokens += self._read(finished, t0, keep=launched)
+            except Exception as e:
+                spec = None
+                overlapped = 0
+                self._recover("serve.step", e)
+            if spec is not None:
                 with phase("serve.advance"):
-                    if spec is not None:
-                        new_tokens, spec_proposed, spec_accepted = \
-                            self._advance_spec(spec, self._clock() - t0,
-                                               finished)
-                    else:
-                        new_tokens = self._advance(
-                            toks, self._clock() - t0, finished)
+                    n, spec_proposed, spec_accepted = self._advance_spec(
+                        spec, self._clock() - t0, finished)
+                    new_tokens += n
+            late_rows = self.late_rows - late0
+            if overlapped:
+                self.rounds_overlapped += 1
+                _metrics.counter("serve.rounds_overlapped").inc()
+            if late_rows:
+                _metrics.counter("serve.late_rows").inc(late_rows)
             _metrics.counter("serve.tokens").inc(new_tokens)
             _metrics.gauge("serve.active_slots").set(len(self._running))
             _metrics.gauge("serve.queue_depth").set(len(self._queue))
@@ -932,7 +1039,8 @@ class ServingEngine:
             in_use = self.pages_in_use()
             _metrics.gauge("serve.kv_pages_in_use").set(in_use)
             sp.count(pages_in_use=in_use, pages_cached=self.pages_cached(),
-                     num_pages=self.cfg.num_pages, sampled_rows=sampled_rows)
+                     num_pages=self.cfg.num_pages, sampled_rows=sampled_rows,
+                     overlapped=overlapped, late_rows=late_rows)
             if self._stateful:
                 # every running slot holds its recurrent state whole
                 state_bytes = len(self._running) * self._state_bytes_per_slot
@@ -962,24 +1070,102 @@ class ServingEngine:
             self._step_no += 1
             return finished
 
-    def _advance(self, toks, dt, finished):
-        """After a plain decode round: append each active slot's token,
-        retire what is done. Returns the tokens emitted."""
+    def _launch_round(self):
+        """Launch ONE plain decode round over the active slots and move
+        on, for each of them, everything that does not depend on the
+        token's VALUE: the length grows by one whatever is sampled, and
+        so does the count that keys the next draw (fold(seed, i)) and
+        ends the request at max_new. The pending tokens come from the
+        device's own array. The host arrays are handed over as copies:
+        they are edited in place for the next round while the backend
+        may not have consumed them (the CPU backend aliases an aligned
+        numpy argument: a later write shows in the program). A row that
+        gets no token this round (stalled, or waiting for its last
+        token to be read) is handed over as greedy, so that it never
+        sends the sampler down its long branch."""
+        rows = {slot: req for slot, req in self._running.items()
+                if self._active[slot]}
+        if self._spec_on:
+            # a speculative engine keeps the pending tokens on the host
+            # (everything was read before this launch)
+            self._tokens_dev = jnp.asarray(self._pending_tokens())
+        self._tokens_dev, (self._caches, self._state) = self._decode_jit(
+            self._params, (self._caches, self._state), self._tokens_dev,
+            self._page_table.copy(), self._lengths.copy(),
+            self._active.copy(), self._temps * self._active,
+            self._top_ks.copy(), self._top_ps.copy(), self._seeds.copy(),
+            self._gen_counts.copy())
+        self._lengths[self._active] += 1     # the pending token is cached
+        self._gen_counts[self._active] += 1  # next draw = fold(seed, i)
+        return self._took_off(rows)
+
+    def _took_off(self, rows, first=False):
+        """Record that the program just launched computes ``rows``'
+        tokens into the device's token array, and start that array's
+        copy to the host as soon as it exists (the later read then
+        waits for the program, not for a transfer behind it)."""
+        self._tokens_dev.copy_to_host_async()
+        flight = _Flight(self._tokens_dev, rows, first)
+        self._inflight.append(flight)
+        return flight
+
+    def _read(self, finished, t0, keep=None):
+        """Wait for every flight but ``keep`` (the round this step
+        launched), oldest first, and advance the requests that sat in
+        their rows; a flight whose every row has left since (cancelled,
+        preempted, ended) is let go unread. Returns the decode tokens
+        emitted."""
+        take = [fl for fl in self._inflight if fl is not keep and fl.rows]
+        self._inflight = [fl for fl in self._inflight if fl is keep]
+        got = []
+        for fl in take:
+            if fl.first:
+                (req,) = fl.rows.values()
+                name, rid = "serve.prefill.fetch", req.id
+            else:
+                name, rid = "serve.fetch", None
+            with phase(name, rid=rid):
+                toks = jax.device_get(fl.toks)  # graft-lint: disable=hot-path-sync (the one deliberate wait a decode round, one round BEHIND its launch, and one an admission: the python scheduler needs the token values to append them and to free slots; the device already runs the round launched after this one)
+                got.append((fl, toks))
+            if not fl.first:
+                self._round_read()
+        if not got:
+            return 0
+        with phase("serve.advance"):
+            return self._advance(got, self._clock() - t0, finished)
+
+    def _round_read(self):
+        """A target-model round came back whole."""
+        self._retry_budget.success()   # consecutive-failure reset
+        self.target_steps += 1
+
+    def _advance(self, got, dt, finished):
+        """What waits for the token's value: append each row's token to
+        the request that sat there at the launch, emit an admission's
+        first-token events, retire what is done. Returns the decode
+        tokens emitted."""
         new_tokens = 0
         lat = _metrics.histogram("serve.token_latency_s")
-        for slot, req in list(self._running.items()):
-            if not self._active[slot]:
-                continue               # page-stalled this round
-            self._lengths[slot] += 1   # pending token now cached
-            tok = int(toks[slot])
-            req.tokens.append(tok)
-            self._gen_counts[slot] += 1  # next draw = fold(seed, i)
-            self._last_tokens[slot] = tok
-            lat.observe(dt)
-            new_tokens += 1
-            reason = self._done_reason(req, tok)
-            if reason:
-                self._release(req, finished, reason)
+        for fl, toks in got:
+            # these flights are out of `_inflight`: no release below
+            # edits their rows
+            for slot, req in fl.rows.items():
+                tok = int(toks[slot])
+                if fl.first:
+                    self._trace_event(req, "prefill_done")
+                    t = self._trace_event(req, "first_token")
+                    if req.first_token_t is None:  # a replay keeps the 1st
+                        req.first_token_t = t
+                        _metrics.histogram("serve.ttft_s").observe(
+                            t - req.submit_t)
+                    _metrics.counter("serve.tokens").inc()
+                else:
+                    lat.observe(dt)
+                    new_tokens += 1
+                req.tokens.append(tok)
+                reason = self._done_reason(req, tok)
+                if reason:
+                    self._release(req, finished, reason)
         return new_tokens
 
     def _advance_spec(self, spec, dt, finished):
@@ -1011,7 +1197,6 @@ class ServingEngine:
                 self._lengths[slot] += 1   # its KV is cached
                 req.tokens.append(tok)
                 self._gen_counts[slot] += 1
-                self._last_tokens[slot] = tok
                 lat.observe(dt / m)
                 new_tokens += 1
                 emitted += 1
@@ -1030,14 +1215,17 @@ class ServingEngine:
         return new_tokens, spec_proposed, spec_accepted
 
     def drain(self, max_steps=100000):
-        """Run step() until every submitted request finishes; returns the
-        finished requests in completion order."""
+        """Run step() until every submitted request finishes: nothing
+        queued, nothing running and nothing in flight (a request whose
+        last token is launched and not read is still running). Returns
+        the finished requests in completion order."""
         out = []
         # the lock is released between rounds so client threads can
         # still reach submit()/cancel() while the drain loop runs
         for _ in range(max_steps):
             with self._lock:
-                more = bool(self._queue or self._running)
+                more = bool(self._queue or self._running
+                            or self._inflight)
             if not more:
                 break
             out.extend(self.step())
@@ -1058,6 +1246,10 @@ class ServingEngine:
         return out
 
     def close(self):
+        with self._lock:
+            # a round nobody will read is let go: no request holds its
+            # tokens, and whoever adopts the requests recomputes them
+            self._inflight = []
         if self._metrics_server is not None:
             self._metrics_server.stop()
             self._metrics_server = None
@@ -1092,6 +1284,7 @@ class ServingEngine:
         try:
             return self._prefill_jit.lower(
                 self._params, (self._caches, self._state),
+                np.zeros(cfg.num_slots, np.int32),
                 np.zeros((1, cfg.prefill_len), np.int32),
                 np.zeros(1, np.int32), np.zeros(1, np.int32),
                 self._page_table[:1], np.zeros(1, np.int32),
@@ -1484,22 +1677,24 @@ class ServingEngine:
                 break                      # head-of-line waits for pages
             self._queue.remove(req)
             with phase("serve.prefill", rid=req.id):
-                ok = self._prefill_request(req, total, finished)
+                ok = self._prefill_request(req, total)
             if not ok:
                 break          # mid-admission page stall or a recovery
         _metrics.gauge("serve.queue_depth").set(len(self._queue))
 
-    def _prefill_request(self, req, total, finished):
+    def _prefill_request(self, req, total):
         """Admit one request: take a slot, match the prompt's leading
         full pages against the prefix cache (hits map read-only shared
         pages into the table — prefill for those tokens is SKIPPED),
         then for each remaining prefill_len chunk of the replay sequence
-        grow the page table and run the ONE prefill trace; only the
-        final chunk's sampled token is consumed. On the way out the
-        prompt's own full pages are registered in the cache so later
-        admissions share them. Returns False when admission must back
-        off (pages ran out between chunks, or a prefill failure
-        triggered recovery)."""
+        grow the page table and LAUNCH the ONE prefill trace. No chunk
+        is waited for: the final chunk's sampled token goes into the
+        slot's row of the device's token array, where the next decode
+        round finds it, and the host reads it at the end of this step
+        (`_fetch`). On the way out the prompt's own full pages are
+        registered in the cache so later admissions share them. Returns
+        False when admission must back off (pages ran out between
+        chunks, or a prefill failure triggered recovery)."""
         cfg = self.cfg
         ps = cfg.page_size
         slot = self._free_slots.pop()
@@ -1522,7 +1717,6 @@ class ServingEngine:
                 _metrics.counter("serve.kv_quant_degraded").inc()
                 quant_ok = False
         matched = self._map_prefix(req, total) if quant_ok else 0
-        tok = None
         skipped = 0
         for ci in range(-(-total // cfg.prefill_len)):
             start = ci * cfg.prefill_len
@@ -1547,14 +1741,17 @@ class ServingEngine:
             starts = np.asarray([start], np.int32)
             lens = np.asarray([clen], np.int32)
             floors = np.asarray([matched], np.int32)
+            # its own copy: the table is edited while the chunk is queued
+            page_row = self._page_table[slot][None, :].copy()
             try:
                 fault_point("serve.prefill")
-                tok_dev, (self._caches, self._state) = self._prefill_jit(
-                    self._params, (self._caches, self._state),
-                    req.device_prompt[ci], starts, lens,
-                    self._page_table[slot][None, :], floors,
-                    np.asarray([slot], np.int32),
-                    *self._sampling_rows(req))
+                self._tokens_dev, (self._caches, self._state) = \
+                    self._prefill_jit(
+                        self._params, (self._caches, self._state),
+                        self._tokens_dev, req.device_prompt[ci], starts,
+                        lens, page_row, floors,
+                        np.asarray([slot], np.int32),
+                        *self._sampling_rows(req))
                 if self._spec_on:
                     # mirror the chunk into the draft pools (same pages,
                     # same write floor — shared prefix pages keep their
@@ -1562,10 +1759,8 @@ class ServingEngine:
                     # round sees a fully warm draft cache
                     self._draft_caches = self._draft_prefill_jit(
                         self._draft_params, self._draft_caches,
-                        req.device_prompt[ci], starts, lens,
-                        self._page_table[slot][None, :], floors)
-                with phase("serve.prefill.fetch", rid=req.id):
-                    tok = int(np.asarray(tok_dev)[0])  # graft-lint: disable=hot-path-sync (admission-time sync, once per prefill chunk: the slot table needs the first token before decode rounds start)
+                        req.device_prompt[ci], starts, lens, page_row,
+                        floors)
             except Exception as e:
                 self._recover("serve.prefill", e, pending=req)
                 return False
@@ -1573,25 +1768,17 @@ class ServingEngine:
         if quant_ok:
             self._publish_prefix(req)
         self._lengths[slot] = total
-        self._trace_event(req, "prefill_done")
-        t = self._trace_event(req, "first_token")
-        if req.first_token_t is None:     # recovery replay keeps the 1st
-            req.first_token_t = t
-            _metrics.histogram("serve.ttft_s").observe(t - req.submit_t)
-        req.tokens.append(tok)
         req.status = "running"
         self._running[slot] = req
         self._temps[slot] = req.temperature
         self._top_ks[slot] = req.top_k
         self._top_ps[slot] = req.top_p
         self._seeds[slot] = req.seed
-        self._gen_counts[slot] = len(req.tokens)
-        self._last_tokens[slot] = tok
+        # the first token is counted though not read: the slot's next
+        # draw is fold(seed, count) and max_new is reached by count
+        self._gen_counts[slot] = len(req.tokens) + 1
         self._active[slot] = True
-        _metrics.counter("serve.tokens").inc()
-        reason = self._done_reason(req, tok)
-        if reason:
-            self._release(req, finished, reason)
+        self._took_off({slot: req}, first=True)
         return True
 
     def _abort_admission(self, req):
@@ -1612,12 +1799,18 @@ class ServingEngine:
     def _grow_pages(self):
         """Allocate the page each slot's next token write needs where
         lengths crossed a boundary; slots that cannot get one stall
-        (deactivate) for this round and retry next step. Returns the
-        stalled slots. Idempotent — safe to re-run after a preemption
+        (deactivate) for this round and retry next step. A slot whose
+        request has reached max_new by count gets no further row.
+        Returns the stalled slots. Idempotent — safe to re-run after a preemption
         freed pages."""
         stalled = []
         ps = self.cfg.page_size
         for slot, req in self._running.items():
+            if self._gen_counts[slot] >= req.max_new:
+                # every token it may have is launched: the slot only
+                # waits for the last one to be read
+                self._active[slot] = False
+                continue
             self._active[slot] = True
             ln = int(self._lengths[slot])
             owned = len(req.shared_pages) + len(req.pages)
@@ -1678,7 +1871,7 @@ class ServingEngine:
             # feed back as device arrays; nothing syncs until the window is
             # scored.
             props_dev = []
-            tok = self._last_tokens
+            last = tok = self._pending_tokens()
             for i in range(k):
                 step_act = self._active & (win > i + 1)
                 tok, self._draft_caches = self._draft_jit(
@@ -1687,8 +1880,7 @@ class ServingEngine:
                     self._temps, self._top_ks, self._top_ps,
                     self._seeds, self._gen_counts + i)
                 props_dev.append(tok)
-            window = jnp.stack([jnp.asarray(self._last_tokens)] + props_dev,
-                               axis=1)
+            window = jnp.stack([jnp.asarray(last)] + props_dev, axis=1)
             sampled_dev, self._caches = self._verify_jit(
                 self._params, self._caches, window, self._lengths, win,
                 self._page_table, self._temps, self._top_ks, self._top_ps,
@@ -1698,17 +1890,35 @@ class ServingEngine:
             sampled = np.asarray(sampled_dev)  # graft-lint: disable=hot-path-sync (the speculative round's one deliberate sync point, fetching proposals + verify draws together: acceptance is a host-side compare, and the scheduler needs this round's tokens to advance/free slots)
         return sampled, props, win
 
+    def _pending_tokens(self):
+        """Every running slot's pending token from the host's record,
+        [slots] int32: what a speculative engine, which has read every
+        token before it launches, feeds its round."""
+        last = np.zeros(self.cfg.num_slots, np.int32)
+        for slot, req in self._running.items():
+            last[slot] = req.tokens[-1]
+        return last
+
     def _free_slot_state(self, req):
         """Return a request's slot and pages to the free lists (shared
-        pages back to the prefix cache) and zero the slot's scheduler
-        rows. Leaves req.slot set (terminal trace events carry it);
-        requeue paths null it themselves."""
+        pages back to the prefix cache), zero the slot's scheduler rows
+        and take its rows out of what is in flight: whatever a launched
+        program still computes for the slot is nobody's. Safe under a
+        round in flight by the device's program order: a later prefill
+        into the slot or its pages is queued behind the round that still
+        reads them. Leaves req.slot set (terminal trace events carry
+        it); requeue paths null it themselves. Returns the decode rows
+        dropped."""
         slot = req.slot
+        dropped = 0
+        for fl in self._inflight:
+            if fl.rows.pop(slot, None) is not None and not fl.first:
+                dropped += 1
+        self._inflight = [fl for fl in self._inflight if fl.rows]
         self._return_pages(req)
         self._page_table[slot] = 0
         self._lengths[slot] = 0
         self._active[slot] = False
-        self._last_tokens[slot] = 0
         self._temps[slot] = 0.0
         self._top_ks[slot] = 0
         self._top_ps[slot] = 0.0
@@ -1716,6 +1926,7 @@ class ServingEngine:
         self._gen_counts[slot] = 0
         self._running.pop(slot, None)
         self._free_slots.append(slot)
+        return dropped
 
     def _preempt(self, req):
         """Recompute preemption: drop the request's device state and
@@ -1769,7 +1980,10 @@ class ServingEngine:
         self._page_table[:] = 0
         self._lengths[:] = 0
         self._active[:] = False
-        self._last_tokens[:] = 0
+        # the rounds in flight are dropped with the state they ran on:
+        # every request replays from the tokens that were READ
+        self._inflight = []
+        self._tokens_dev = jnp.zeros(cfg.num_slots, jnp.int32)
         self._temps[:] = 0.0
         self._top_ks[:] = 0
         self._top_ps[:] = 0.0
@@ -1948,7 +2162,10 @@ class ServingEngine:
         _metrics.gauge("serve.goodput").set(self.goodput())
 
     def _release(self, req, finished, reason="length"):
-        self._free_slot_state(req)
+        # a request that ends by max_new has no row in flight (the host
+        # stopped launching it by count); one that ends at EOS learnt it
+        # a round late, and that round's row for it is discarded
+        self.late_rows += self._free_slot_state(req)
         req.status = "done"
         req.retire_reason = reason
         req.done_t = self._clock()

@@ -122,6 +122,45 @@ def test_a_recovered_request_finishes_token_exact(served, where, nth,
     eng.close()
 
 
+def test_a_failure_at_the_trailing_read_rebuilds_pools_and_state(
+        served, fast_retry):
+    """The engine launches round n+1 before it reads round n, so a
+    round that failed on the device is found out one step late, with a
+    round launched on its state behind it. Both are dropped, pools AND
+    state are rebuilt, and every request replays from the tokens read
+    (tests/test_serving.py::TestTrailingFetch holds the other cases of
+    the trailing read for this model and for GPTDecoder alike)."""
+    eng = engine_for(served, max_len=32)
+    ps = prompts((5, 11, 3), seed=4)
+    rids = [eng.submit(p, max_new=7) for p in ps]
+    decode, launched = eng._decode_jit, []
+
+    class Lost:
+        def __init__(self, real):
+            self.real = real
+
+        def copy_to_host_async(self):
+            pass
+
+        def __array__(self, *args, **kwargs):
+            raise RuntimeError("the device lost this round")
+
+    def failing(params, caches, tokens, *rest):
+        toks, caches = decode(params, caches,
+                              getattr(tokens, "real", tokens), *rest)
+        launched.append(1)
+        return (Lost(toks) if len(launched) == 2 else toks), caches
+    eng._decode_jit = failing
+    eng.drain()
+    assert eng.recoveries == 1
+    assert all(eng.requests[r].recoveries == 1 for r in rids[:2])
+    for p, rid in zip(ps, rids):
+        assert eng.requests[rid].tokens == greedy(*served, p, 7)
+    assert eng.decode_traces == 1 and eng.prefill_traces == 1
+    assert not eng._inflight
+    eng.close()
+
+
 @pytest.mark.parametrize("kw,reason", [
     ({"prefix_cache": True}, "prefix hit skips"),
     ({"draft": True}, "roll"),

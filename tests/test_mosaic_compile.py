@@ -486,3 +486,92 @@ def test_sampler_keeps_its_conditional_on_the_chip(one_chip, rows, vocab):
     assert " conditional(" in entry and 'op_name="jit(_sample)/cond"' in entry
     assert " sort(" in hlo and " sort(" not in entry
     assert "rng-bit-generator" not in entry and "cumsum" not in entry
+
+
+# ------------------------------------ the serving engine's step programs
+#
+# Both whole programs as ServingEngine builds them, at gpt2_medium.chat's
+# widths and two layers: the slots' pending tokens go in and come out as
+# a device array (the engine launches round n+1 before it reads round n,
+# serving/engine.py "the round"), the donated pools still come back in
+# place with nothing relaid, and the sampler is still a branch.
+
+@pytest.fixture(scope="module")
+def serve_programs(one_chip):
+    from paddle_tpu.models.gpt import GPTConfig, GPTDecoder
+    from paddle_tpu.ops.pallas import core
+    from paddle_tpu.serving import ServeConfig, ServingEngine
+    cfg = GPTConfig(vocab_size=50257, hidden_size=1024, num_layers=2,
+                    num_heads=16, intermediate_size=4096,
+                    max_position=1024, dropout=0.0, use_flash=True)
+    model = GPTDecoder(cfg)
+    # weights and pools as shapes alone (the cell's 1024 pages a pool: a
+    # small pool the compiler would move to faster memory and back): the
+    # engine only hands them to its programs
+    variables = jax.eval_shape(lambda: model.init(jax.random.key(0)))
+    real_pools = model.init_paged_caches
+    model.init_paged_caches = lambda *a, **kw: jax.eval_shape(
+        lambda: real_pools(*a, **kw))
+    eng = ServingEngine(model, variables, ServeConfig(
+        num_slots=POOL_SLOTS, page_size=POOL_PAGE, max_len=1024,
+        prefill_len=CHUNK, num_pages=POOL_PAGES, cache_dtype=BF16,
+        prefix_cache=False, metrics_port=0))
+    s = POOL_SLOTS
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=one_chip), tree)
+
+    def knobs(n):
+        return [jax.ShapeDtypeStruct((n,), d, sharding=one_chip)
+                for d in (F32, I32, F32, jnp.uint32, I32)]
+
+    def vec(n, dt=I32):
+        return jax.ShapeDtypeStruct((n,), dt, sharding=one_chip)
+
+    state = on_chip((eng._params, (eng._caches, eng._state)))
+    table = jax.ShapeDtypeStruct((s, POOL_PMAX), I32, sharding=one_chip)
+    row = jax.ShapeDtypeStruct((1, POOL_PMAX), I32, sharding=one_chip)
+    chunk = jax.ShapeDtypeStruct((1, CHUNK), I32, sharding=one_chip)
+    was = core.on_tpu
+    core.on_tpu = lambda: True
+    eng._aot_trace = True         # deliberate traces, as compiled_*()
+    try:
+        decode = eng._decode_jit.lower(
+            *state, vec(s), table, vec(s), vec(s, jnp.bool_),
+            *knobs(s)).compile().as_text()
+        prefill = eng._prefill_jit.lower(
+            *state, vec(s), chunk, vec(1), vec(1), row, vec(1), vec(1),
+            *knobs(1)).compile().as_text()
+    finally:
+        core.on_tpu = was
+        eng._aot_trace = False
+        eng.close()
+    pools = sum(len(jax.tree_util.tree_leaves(c)) for c in eng._caches)
+    return {"decode": decode, "prefill": prefill, "pools": pools}
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_serve_step_programs_keep_the_tokens_on_the_device(
+        serve_programs, program):
+    hlo = serve_programs[program]
+    entry = hlo[hlo.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    # the pending tokens: a [slots] int32 parameter in, the same out
+    slots = re.escape(f"s32[{POOL_SLOTS}]") + r"\{[^}]*\}"
+    assert re.search(slots + r' parameter\(\d+\)[^\n]*op_name="tokens"',
+                     entry)
+    header = next(l for l in hlo.splitlines() if l.startswith("HloModule"))
+    assert re.search(r"\)->\(" + slots + ", bf16", header)
+    # every pool is written in place, twice a layer, and nothing of a
+    # pool's shape is copied or relaid
+    copies, writes, aliased = _pool_relayouts(hlo, POOL)
+    assert not copies, copies
+    assert writes == serve_programs["pools"] == len(aliased) == 4
+    # the sampler keeps its conditional: no sort in the entry computation
+    assert " conditional(" in entry and " sort(" in hlo
+    assert " sort(" not in entry and "rng-bit-generator" not in entry
+    want = {"decode": {"decode_attention", "mlp"},
+            "prefill": {"flash_attention", "mlp"}}[program]
+    assert want <= set(_kernels(hlo)), _kernels(hlo)

@@ -374,20 +374,21 @@ class TestEnginePrefixCache:
         done = {}
 
         def wave():
-            """Drain; the sampled rows of each decode round it ran."""
+            """Drain; the sampled rows of each decode round it launched
+            (its last step launches none: it reads the last round)."""
             done.update({r.id: r for r in eng.drain()})
             return new_step_counts("sampled_rows")
 
         with profiler_session():
             eng.submit(prompts[4], max_new=5)             # greedy wave
-            assert wave() == [0] * 4
+            assert wave() == [0] * 5
             eng.submit(prompts[0], max_new=6)             # greedy
             eng.submit(prompts[1], max_new=6, temperature=0.8)
             eng.submit(prompts[2], max_new=6, temperature=0.9, top_k=5)
             eng.submit(prompts[3], max_new=6, temperature=0.7, top_p=0.9)
-            assert wave() == [3] * 5
+            assert wave() == [3] * 5 + [0]
             eng.submit(prompts[5], max_new=5)             # greedy again
-            assert wave() == [0] * 4
+            assert wave() == [0] * 5
         assert eng.decode_traces == 1 and eng.prefill_traces == 1
         assert _metrics.counter("jit.retraces").total() == retraces
         for rid, p, mn in ((0, prompts[4], 5), (1, prompts[0], 6),
@@ -602,7 +603,7 @@ def _step_args(eng, program):
             *knobs(s))
     if program == "prefill":
         return eng._prefill_jit, (
-            eng._params, (eng._caches, eng._state),
+            eng._params, (eng._caches, eng._state), np.zeros(s, np.int32),
             np.zeros((1, cfg.prefill_len), np.int32), np.zeros(1, np.int32),
             np.zeros(1, np.int32), eng._page_table[:1],
             np.zeros(1, np.int32), np.zeros(1, np.int32), *knobs(1))

@@ -92,11 +92,13 @@ class TestChunkedPrefill:
 class TestStepRecovery:
     SPECS = [(5, 6), (11, 9), (3, 4), (18, 7)]   # 18 > prefill_len=8
 
-    def _run(self, plan=None, step_retries=3):
+    def _run(self, plan=None, step_retries=3, prepare=None):
         model, variables, cfg = _tiny_decoder()
         engine = _engine(model, variables, num_slots=2, page_size=8,
                          max_len=32, prefill_len=8,
                          step_retries=step_retries)
+        if prepare is not None:
+            prepare(engine)
         rng = np.random.RandomState(5)
         prompts = [rng.randint(0, cfg.vocab_size, (L,), np.int32)
                    for L, _ in self.SPECS]
@@ -128,6 +130,52 @@ class TestStepRecovery:
             assert np.array_equal(clean[rid], faulted[rid]), (
                 f"request {rid} not token-exact after recovery")
         # the rebuilt pools have identical shapes: recovery never retraces
+        assert engine.decode_traces == 1 and engine.prefill_traces == 1
+
+    def test_a_failure_at_the_trailing_read_recovers_token_exact(
+            self, fast_retry):
+        """The third decode round is launched without complaint and
+        fails where a device failure surfaces, at the read one step
+        later, with the fourth round already launched behind it: the
+        engine recovers at `serve.step`, both rounds are dropped, and
+        every request replays from the tokens that were READ."""
+        _, clean = self._run()
+        launched = []
+
+        class Lost:
+            """A token array whose program failed on the device."""
+            def __init__(self, real):
+                self.real = real
+
+            def copy_to_host_async(self):
+                pass
+
+            def __array__(self, *args, **kwargs):
+                raise RuntimeError("the device lost this round")
+
+        def prepare(engine):
+            decode = engine._decode_jit
+
+            def failing(params, caches, tokens, *rest):
+                tokens = getattr(tokens, "real", tokens)
+                toks, caches = decode(params, caches, tokens, *rest)
+                launched.append(sum(not fl.first
+                                    for fl in engine._inflight))
+                return (Lost(toks) if len(launched) == 3 else toks), caches
+            engine._decode_jit = failing
+
+        before = _metrics.counter("serve.recoveries").snapshot().get(
+            "where=serve.step", 0)
+        engine, faulted = self._run(prepare=prepare)
+        assert engine.recoveries == 1
+        assert _metrics.counter("serve.recoveries").snapshot()[
+            "where=serve.step"] == before + 1
+        # rounds two to four were each launched with one round unread
+        assert launched[:4] == [0, 1, 1, 1]
+        assert all(r.status == "done" for r in engine.requests.values())
+        assert any(r.recoveries for r in engine.requests.values())
+        for rid in clean:
+            assert np.array_equal(clean[rid], faulted[rid])
         assert engine.decode_traces == 1 and engine.prefill_traces == 1
 
     def test_prefill_fault_recovers_token_exact(self, fast_retry):
@@ -232,6 +280,79 @@ class TestBoundedAdmission:
         assert np.array_equal(engine.requests[r1].output,
                               _reference(model, variables, p1, 12))
         engine.close()
+
+
+class TestRoundInFlight:
+    """Who reads tokens from outside step() while a round is launched
+    and not read (engine.py, "the round")."""
+
+    def _engine(self, n=2):
+        model, variables, cfg = _tiny_decoder()
+        engine = _engine(model, variables, num_slots=2, page_size=8,
+                         max_len=32, prefill_len=8)
+        rng = np.random.RandomState(21)
+        prompts = [rng.randint(0, cfg.vocab_size, (L,), np.int32)
+                   for L in (5, 9, 4)[:n]]
+        return model, variables, engine, prompts
+
+    def test_export_inflight_never_holds_a_token_that_was_not_read(self):
+        """After every step the failover mirror is the tokens READ: one
+        behind what the device has computed while a round is in flight,
+        and complete when the request retires."""
+        model, variables, engine, prompts = self._engine()
+        rids = [engine.submit(p, max_new=7) for p in prompts]
+        seen = []
+        while engine._queue or engine._running:
+            engine.step()
+            mirror = {e["rid"]: e["tokens"]
+                      for e in engine.export_inflight()}
+            for slot, req in engine._running.items():
+                assert mirror[req.id] == req.tokens
+                launched = int(engine._gen_counts[slot])
+                in_flight = sum(slot in fl.rows for fl in engine._inflight)
+                assert len(req.tokens) == launched - in_flight
+                seen.append(in_flight)
+        assert 1 in seen                   # a round was in flight
+        for rid, p in zip(rids, prompts):
+            assert np.array_equal(engine.requests[rid].output,
+                                  _reference(model, variables, p, 7))
+        engine.close()
+
+    def test_drain_returns_only_when_nothing_is_in_flight(self):
+        """The step that launches a request's last round does not
+        finish it: drain() takes the step that reads it too."""
+        model, variables, engine, prompts = self._engine(1)
+        rid = engine.submit(prompts[0], max_new=3)
+        engine.step()                      # first token read, round 1 up
+        engine.step()                      # round 2 up: all 3 launched
+        req = engine.requests[rid]
+        assert len(req.tokens) == 2 and req.status == "running"
+        assert engine._inflight
+        assert int(engine._gen_counts[req.slot]) == req.max_new
+        (done,) = engine.drain()
+        assert done is req and len(req.tokens) == 3
+        assert not engine._inflight and not engine._running
+        assert engine.drain() == []
+        engine.close()
+
+    def test_a_replica_adopts_what_was_read_and_recomputes_the_rest(self):
+        """Failover with a round in flight: the mirror holds the tokens
+        read, the adopting engine recomputes the round that was lost
+        with the first engine, token-exact."""
+        model, variables, engine, prompts = self._engine(1)
+        engine.submit(prompts[0], max_new=8)
+        for _ in range(4):
+            engine.step()
+        (entry,) = engine.export_inflight()
+        assert engine._inflight and len(entry["tokens"]) == 4
+        engine.close()                     # the round in flight is let go
+        assert not engine._inflight
+        _, _, other, _ = self._engine(0)
+        rid = other.adopt(prompts[0], tokens=entry["tokens"], max_new=8)
+        other.drain()
+        assert np.array_equal(other.requests[rid].output,
+                              _reference(model, variables, prompts[0], 8))
+        other.close()
 
 
 class TestCancel:

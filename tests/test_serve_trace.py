@@ -311,19 +311,35 @@ class TestEngineSpans:
             group.sort(key=lambda r: r["start"])
         steps = [r for r in recs if r["name"] == "serve.step"]
         decoded = 0
-        for s in steps:
+        for i, s in enumerate(steps):
             names = [k["name"] for k in kids[s["id"]]]
             assert names[:2] == ["serve.admit", "serve.grow"]
-            assert names[2:] in ([], ["serve.decode", "serve.fetch",
-                                      "serve.advance"])
-            decoded += len(names) == 5
+            # the round is LAUNCHED, then the round before it and this
+            # step's admissions are waited for, then advanced
+            rest = names[2:]
+            launched = rest[:1] == ["serve.decode"]
+            waits = rest[launched:-1]
+            assert waits == sorted(waits, key=[
+                "serve.fetch", "serve.prefill.fetch"].index)
+            assert waits.count("serve.fetch") <= 1
+            assert (rest[-1:] == ["serve.advance"]) == bool(waits)
+            if launched and waits:
+                # the launch has returned before any wait begins
+                assert kids[s["id"]][2]["end"] <= kids[s["id"]][3]["start"]
+            decoded += launched
             c = s["counts"]
             assert set(c) == {"pages_in_use", "pages_cached", "num_pages",
-                              "sampled_rows"}
+                              "sampled_rows", "overlapped", "late_rows"}
             assert c["sampled_rows"] == 0            # every request greedy
+            assert c["late_rows"] == 0               # all end by max_new
+            # every round but the first after an empty engine is
+            # launched before the round before it is read
+            assert c["overlapped"] == (launched and i > 0)
+            assert ("serve.fetch" in waits) == (i > 0)
             assert c["num_pages"] == 12
             assert 0 <= c["pages_in_use"] <= 12 - c["pages_cached"]
         assert decoded >= 4
+        assert eng.rounds_overlapped == decoded - 1
         assert steps[-1]["counts"]["pages_in_use"] == 0
         # a parent covers its children, at every level
         for r in recs:
@@ -342,9 +358,12 @@ class TestEngineSpans:
         prefills = {r["rid"]: r for r in recs if r["name"] == "serve.prefill"}
         assert set(prefills) == {0, 1, 2}
         assert by_id[prefills[1]["parent"]]["name"] == "serve.admit"
+        # one wait an admission, however many chunks it launched, at the
+        # end of the step that admitted it
         fetches = [r for r in recs if r["name"] == "serve.prefill.fetch"]
-        assert sorted(f["rid"] for f in fetches) == [0, 1, 1, 2]
-        assert all(by_id[f["parent"]]["name"] == "serve.prefill"
+        assert sorted(f["rid"] for f in fetches) == [0, 1, 2]
+        assert all(f["parent"] == by_id[prefills[f["rid"]]["parent"]]
+                   ["parent"] and f["start"] >= prefills[f["rid"]]["end"]
                    for f in fetches)
         assert {r["rid"] for r in recs if r["name"] == "serve.submit"} == \
             {0, 1, 2}

@@ -302,10 +302,12 @@ class TestServingEngine:
             self, rng, new_step_counts, profiler_session, sampled_new):
         """Which branch of the sampler each decode round took, read from
         the host's copy of the knobs: ``sampled_rows`` on the
-        ``serve.step`` span (0 = the argmax alone). A greedy request of
-        9 tokens (8 decode rounds) runs beside a sampled one of
-        ``sampled_new`` tokens (none, or 3 rounds of the 8); the last
-        step decodes nothing."""
+        ``serve.step`` span (0 = the argmax alone), on the step that
+        LAUNCHED the round. A greedy request of 9 tokens (8 decode
+        rounds) runs beside a sampled one of ``sampled_new`` tokens
+        (none, or 3 rounds of the 8, after which the sampled slot waits
+        for its last token as a greedy row); the ninth step only reads
+        the eighth round and the last one is idle."""
         from paddle_tpu.serving import ServeConfig, ServingEngine
         model, v, cfg = _tiny_decoder()
         eng = ServingEngine(model, v, ServeConfig(
@@ -322,7 +324,7 @@ class TestServingEngine:
             eng.step()                       # an idle round: no decode
         sampled_rounds = max(sampled_new - 1, 0)
         assert new_step_counts("sampled_rows") == \
-            [1] * sampled_rounds + [0] * (9 - sampled_rounds)
+            [1] * sampled_rounds + [0] * (10 - sampled_rounds)
         assert eng.decode_traces == 1 and eng.prefill_traces == 1
         eng.close()
 
@@ -368,17 +370,21 @@ class TestServeExport:
         prog = load_program(path)
         state_flat = jax.tree_util.tree_leaves(
             (eng._params, eng._caches))
-        out = prog(*state_flat, eng._last_tokens.copy(),
+        # the first step launched round 1 and left it in flight: the
+        # pending tokens are the device's, the lengths the launch's
+        out = prog(*state_flat, np.asarray(eng._tokens_dev),
                    eng._page_table.copy(), eng._lengths.copy(),
                    eng._active.copy())
         toks = np.asarray(out[0])
         assert toks.shape == (2,) and toks.dtype == np.int32
-        # parity: the engine's own next step must pick the same token
-        # for the running slot
+        # parity: the engine's own round 2 must pick the same token for
+        # the running slot (launched by the next step, read by the one
+        # after it)
         slot = next(iter(eng._running))
         req = eng._running[slot]
         eng.step()
-        assert req.tokens[-1] == int(toks[slot])
+        eng.step()
+        assert req.tokens[2] == int(toks[slot])
 
 
 class TestAdmissionStaging:
@@ -469,3 +475,226 @@ class TestGenerateSampling:
         # tiny model — require the first step exact and >=90% overall
         np.testing.assert_array_equal(o16[:, 6], o32[:, 6])
         assert float(np.mean(o16 == o32)) >= 0.9
+
+
+# ---------------------------------------------------------------------
+# The round launched before the round before it is read (engine.py, "the
+# round"): whatever the order of launch and read, the tokens are the
+# unbatched reference's, for a model without per-slot state and for one
+# with it.
+
+_SERVED = {}
+
+
+def _served(kind):
+    """(model, variables, jitted plain forward), built once a kind."""
+    if kind not in _SERVED:
+        if kind == "gpt":
+            model, variables, _ = _tiny_decoder(seed=4)
+        else:
+            from paddle_tpu.models.hybrid import HybridConfig, HybridDecoder
+            model = HybridDecoder(HybridConfig.tiny())
+            variables = model.init(jax.random.key(0))
+        forward = jax.jit(lambda v, ids: model.apply(v, ids))
+        _SERVED[kind] = model, variables, forward
+    return _SERVED[kind]
+
+
+def _lag_engine(kind, **kw):
+    from paddle_tpu.serving import ServeConfig, ServingEngine
+    model, variables, _ = _served(kind)
+    kw = {"num_slots": 2, "page_size": 8, "max_len": 64, "prefill_len": 8,
+          "prefix_cache": False, "metrics_port": 0, **kw}
+    return ServingEngine(model, variables, ServeConfig(**kw))
+
+
+def _unbatched(kind, prompt, n, draw=None):
+    """The n tokens decoding gives one request alone, by the plain
+    forward over the sequence so far (padded to one length: causal, so
+    the padding changes nothing before it). ``draw(logits, i)`` picks
+    token i; the default is greedy."""
+    _, variables, forward = _served(kind)
+    ids = np.zeros(64, np.int32)
+    ids[:len(prompt)] = prompt
+    for i, pos in enumerate(range(len(prompt), len(prompt) + n)):
+        logits = forward(variables, jnp.asarray(ids)[None])[0, pos - 1]
+        ids[pos] = int(jnp.argmax(logits)) if draw is None \
+            else draw(logits, i)
+    return ids[len(prompt):len(prompt) + n].tolist()
+
+
+def _lag_prompts(lengths, seed=0):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(0, 512, n).astype(np.int32) for n in lengths]
+
+
+def _steps(eng, after=None, limit=200):
+    """step() until nothing is queued or running; returns, for every
+    request, the step that admitted it and the step that retired it."""
+    admitted, retired = {}, {}
+    n = 0
+    while eng._queue or eng._running:
+        for req in eng.step():
+            retired[req.id] = n
+        for req in eng._running.values():
+            admitted.setdefault(req.id, n)
+        if after is not None:
+            after(eng, n)
+        n += 1
+        assert n < limit
+    return admitted, retired
+
+
+@pytest.mark.parametrize("kind", ["gpt", "hybrid"])
+class TestTrailingFetch:
+    def test_requests_ending_by_max_new(self, kind):
+        """(a) Five requests through two slots, one to three chunks a
+        prompt: exact tokens, every round but the first launched before
+        the one before it was read, and no row computed in vain: a
+        request that ends by count gets no surplus row."""
+        eng = _lag_engine(kind)
+        ps = _lag_prompts((5, 13, 9, 21, 3))
+        rids = [eng.submit(p, max_new=mn)
+                for p, mn in zip(ps, (6, 1, 4, 7, 2))]
+        _steps(eng)
+        for p, rid, mn in zip(ps, rids, (6, 1, 4, 7, 2)):
+            assert eng.requests[rid].tokens == _unbatched(kind, p, mn)
+            assert eng.requests[rid].retire_reason == "length"
+        assert eng.late_rows == 0 and eng.rounds_overlapped >= 8
+        assert eng.decode_traces == 1 and eng.prefill_traces == 1
+        assert not eng._inflight and eng._pages_available() == 16
+        assert not eng._lengths.any() and not eng._gen_counts.any()
+        eng.close()
+
+    def test_eos_mid_stream_is_learnt_a_round_late(self, kind):
+        """(b) A request's eos_id arrives while the other slot runs on:
+        the round already launched holds a row for it, whose token is
+        discarded (``late_rows``); its neighbour is untouched, and the
+        request admitted into the freed slot is exact."""
+        ps = _lag_prompts((6, 9, 4), seed=3)
+        ref = _unbatched(kind, ps[0], 10)
+        eos = ref[3]
+        want = ref[:ref.index(eos) + 1]
+        assert 1 < len(want) < 10
+        eng = _lag_engine(kind)
+        a = eng.submit(ps[0], max_new=10, eos_id=eos)
+        b = eng.submit(ps[1], max_new=12)
+        c = eng.submit(ps[2], max_new=5)         # waits for a's slot
+        admitted, retired = _steps(eng)
+        assert eng.requests[a].tokens == want
+        assert eng.requests[a].retire_reason == "eos"
+        assert eng.requests[b].tokens == _unbatched(kind, ps[1], 12)
+        assert eng.requests[c].tokens == _unbatched(kind, ps[2], 5)
+        assert eng.requests[c].slot == eng.requests[a].slot
+        assert admitted[c] == retired[a] + 1
+        assert eng.late_rows == 1
+        from paddle_tpu.observability import metrics as _metrics
+        assert _metrics.counter("serve.late_rows").total() >= 1
+        eng.close()
+
+    def test_eos_as_the_first_token(self, kind):
+        """(b') The first token is the eos_id: the request has a row in
+        the round launched with its admission, and nothing else."""
+        (p,) = _lag_prompts((7,), seed=4)
+        first = _unbatched(kind, p, 1)
+        eng = _lag_engine(kind)
+        rid = eng.submit(p, max_new=6, eos_id=first[0])
+        (req,) = eng.step()
+        assert req.id == rid and req.tokens == first
+        assert eng.late_rows == 1 and not eng._inflight
+        assert not eng._running and eng._pages_available() == 16
+        eng.close()
+
+    def test_slot_and_pages_reused_the_step_after_a_release(self, kind):
+        """(c) One slot and just the pages one request needs: the
+        second request is admitted, into the same slot and the same
+        pages, in the very step after the first one's EOS was read,
+        behind the round that still holds the first one's surplus row;
+        a third follows a request that ended by count."""
+        ps = _lag_prompts((9, 11, 5), seed=5)
+        ref = _unbatched(kind, ps[0], 8)
+        eos = ref[2]
+        want = ref[:ref.index(eos) + 1]
+        eng = _lag_engine(kind, num_slots=1, max_len=24, num_pages=3)
+        a = eng.submit(ps[0], max_new=8, eos_id=eos)
+        b = eng.submit(ps[1], max_new=6)
+        c = eng.submit(ps[2], max_new=7)
+        admitted, retired = _steps(eng)
+        assert eng.requests[a].tokens == want
+        assert eng.requests[b].tokens == _unbatched(kind, ps[1], 6)
+        assert eng.requests[c].tokens == _unbatched(kind, ps[2], 7)
+        assert admitted[b] == retired[a] + 1
+        assert admitted[c] == retired[b] + 1
+        assert eng.late_rows == 1 and eng._pages_available() == 3
+        eng.close()
+
+    def test_a_stalled_slot_and_a_preemption_under_a_round_in_flight(
+            self, kind):
+        """(d) Three pages for two requests that want three each: a slot
+        stalls for a page (its pending token waits on the device, its
+        length does not move), the pool deadlocks, the engine reads what
+        is in flight before it chooses a victim, and both requests
+        finish exactly."""
+        from paddle_tpu.observability import metrics as _metrics
+        stalls = _metrics.counter("serve.page_stalls").total()
+        eng = _lag_engine(kind, max_len=24, num_pages=3)
+        ps = _lag_prompts((7, 7), seed=1)
+        rids = [eng.submit(p, max_new=12) for p in ps]
+        _steps(eng)
+        assert _metrics.counter("serve.page_stalls").total() > stalls
+        assert sum(eng.requests[r].preemptions for r in rids) >= 1
+        for p, rid in zip(ps, rids):
+            assert eng.requests[rid].tokens == _unbatched(kind, p, 12)
+        assert eng.rounds_overlapped > 0 and not eng._inflight
+        eng.close()
+
+    def test_seeded_sampling_draws_with_the_hosts_count(self, kind):
+        """(e) temperature > 0: token i of a request is drawn with
+        fold(fold(base, seed), i), the count the host advances at the
+        LAUNCH, beside a greedy request in the other slot: the same
+        draws as the law gives one request alone."""
+        eng = _lag_engine(kind, seed=11)
+        ps = _lag_prompts((6, 10), seed=6)
+        knobs = dict(temperature=0.9, top_k=40, top_p=0.95, seed=1234)
+
+        def draw(logits, i):
+            one = lambda x, dt: np.asarray([x], dt)
+            return int(eng._sample(
+                logits[None], one(knobs["temperature"], np.float32),
+                one(knobs["top_k"], np.int32),
+                one(knobs["top_p"], np.float32),
+                one(knobs["seed"], np.uint32), one(i, np.int32))[0])
+
+        s = eng.submit(ps[0], max_new=9, **knobs)
+        g = eng.submit(ps[1], max_new=7)
+        _steps(eng)
+        want = _unbatched(kind, ps[0], 9, draw)
+        assert eng.requests[s].tokens == want
+        assert want != _unbatched(kind, ps[0], 9)     # it did sample
+        assert eng.requests[g].tokens == _unbatched(kind, ps[1], 7)
+        eng.close()
+
+    def test_cancel_drops_the_rows_in_flight(self, kind):
+        """cancel() between two steps: the cancelled request's row in
+        the round in flight is dropped unread (no late row: it did not
+        end at EOS), its neighbour is exact, the slot is reusable."""
+        eng = _lag_engine(kind)
+        ps = _lag_prompts((5, 8, 6), seed=7)
+        a = eng.submit(ps[0], max_new=12)
+        b = eng.submit(ps[1], max_new=9)
+        for _ in range(3):
+            eng.step()
+        read = list(eng.requests[a].tokens)
+        assert eng._inflight and a in {
+            r.id for fl in eng._inflight for r in fl.rows.values()}
+        assert eng.cancel(a)
+        assert a not in {r.id for fl in eng._inflight
+                         for r in fl.rows.values()}
+        c = eng.submit(ps[2], max_new=4)
+        _steps(eng)
+        assert eng.requests[a].status == "cancelled"
+        assert eng.requests[a].tokens == read
+        assert eng.requests[b].tokens == _unbatched(kind, ps[1], 9)
+        assert eng.requests[c].tokens == _unbatched(kind, ps[2], 4)
+        assert eng.late_rows == 0
+        eng.close()

@@ -38,7 +38,7 @@ from paddle_tpu.nn.layers import (
 from paddle_tpu.nn.heads import MultiBoxHead
 from paddle_tpu.nn.mamba import MambaMixer
 from paddle_tpu.nn.scan import ScanLayers
-from paddle_tpu.nn.moe import MoE, top_k_gating
+from paddle_tpu.nn.moe import HeldExperts, MoE, top_k_gating
 from paddle_tpu.nn.rnn import (RNN, BeamSearchDecoder, Decoder, GRUCell,
                                LSTMCell, RNNCell, dynamic_decode)
 

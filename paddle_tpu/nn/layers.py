@@ -749,34 +749,89 @@ class MultiHeadAttention(Module):
         return self._project(out, "o"), pool
 
 
+def rotate_half(x, positions, theta):
+    """Rotary positions, rotate-half over the whole last dim ``d``:
+    ``x * cos + [-x2, x1] * sin`` with ``x = [x1, x2]`` and the angle of
+    lane ``i`` and ``i + d/2`` ``positions * theta ** (-2i / d)``.
+    x [..., T, heads, d] float32; positions [..., T]."""
+    d = x.shape[-1]
+    freqs = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    angle = positions[..., None].astype(jnp.float32) * freqs  # [..., T, d/2]
+    cos = jnp.concatenate([jnp.cos(angle)] * 2, -1)[..., None, :]
+    sin = jnp.concatenate([jnp.sin(angle)] * 2, -1)[..., None, :]
+    x1, x2 = x[..., :d // 2], x[..., d // 2:]
+    return x * cos + jnp.concatenate([-x2, x1], -1) * sin
+
+
 class GroupedQueryAttention(Module):
     """Causal self-attention whose keys and values have a head count of
     their own: ``num_heads`` query heads of ``head_dim`` over
     ``num_kv_heads`` K/V heads, each shared by num_heads / num_kv_heads
-    queries (1 = multi-query attention). No biases, no positional
-    encoding of any kind: an architecture that wants positions adds them
-    outside. The paged pool holds the K/V heads as they are,
-    ``[num_pages, page_size, num_kv_heads * head_dim]`` (ops/attention.py);
-    no path copies K/V per query head. Products are float32 (``matmul``
-    rounds the projections' operands to the weights' dtype)."""
+    queries (1 = multi-query attention). No biases. The paged pool holds
+    the K/V heads as they are, ``[num_pages, page_size, num_kv_heads *
+    head_dim]`` (ops/attention.py); no path copies K/V per query head.
+    Products are float32 (``matmul`` rounds the projections' operands to
+    the weights' dtype).
+
+    Three options, all off by default (then no positional encoding of
+    any kind and every key at or before the query is seen):
+
+      * ``qk_norm``: an RMSNorm over each head's ``head_dim`` on q and
+        on k (one learned scale each, ``q_norm`` / ``k_norm``), before
+        any rotation.
+      * ``rope_theta``: rotary positions on q and k (``rotate_half``),
+        applied BEFORE a key is cached, so a cached key never moves.
+      * ``window``: query at position p sees keys ``p - window + 1 ..
+        p``. Served, such a layer keeps NO pages: its K/V is a per-slot
+        ring of exactly ``window`` rows (``init_ring``: position p at
+        row ``p % window``; softmax does not care for the order of
+        keys), read in decode by the paged decode kernel as one page a
+        slot with ``min(length, window)`` valid rows, and in a prefill
+        chunk beside the chunk's own keys under the window's mask."""
 
     def __init__(self, embed_dim, num_heads, num_kv_heads, head_dim=None,
-                 dtype=jnp.float32):
+                 dtype=jnp.float32, qk_norm=False, rope_theta=None,
+                 window=None, epsilon=1e-6):
         super().__init__()
         head_dim = head_dim or embed_dim // num_heads
         assert num_heads % num_kv_heads == 0, (num_heads, num_kv_heads)
         self.num_heads, self.num_kv_heads = num_heads, num_kv_heads
         self.head_dim = head_dim
+        self.rope_theta, self.window = rope_theta, window
         self.param("wq", (embed_dim, num_heads * head_dim), I.xavier(), dtype)
         self.param("wk", (embed_dim, num_kv_heads * head_dim), I.xavier(),
                    dtype)
         self.param("wv", (embed_dim, num_kv_heads * head_dim), I.xavier(),
                    dtype)
         self.param("wo", (num_heads * head_dim, embed_dim), I.xavier(), dtype)
+        self.q_norm = self.k_norm = None
+        if qk_norm:
+            self.q_norm = RMSNorm(head_dim, epsilon)
+            self.k_norm = RMSNorm(head_dim, epsilon)
 
-    def _attend(self, q, k, v, q_pos):
-        """q [B, T, H*hd]; k, v [B, Tk, KVH, hd] with key j at absolute
-        position j; q_pos [B, T]: query t sees keys <= q_pos[b, t].
+    def _qk(self, x, positions):
+        """The projections as the scores see them: q [..., T, H*hd] and
+        k [..., T, KVH*hd] float32 of x [..., T, E] at ``positions``
+        [..., T], normed per head and rotated where the layer says."""
+        q, k = matmul(x, self.p("wq")), matmul(x, self.p("wk"))
+        if self.q_norm is None and self.rope_theta is None:
+            return q, k
+
+        def shaped(z, norm, heads):
+            z = z.reshape(*z.shape[:-1], heads, self.head_dim)
+            if norm is not None:
+                z = norm(z)
+            if self.rope_theta is not None:
+                z = rotate_half(z, positions, self.rope_theta)
+            return z.reshape(*z.shape[:-2], heads * self.head_dim)
+        return (shaped(q, self.q_norm, self.num_heads),
+                shaped(k, self.k_norm, self.num_kv_heads))
+
+    def _attend(self, q, k, v, q_pos, k_pos=None, k_valid=None):
+        """q [B, T, H*hd]; k, v [B, Tk, KVH, hd]; q_pos [B, T]; key j at
+        absolute position ``k_pos[b, j]`` (``j`` where None). Query t
+        sees the keys at or before q_pos[b, t], inside the window where
+        the layer has one, that ``k_valid`` [B, Tk] allows.
         -> [B, T, H*hd] float32."""
         from paddle_tpu.ops.attention import NEG_INF
         b, t, _ = q.shape
@@ -784,8 +839,14 @@ class GroupedQueryAttention(Module):
         q = q.reshape(b, t, kvh, self.num_heads // kvh, hd)
         scores = jnp.einsum("btkgd,bskd->bkgts", q.astype(jnp.float32),
                             k.astype(jnp.float32)) / (hd ** 0.5)
-        keep = (jnp.arange(k.shape[1])[None, None, None, None, :]
-                <= q_pos[:, None, None, :, None])
+        if k_pos is None:
+            k_pos = jnp.arange(k.shape[1])[None]
+        k_pos = k_pos[:, None, None, None, :]
+        keep = k_pos <= q_pos[:, None, None, :, None]
+        if self.window is not None:
+            keep &= k_pos > q_pos[:, None, None, :, None] - self.window
+        if k_valid is not None:
+            keep &= k_valid[:, None, None, None, :]
         scores = jnp.where(keep, scores, NEG_INF)
         p = jax.nn.softmax(scores, axis=-1)
         ctx = jnp.einsum("bkgts,bskd->btkgd", p, v.astype(jnp.float32))
@@ -795,10 +856,10 @@ class GroupedQueryAttention(Module):
         """Whole sequences, causal. x [B, T, E] -> [B, T, E] float32."""
         b, t, _ = x.shape
         shape = (b, t, self.num_kv_heads, self.head_dim)
-        ctx = self._attend(
-            matmul(x, self.p("wq")), matmul(x, self.p("wk")).reshape(shape),
-            matmul(x, self.p("wv")).reshape(shape),
-            jnp.broadcast_to(jnp.arange(t), (b, t)))
+        pos = jnp.broadcast_to(jnp.arange(t), (b, t))
+        q, k = self._qk(x, pos)
+        ctx = self._attend(q, k.reshape(shape),
+                           matmul(x, self.p("wv")).reshape(shape), pos)
         return matmul(ctx, self.p("wo"))
 
     def init_page_pool(self, num_pages, page_size, dtype=jnp.float32,
@@ -810,17 +871,18 @@ class GroupedQueryAttention(Module):
     def paged_decode_step(self, x_t, pool, page_table, att_lengths,
                           write_pages, write_offsets):
         """As MultiHeadAttention.paged_decode_step: x_t [S, 1, E], one
-        pending token a slot, its K/V row written first and then read
-        with the slot's live pages by the decode kernel."""
+        pending token a slot (at position ``att_lengths - 1`` where it is
+        active), its K/V row written first and then read with the slot's
+        live pages by the decode kernel."""
         from paddle_tpu.ops.attention import (paged_decode_attention,
                                               paged_write)
         s = x_t.shape[0]
         x_t = x_t.reshape(s, -1)
-        pool = paged_write(pool, matmul(x_t, self.p("wk")),
-                           matmul(x_t, self.p("wv")), write_pages,
-                           write_offsets)
-        q = matmul(x_t, self.p("wq")).reshape(s, self.num_heads, -1)
-        ctx = paged_decode_attention(q, pool["k"], pool["v"], page_table,
+        q, k = self._qk(x_t, att_lengths - 1)
+        pool = paged_write(pool, k, matmul(x_t, self.p("wv")),
+                           write_pages, write_offsets)
+        ctx = paged_decode_attention(q.reshape(s, self.num_heads, -1),
+                                     pool["k"], pool["v"], page_table,
                                      att_lengths,
                                      k_scale=pool.get("k_scale"),
                                      v_scale=pool.get("v_scale"))
@@ -838,16 +900,92 @@ class GroupedQueryAttention(Module):
         admission-rate work. -> (out [B, T, E] float32, new pool)."""
         from paddle_tpu.ops.attention import gather_pages, paged_write
         b, t, _ = x.shape
+        q, k = self._qk(x, q_pos)
         pool = paged_write(
-            pool, matmul(x, self.p("wk")).reshape(b * t, -1),
+            pool, k.reshape(b * t, -1),
             matmul(x, self.p("wv")).reshape(b * t, -1),
             page_ids.reshape(b * t), offsets.reshape(b * t))
         kf = gather_pages(pool["k"], page_rows, self.num_kv_heads,
                           pool.get("k_scale"))
         vf = gather_pages(pool["v"], page_rows, self.num_kv_heads,
                           pool.get("v_scale"))
-        ctx = self._attend(matmul(x, self.p("wq")), kf, vf, q_pos)
+        ctx = self._attend(q, kf, vf, q_pos)
         return matmul(ctx, self.p("wo")), pool
+
+    # --- a window layer's K/V: a per-slot ring, no pages ---
+
+    def init_ring(self, num_slots, dtype=jnp.float32):
+        """{"k", "v"} [num_slots, window, KVH*hd]: the last ``window``
+        positions of every slot, position p at row ``p % window``. It IS
+        a page pool of one page a slot (``paged_write`` and the decode
+        kernel take it as it lies), and it is never zeroed: which rows
+        are live follows from the slot's length."""
+        from paddle_tpu.ops.attention import init_page_pool
+        return init_page_pool(num_slots, self.num_kv_heads, self.window,
+                              self.head_dim, dtype)
+
+    def ring_decode_step(self, x_t, ring, lengths, active):
+        """One decode round of a window layer: x_t [S, 1, E], the
+        pending token of slot s at position ``lengths[s]``; an active
+        slot's (rotated) K/V row goes to ring row ``lengths % window``
+        and the query reads the slot's ``min(lengths + 1, window)`` live
+        rows; an inactive slot writes nothing and reads nothing.
+        -> (out [S, 1, E] float32, new ring)."""
+        from paddle_tpu.ops.attention import (paged_decode_attention,
+                                              paged_write)
+        s = x_t.shape[0]
+        x_t = x_t.reshape(s, -1)
+        q, k = self._qk(x_t, lengths)
+        slot = jnp.arange(s, dtype=jnp.int32)
+        ring = paged_write(ring, k, matmul(x_t, self.p("wv")),
+                           jnp.where(active, slot, s),
+                           lengths % self.window)
+        live = jnp.where(active, jnp.minimum(lengths + 1, self.window), 0)
+        ctx = paged_decode_attention(q.reshape(s, self.num_heads, -1),
+                                     ring["k"], ring["v"], slot[:, None],
+                                     live.astype(jnp.int32))
+        return matmul(ctx.reshape(s, -1), self.p("wo"))[:, None], ring
+
+    def ring_prefill_chunk(self, x, ring, slots, starts, chunk_lengths):
+        """A prompt chunk of a window layer: x [B, T, E] at positions
+        ``starts[b] + t`` of slot ``slots[b]``. Every query attends the
+        ring's rows (the ``window`` positions before ``starts``; none at
+        ``starts == 0``, whatever the slot held before) beside the
+        chunk's own real keys under the window's mask; then the chunk's
+        last ``window`` real positions are written to their rows.
+        -> (out [B, T, E] float32, new ring)."""
+        from paddle_tpu.ops.attention import paged_write
+        b, t, _ = x.shape
+        w, kvh, hd = self.window, self.num_kv_heads, self.head_dim
+        rel = jnp.arange(t)
+        pos = starts[:, None] + rel[None, :]                    # [B, T]
+        q, k = self._qk(x, pos)
+        v = matmul(x, self.p("wv"))
+        # ring row r holds the last position before ``starts`` that is
+        # congruent to r (negative: nothing of this request)
+        last = starts[:, None] - 1
+        ring_pos = last - (last - jnp.arange(w)[None, :]) % w   # [B, W]
+        keys = jnp.concatenate(
+            [ring["k"][slots].astype(jnp.float32), k], axis=1)
+        vals = jnp.concatenate(
+            [ring["v"][slots].astype(jnp.float32), v], axis=1)
+        ctx = self._attend(
+            q, keys.reshape(b, w + t, kvh, hd),
+            vals.reshape(b, w + t, kvh, hd), pos,
+            k_pos=jnp.concatenate([ring_pos, pos], axis=1),
+            k_valid=jnp.concatenate(
+                [ring_pos >= 0, rel[None, :] < chunk_lengths[:, None]],
+                axis=1))
+        # two positions of one chunk may share a row (a chunk longer
+        # than the window): only the last ``window`` real ones write
+        keep = ((rel[None, :] < chunk_lengths[:, None])
+                & (rel[None, :] >= chunk_lengths[:, None] - w))
+        ring = paged_write(
+            ring, k.reshape(b * t, -1), v.reshape(b * t, -1),
+            jnp.where(keep, slots[:, None], ring["k"].shape[0]
+                      ).reshape(b * t),
+            (pos % w).reshape(b * t))
+        return matmul(ctx, self.p("wo")), ring
 
 
 class FC(Linear):

@@ -17,6 +17,24 @@ TPU-first design (static shapes throughout):
   * single-device: one einsum pipeline. Expert-parallel: call
     `moe_shard_map`-style under shard_map with experts sharded over
     "ep"; dispatch/combine ride lax.all_to_all.
+
+Two layers live here, and which is for what:
+
+  * ``MoE`` (above): GShard's layer, for TRAINING a model of one's own:
+    softmax scores, an auxiliary balance loss, capacity buffers that
+    drop what overflows, GELU experts with biases, the exchange over
+    "ep". Its dense ``[T, E, C]`` one-hots cost ``T x E x C`` and its
+    dropped tokens change the result, so no published sparse model is
+    served through it.
+  * ``HeldExperts`` (below): the routed layer of today's open sparse
+    models (the DeepSeek-V3 router: sigmoid scores, a selection bias,
+    gates normalised over the chosen and scaled; SiLU-gated experts
+    without biases beside a shared expert), for SERVING one chip's
+    share of an expert-parallel deployment. It is told which experts it
+    holds, routes over all of them, drops no row and computes its own
+    experts' part of the result by a grouped matrix product
+    (ops/pallas/moe_mlp.py). It has no backward of its own yet and no
+    exchange: on one chip the layer runs without it (ROADMAP R1b, R2b).
 """
 
 import jax
@@ -149,3 +167,117 @@ class MoE(Module):
             out = expert_ffn(buf)
         y = jnp.einsum("ecd,tec->td", out, combine)
         return y.reshape(b, t, d), aux
+
+
+def sigmoid_top_k(scores, bias, k, scale):
+    """The DeepSeek-V3 router on sigmoid ``scores`` [T, E] (float32):
+    the ``k`` experts of each row with the largest ``scores + bias``
+    (the selection bias enters the CHOICE only), and their gates
+    ``scale * s_e / (sum over the chosen of s + 1e-20)`` from the
+    unbiased scores. -> (experts [T, k] int32, gates [T, k] float32)."""
+    _, chosen = lax.top_k(scores + bias, k)
+    s = jnp.take_along_axis(scores, chosen, axis=-1)
+    gates = scale * s / (jnp.sum(s, axis=-1, keepdims=True) + 1e-20)
+    return chosen.astype(jnp.int32), gates
+
+
+class HeldExperts(Module):
+    """A sigmoid-routed, dropless expert layer that holds a SHARE of the
+    experts: rows [T, dim] -> (the shared expert's output plus the gated
+    outputs of the chosen experts that are HELD here [T, dim] float32,
+    rows routed to each held expert [count] int32).
+
+    ``num_experts`` is the router's width (every expert of the
+    deployment), ``held = (first, count)`` the run of experts whose
+    weights live here (all of them where it is None). The gates are
+    normalised over all ``k`` chosen experts, held or not; what an
+    absent expert would have added is left out and nothing else changes,
+    so the parts of all the shares, with the shared expert counted once,
+    add up to the whole layer (tests/test_held_experts.py).
+
+    Precision: the router's product, the sigmoid, the choice and the
+    gates are float32 whatever the weights' dtype (a score rounded to
+    bfloat16 swaps the k-th expert for the next in some rows); the
+    experts multiply in the weights' dtype with float32 accumulation.
+
+    Static shapes, no capacity: the ``T x k`` (row, choice) pairs are
+    sorted by held expert, the pairs of absent experts and of rows that
+    are not ``live`` behind them in a group nobody computes."""
+
+    def __init__(self, dim, hidden, num_experts, k, held=None, scale=1.0,
+                 shared_hidden=None, dtype=jnp.float32):
+        super().__init__()
+        from paddle_tpu.core.enforce import enforce
+        first, count = held if held is not None else (0, num_experts)
+        enforce(0 <= first and count >= 1 and first + count <= num_experts,
+                f"held={held} is no run of the {num_experts} experts")
+        enforce(k <= num_experts, "top-k needs k <= num_experts")
+        self.dim, self.hidden = dim, hidden
+        self.num_experts, self.k, self.scale = num_experts, k, float(scale)
+        self.first, self.count = first, count
+        self.shared_hidden = shared_hidden
+        self.param("router", (num_experts, dim), I.xavier(), dtype)
+        self.param("bias", (num_experts,), I.zeros(), dtype)
+        self.param("w_gate", (count, dim, hidden),
+                   I.xavier(fan_in=dim, fan_out=hidden), dtype)
+        self.param("w_up", (count, dim, hidden),
+                   I.xavier(fan_in=dim, fan_out=hidden), dtype)
+        self.param("w_down", (count, hidden, dim),
+                   I.xavier(fan_in=hidden, fan_out=dim), dtype)
+        if shared_hidden:
+            self.param("shared_gate", (dim, shared_hidden), I.xavier(), dtype)
+            self.param("shared_up", (dim, shared_hidden), I.xavier(), dtype)
+            self.param("shared_down", (shared_hidden, dim), I.xavier(),
+                       dtype)
+
+    def route(self, x):
+        """(experts [T, k], gates [T, k]) of rows x [T, dim]."""
+        scores = jax.nn.sigmoid(jnp.dot(
+            x.astype(jnp.float32), self.p("router").astype(jnp.float32).T,
+            precision=lax.Precision.HIGHEST))
+        return sigmoid_top_k(scores, self.p("bias").astype(jnp.float32),
+                             self.k, self.scale)
+
+    def routed(self, x, live=None):
+        """The held experts' part alone -> ([T, dim] float32, rows routed
+        to each held expert [count] int32). ``live`` [T] bool: rows that
+        are padding or idle slots are routed nowhere and counted
+        nowhere."""
+        from paddle_tpu.ops.pallas.moe_mlp import expert_mlp
+        t, k, n = x.shape[0], self.k, self.count
+        chosen, gates = self.route(x)
+        local = chosen - self.first
+        here = (local >= 0) & (local < n)
+        if live is not None:
+            here = here & live[:, None]
+        group = jnp.where(here, local, n).reshape(t * k)
+        order = jnp.argsort(group, stable=True)
+        sizes = jnp.bincount(group, length=n + 1)[:n].astype(jnp.int32)
+        w = self.p("w_gate")
+        y = expert_mlp(x.astype(w.dtype)[order // k], w, self.p("w_up"),
+                       self.p("w_down"), sizes)
+        # back to (row, choice) order by a GATHER through the inverse
+        # permutation (a scatter-add of T x k rows is serial work on a
+        # TPU), then each row's k parts weighted and summed. The rows
+        # behind the last run are undefined: select, never multiply
+        back = jnp.zeros(t * k, jnp.int32).at[order].set(
+            jnp.arange(t * k, dtype=jnp.int32))
+        y = jnp.where(here[..., None],
+                      y[back].reshape(t, k, self.dim) * gates[..., None],
+                      0.0)
+        return jnp.sum(y, axis=1), sizes
+
+    def shared(self, x):
+        """The shared expert (every chip computes it alike) through the
+        fused MLP kernel's gate path. -> [T, dim] float32."""
+        from paddle_tpu.ops.pallas.mlp import fused_mlp
+        return fused_mlp(x, self.p("shared_gate"), None,
+                         self.p("shared_down"), None,
+                         wg=self.p("shared_up"), act="silu"
+                         ).astype(jnp.float32)
+
+    def forward(self, x, live=None):
+        y, sizes = self.routed(x, live)
+        if self.shared_hidden:
+            y = y + self.shared(x)
+        return y, sizes

@@ -247,6 +247,18 @@ CATALOG = {
         "Request lifecycle tallies (status: submitted | adopted | "
         "completed | rejected | shed | cancelled | failed; adopted = "
         "fleet dispatch / failover replay into an engine)."),
+    "serve.moe.experts_idle": MetricSpec(
+        "counter", (),
+        "Held experts that got no row from a step program (summed over "
+        "expert layers and over the programs whose tokens a scheduling "
+        "round read): an idle expert streams no weight. Only a model "
+        "with routed experts (nn.HeldExperts) counts it."),
+    "serve.moe.rows": MetricSpec(
+        "counter", (),
+        "(row, choice) pairs that fell on an expert held here, over all "
+        "expert layers; read with the tokens, one round behind the "
+        "launch. `moe_rows` on the serve.step span's counts, beside "
+        "moe_rows_max, moe_experts_hit and moe_calls."),
     "serve.rounds_overlapped": MetricSpec(
         "counter", (),
         "Decode rounds launched before the round before them was read "

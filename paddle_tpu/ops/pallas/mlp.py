@@ -29,12 +29,14 @@ dtype (it IS the pre-existing model math, and the parity reference).
 """
 
 import functools
+import logging
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from paddle_tpu.ops.pallas import log_fallback
 from paddle_tpu.ops.pallas.core import (INTERPRET, kernel_call, kernel_mode,
                                         legal_block, partitioned,
                                         pick_block_rows, tail_valid_cols,
@@ -49,6 +51,19 @@ _BLOCKS_BUDGET = 15 * 2 ** 20
 #: rows x (x row + activation row + accumulator row) up to which all
 #: rows go into one row tile (pick_block_rows alone stops at 2 MiB)
 _ALL_ROWS_BUDGET = 3 * 2 ** 20
+
+#: a row tile's pass over the weights is bound by READING them while the
+#: tile has fewer rows than the chip multiplies in the time it reads a
+#: weight (a v5e: 197e12 / 819e9 = 240 operations a byte, 240 rows of
+#: bfloat16), so a call split into such tiles pays one read of every
+#: weight a tile. What the code can compute is the tile (``bn``, from
+#: the VMEM budget) and the rows; the bound on the ROWS is twice that
+#: ridge and deliberately conservative: a call of 512 rows or more (the
+#: training shapes, a long prefill) keeps the kernel it was measured
+#: with, because there the [rows, I] activation that the kernel keeps
+#: out of HBM weighs against the weights, and that crossover has one
+#: reading only (256 x 6144: PERF.md section 6, PR 35)
+_WEIGHT_BOUND_ROWS = 512
 
 _ACTS = {
     # exact erf gelu — must match ops/activations.py A.gelu for parity
@@ -240,6 +255,31 @@ def _mlp_unfused(x2, w1, b1, w2, b2, wg, bg, act):
     return a @ w2 + b2
 
 
+def _mlp_weights_once(x2, w1, b1, w2, b2, wg, bg, act):
+    """The composition with every product's operands in the WEIGHT's
+    dtype and float32 accumulation (``nn.layers.matmul``'s rule; the
+    kernel's own precision where the weights are bfloat16): XLA reads
+    each matrix once whatever the rows. For a call that is bound by
+    reading its weights and whose rows the kernel would have to split.
+
+    Not ``_mlp_unfused``, and the two do not fold into one: that one
+    multiplies in the INPUT's dtype (float32 rows promote bfloat16
+    weights to float32), which is the model math from before the kernel,
+    bit for bit: the path off the chip, the parity reference and the
+    backward's recompute. On the chip it would read every weight at
+    twice the bytes through the MXU's float32 passes and hand a served
+    model other numbers at 256 rows than at 128; this one keeps the
+    kernel's precision, so the rows a round happens to hold do not
+    change what is served."""
+    def dot(a, w):
+        return jnp.dot(a.astype(w.dtype), w,
+                       preferred_element_type=jnp.float32)
+    a = _ACTS[act](dot(x2, w1) + b1)
+    if wg is not None:
+        a = a * (dot(x2, wg) + bg)
+    return (dot(a, w2) + b2).astype(x2.dtype)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
 def _mlp_core(x2, w1, b1, w2, b2, wg, bg, act, has_gate, interpret):
     return _mlp_pallas(x2, w1, b1, w2, b2, wg if has_gate else None,
@@ -301,6 +341,19 @@ def fused_mlp(x, w1, b1, w2, b2, wg=None, bg=None, act="gelu"):
     for d in lead:
         R *= d
     x2 = x.reshape(R, H)
+    if R < _WEIGHT_BOUND_ROWS:
+        bn, _ = _default_mlp_blocks(x2, w1, w2, mode == INTERPRET, has_gate)
+        if bn < R:
+            # a decode round's rows at a hidden size whose row tile the
+            # scoped VMEM cuts short (256 rows x 6144: tiles of 40, the
+            # weights read seven times): refused like any shape the
+            # kernel does not serve well, logged and counted
+            log_fallback("mlp", f"{R} rows x H={H} need {-(-R // bn)} row "
+                         f"tiles of {bn}, each reading every weight again "
+                         f"(supported: the rows in one tile, or at least "
+                         f"{_WEIGHT_BOUND_ROWS} rows)", logging.INFO)
+            return _mlp_weights_once(x2, w1, b1, w2, b2, wg, bg,
+                                     act).reshape(*lead, Hout)
     # dummy gate operands keep the custom_vjp signature static
     wg_ = wg if has_gate else jnp.zeros((1, 1), x.dtype)
     bg_ = bg if has_gate else jnp.zeros((1,), x.dtype)

@@ -30,6 +30,14 @@ back:
     kept cannot be served: the prefix cache (a hit skips positions whose
     state nobody holds) and speculative decoding (a rejected proposal
     would have to roll the state back) are refused for such a model.
+  * a model with routed experts (nn.HeldExperts) returns one value
+    more from both methods: the rows routed to each expert it holds,
+    int32 ``[expert layers, held experts]``. Both step programs hand it
+    back beside the tokens and it rides the same trailing fetch (no
+    sync of its own); what it says goes into ``serve.step``'s counts
+    (``moe_rows``, ``moe_rows_max``, ``moe_experts_hit``, ``moe_calls``)
+    and the counters ``serve.moe.rows`` / ``serve.moe.experts_idle``. A
+    model without experts returns nothing there and counts nothing.
 
 The round: the fetch trails the launch by one round. step() number n
 (1) admits: every admitted request's prefill chunks are LAUNCHED, none
@@ -343,6 +351,8 @@ class _Flight:
     rows: dict                    # slot -> Request, active at the launch
     first: bool = False           # an admission's prefill: the row's
     #                               token is its request's first
+    routed: tuple = ()            # device int32 [expert layers, held]
+    #                               of each program behind this flight
 
 
 class ServingEngine:
@@ -405,6 +415,7 @@ class ServingEngine:
         # round between two step() calls. Rows are dropped as their
         # slots are freed, so a flight only ever names running requests
         self._inflight = []                 # graft-guard: self._lock
+        self._routed_read = []              # graft-guard: self._lock
         self.rounds_overlapped = 0    # rounds launched before the round
         #                               before them was read
         self.late_rows = 0            # rows computed for a request that
@@ -635,9 +646,11 @@ class ServingEngine:
             pools, state = caches
 
             def run(tok):
+                routed = ()
                 if stateful:
-                    logits, new_pools, new_state = model.paged_decode_step(
-                        tok, pools, page_table, lengths, active, state)
+                    logits, new_pools, new_state, *routed = \
+                        model.paged_decode_step(
+                            tok, pools, page_table, lengths, active, state)
                 else:
                     logits, new_pools = model.paged_decode_step(
                         tok, pools, page_table, lengths, active)
@@ -645,7 +658,7 @@ class ServingEngine:
                 sampled = _sample(logits, temps, top_ks, top_ps, seeds,
                                   counts)
                 return (jnp.where(active, sampled, tok),
-                        (new_pools, new_state))
+                        (new_pools, new_state), *routed)
 
             return model.apply({"params": params, "state": {}}, tokens,
                                method=run)
@@ -657,9 +670,10 @@ class ServingEngine:
             pools, state = caches
 
             def run(pr):
+                routed = ()
                 if stateful:
                     # no prefix cache with a state: nothing to floor
-                    logits, new_pools, new_state = \
+                    logits, new_pools, new_state, *routed = \
                         model.paged_prefill_chunk(
                             pr, starts, lengths, pools, page_rows,
                             state=state, slots=slots)
@@ -671,7 +685,7 @@ class ServingEngine:
                 sampled = _sample(logits, temps, top_ks, top_ps, seeds,
                                   counts)
                 return (tokens.at[slots].set(sampled),
-                        (new_pools, new_state))
+                        (new_pools, new_state), *routed)
 
             return model.apply({"params": params, "state": {}}, prompt,
                                method=run)
@@ -948,6 +962,7 @@ class ServingEngine:
             t0 = self._clock()
             finished = []
             late0 = self.late_rows
+            self._routed_read = []
             with phase("serve.admit"):
                 self._shed_expired(finished)
                 self._admit(finished)
@@ -1048,6 +1063,18 @@ class ServingEngine:
                 sp.count(state_slots=len(self._running),
                          state_bytes=state_bytes,
                          state_bytes_reserved=self.state_bytes())
+            if self._routed_read:
+                # rows routed to each held expert, [expert layers, held]
+                # of every program whose tokens this step read
+                routed = np.stack(self._routed_read)
+                rows = int(routed.sum())
+                _metrics.counter("serve.moe.rows").inc(rows)
+                _metrics.counter("serve.moe.experts_idle").inc(
+                    int((routed == 0).sum()))
+                sp.count(moe_rows=rows, moe_rows_max=int(routed.max()),
+                         moe_experts_hit=float(
+                             (routed > 0).sum(-1).mean()),
+                         moe_calls=len(routed))
             wall_s = self._clock() - t0
             if self._run_log is not None:
                 rec = {
@@ -1089,23 +1116,28 @@ class ServingEngine:
             # a speculative engine keeps the pending tokens on the host
             # (everything was read before this launch)
             self._tokens_dev = jnp.asarray(self._pending_tokens())
-        self._tokens_dev, (self._caches, self._state) = self._decode_jit(
-            self._params, (self._caches, self._state), self._tokens_dev,
-            self._page_table.copy(), self._lengths.copy(),
-            self._active.copy(), self._temps * self._active,
-            self._top_ks.copy(), self._top_ps.copy(), self._seeds.copy(),
-            self._gen_counts.copy())
+        self._tokens_dev, (self._caches, self._state), *routed = \
+            self._decode_jit(
+                self._params, (self._caches, self._state), self._tokens_dev,
+                self._page_table.copy(), self._lengths.copy(),
+                self._active.copy(), self._temps * self._active,
+                self._top_ks.copy(), self._top_ps.copy(),
+                self._seeds.copy(), self._gen_counts.copy())
         self._lengths[self._active] += 1     # the pending token is cached
         self._gen_counts[self._active] += 1  # next draw = fold(seed, i)
-        return self._took_off(rows)
+        return self._took_off(rows, routed=routed)
 
-    def _took_off(self, rows, first=False):
+    def _took_off(self, rows, first=False, routed=()):
         """Record that the program just launched computes ``rows``'
         tokens into the device's token array, and start that array's
         copy to the host as soon as it exists (the later read then
-        waits for the program, not for a transfer behind it)."""
+        waits for the program, not for a transfer behind it).
+        ``routed``: the expert-row counts of the programs behind this
+        flight (a model with experts), which travel with the tokens."""
         self._tokens_dev.copy_to_host_async()
-        flight = _Flight(self._tokens_dev, rows, first)
+        for r in routed:
+            r.copy_to_host_async()
+        flight = _Flight(self._tokens_dev, rows, first, tuple(routed))
         self._inflight.append(flight)
         return flight
 
@@ -1125,8 +1157,9 @@ class ServingEngine:
             else:
                 name, rid = "serve.fetch", None
             with phase(name, rid=rid):
-                toks = jax.device_get(fl.toks)  # graft-lint: disable=hot-path-sync (the one deliberate wait a decode round, one round BEHIND its launch, and one an admission: the python scheduler needs the token values to append them and to free slots; the device already runs the round launched after this one)
+                toks, routed = jax.device_get((fl.toks, fl.routed))  # graft-lint: disable=hot-path-sync (the one deliberate wait a decode round, one round BEHIND its launch, and one an admission: the python scheduler needs the token values to append them and to free slots; the device already runs the round launched after this one)
                 got.append((fl, toks))
+                self._routed_read.extend(routed)
             if not fl.first:
                 self._round_read()
         if not got:
@@ -1718,6 +1751,8 @@ class ServingEngine:
                 quant_ok = False
         matched = self._map_prefix(req, total) if quant_ok else 0
         skipped = 0
+        routed = []     # each chunk's expert-row counts (a model with
+        #                 experts): they ride the admission's flight
         for ci in range(-(-total // cfg.prefill_len)):
             start = ci * cfg.prefill_len
             clen = min(cfg.prefill_len, total - start)
@@ -1745,13 +1780,14 @@ class ServingEngine:
             page_row = self._page_table[slot][None, :].copy()
             try:
                 fault_point("serve.prefill")
-                self._tokens_dev, (self._caches, self._state) = \
+                self._tokens_dev, (self._caches, self._state), *chunk = \
                     self._prefill_jit(
                         self._params, (self._caches, self._state),
                         self._tokens_dev, req.device_prompt[ci], starts,
                         lens, page_row, floors,
                         np.asarray([slot], np.int32),
                         *self._sampling_rows(req))
+                routed.extend(chunk)
                 if self._spec_on:
                     # mirror the chunk into the draft pools (same pages,
                     # same write floor — shared prefix pages keep their
@@ -1778,7 +1814,7 @@ class ServingEngine:
         # draw is fold(seed, count) and max_new is reached by count
         self._gen_counts[slot] = len(req.tokens) + 1
         self._active[slot] = True
-        self._took_off({slot: req}, first=True)
+        self._took_off({slot: req}, first=True, routed=routed)
         return True
 
     def _abort_admission(self, req):

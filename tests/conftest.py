@@ -107,20 +107,6 @@ def profiler_session(tmp_path):
     return hold
 
 
-def pytest_collection_finish(session):
-    """``tests/benchmark/conftest.py`` keys its tiny limits by a traffic
-    mix's ``kind`` and knows ``train`` and ``serve``; it belongs to the
-    benchmark, which only a ``benchmark`` PR may edit. A window kind
-    that a later PR adds as a file (``serve_model``, PR 28) is given the
-    serving limits here, so that ``make_tiny_root`` can write every
-    cell; the tests of such a cell bring a fixture of their own. To be
-    folded into that conftest by the next ``benchmark`` PR."""
-    for plugin in session.config.pluginmanager.get_plugins():
-        limits = getattr(plugin, "TINY_LIMITS", None)
-        if isinstance(limits, dict) and "serve" in limits:
-            limits.setdefault("serve_model", limits["serve"])
-
-
 def pytest_sessionfinish(session, exitstatus):
     """Shutdown watchdog: orbax/tensorstore's grpc atexit hooks can hang
     interpreter teardown (observed: suite green, process stuck after the

@@ -50,7 +50,8 @@ RULES = {"harness": harness_rule, "published": published_rule}
 def reference_logits(params, ids, precision="highest"):
     """[T, V]: row j scores the token after position j."""
     return ref.logits_at(params, jnp.asarray(ids), np.int32(0),
-                         num_heads=CFG.num_heads, n_out=len(ids),
+                         shapes={"num_heads": CFG.num_heads},
+                         n_out=len(ids),
                          precision=precision)
 
 

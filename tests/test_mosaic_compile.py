@@ -414,6 +414,57 @@ def test_paged_decode_with_one_kv_head(one_chip, as_tpu):
     assert set(_kernels(hlo)) == {"decode_attention"}
 
 
+@pytest.mark.parametrize("pairs", [2048, 1024])
+def test_grouped_expert_mlp_at_the_published_widths(one_chip, pairs):
+    """K-EXAONE's held experts: 16 of 6144 x 2048 in bfloat16, a decode
+    round's 256 x 8 (row, choice) pairs and a prefill chunk's 128 x 8.
+    The kernel asks for NO raised scoped-VMEM limit (one that did hung a
+    whole step program on the chip: PERF.md section 6, PR 28), so its
+    blocks have to fit the default 16 MiB."""
+    from paddle_tpu.ops.pallas import moe_mlp
+    w = ((16, 6144, 2048), BF16)
+    hlo = _compile(one_chip, moe_mlp.expert_mlp_tpu, ((pairs, 6144), BF16),
+                   w, w, ((16, 2048, 6144), BF16), ((17,), I32))
+    assert 0 < _kernels(hlo)["moe_expert_mlp"] <= 16 * 2 ** 20
+    assert "vmem_limit" not in hlo
+
+
+def test_ring_decode_with_eight_kv_heads_under_sixty_four(one_chip, as_tpu):
+    """A window layer's ring as the decode kernel takes it: one page of
+    128 rows a slot, 8 K/V heads of 128 under 64 query heads."""
+    from paddle_tpu.ops.attention import paged_decode_attention
+    hlo = _compile(
+        one_chip, paged_decode_attention, ((256, 64, 128), F32),
+        ((256, 128, 1024), BF16), ((256, 128, 1024), BF16),
+        ((256, 1), I32), ((256,), I32))
+    assert set(_kernels(hlo)) == {"decode_attention"}
+
+
+def test_fused_mlp_at_six_thousand_wide_reads_its_weights_once(one_chip,
+                                                               as_tpu):
+    """K-EXAONE's dense FFN (6144 x 18432) and shared expert (6144 x
+    2048) at a decode round's 256 rows and a chunk's 128: the scoped
+    VMEM leaves the kernel row tiles of 40, seven reads of every weight
+    (9.2 ms a round on the chip where the bytes are worth 1.2: PERF.md
+    section 6, PR 35), so ``fused_mlp`` hands such a call (fewer than
+    512 rows, more than one row tile) to XLA's own products, which read
+    each matrix once. The 2560-wide layers keep the kernel
+    (test_fused_mlp_wide_gated_fits_the_default_vmem)."""
+    from paddle_tpu.ops.pallas import mlp
+
+    def fn(x, w1, w2, wg):
+        return mlp.fused_mlp(x, w1, None, w2, None, wg=wg, act="silu")
+    for rows, inter in ((256, 18432), (128, 2048)):
+        shapes = [((rows, 6144), F32), ((6144, inter), BF16),
+                  ((inter, 6144), BF16), ((6144, inter), BF16)]
+        x, w1, w2, _ = (jax.ShapeDtypeStruct(s, d) for s, d in shapes)
+        assert mlp._default_mlp_blocks(x, w1, w2, False, True)[0] < rows
+        hlo = _compile(one_chip, fn, *shapes)
+        assert "mlp" not in _kernels(hlo)
+        # bfloat16 operands, float32 accumulation, no f32 copy of a weight
+        assert not re.search(rf"f32\[6144,{inter}\]", hlo)
+
+
 def test_fused_mlp_wide_gated_fits_the_default_vmem(one_chip, as_tpu):
     """2560 x 8192 with a gate: 512-wide tiles of three bf16 matrices
     pass the 16 MiB scoped VMEM limit, so the intermediate tile is

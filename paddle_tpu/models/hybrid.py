@@ -231,6 +231,15 @@ class HybridDecoder(nn.Module):
              "full": attend, "window": attend})
         return self._head(x)
 
+    def moe_grid(self, tokens):
+        """The grouped kernel's (row tile, items a layer's call) for a
+        program over ``tokens`` rows (every expert layer alike), or None
+        for a model without experts."""
+        for blk in self.blocks:
+            if blk.ffn_kind == "moe":
+                return blk.moe.kernel_grid(tokens)
+        return None
+
     # --- the serving engine's cache protocol (serving/engine.py) ---
 
     def init_paged_caches(self, num_pages, page_size, dtype=jnp.float32,

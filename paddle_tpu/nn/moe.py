@@ -267,6 +267,16 @@ class HeldExperts(Module):
                       0.0)
         return jnp.sum(y, axis=1), sizes
 
+    def kernel_grid(self, tokens):
+        """(row tile, work items of its static grid) of the grouped
+        kernel's call over ``tokens`` rows (ops/pallas/moe_mlp.py): the
+        engine counts how many of those items carried rows."""
+        from paddle_tpu.ops.pallas.moe_mlp import pick_tiles
+        m = -(-tokens * self.k // 8) * 8
+        tile_m, _ = pick_tiles(m, self.dim, self.hidden,
+                               self.p("w_gate").dtype.itemsize)
+        return tile_m, m // tile_m + self.count - 1
+
     def shared(self, x):
         """The shared expert (every chip computes it alike) through the
         fused MLP kernel's gate path. -> [T, dim] float32."""

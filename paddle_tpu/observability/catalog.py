@@ -253,6 +253,14 @@ CATALOG = {
         "expert layers and over the programs whose tokens a scheduling "
         "round read): an idle expert streams no weight. Only a model "
         "with routed experts (nn.HeldExperts) counts it."),
+    "serve.moe.items": MetricSpec(
+        "counter", (),
+        "Work items of the grouped expert kernel that carried rows (one "
+        "an expert and row tile that share a row), summed over expert "
+        "layers and over the programs a scheduling round read; the "
+        "kernel's static grid has `moe_item_slots` (serve.step's "
+        "counts, beside `moe_items`), and the items past the live ones "
+        "stream no weight."),
     "serve.moe.rows": MetricSpec(
         "counter", (),
         "(row, choice) pairs that fell on an expert held here, over all "

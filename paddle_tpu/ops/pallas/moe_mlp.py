@@ -19,9 +19,14 @@ runs' bounds (``work_items``) and handed over as scalar prefetch, the
 idiom of the paged decode kernel. A run that spans two row tiles is two
 items, two runs inside one tile are two items on the same tile, an
 expert with no row is no item and streams no weight. The item axis has
-the static length ``M / tile_m + G - 1`` (its worst case); the items
-past the live ones repeat the last one's block indices, so they move
-nothing, and skip the arithmetic.
+the static length ``M / tile_m + G - 1`` (its worst case). The items
+past the live ones skip the arithmetic (``pl.when``) and move nothing,
+BECAUSE their block indices are pinned to the ones the last live step
+holds (``weight_cols_index``, ``weight_rows_index``): the pipeline
+fetches a block whenever its index differs from the step before, and
+``pl.when`` cuts the products, not the fetches: a dead item that kept
+its expert but walked its column steps from 0 again streamed that
+expert whole (PERF.md section 6, PR 36).
 
 An item streams its expert's three matrices once, ``tile_f`` columns of
 the hidden dim at a step (the inner grid axis): [D, tile_f] of W_gate
@@ -48,6 +53,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -96,6 +102,40 @@ def work_items(offsets, m, tile_m):
             n.astype(jnp.int32)[None])
 
 
+def live_items(sizes, tile_m):
+    """``work_items``' live count on the host, from the rows routed to
+    each expert ``sizes`` [..., G] (runs laid from row 0), summed over
+    the leading axes (the engine's expert layers and programs)."""
+    sizes = np.asarray(sizes, np.int64)
+    ends = np.cumsum(sizes, axis=-1)
+    starts = ends - sizes
+    return int(np.where(sizes > 0,
+                        (ends - 1) // tile_m - starts // tile_m + 1, 0).sum())
+
+
+def rows_index(w, j, gid, tile, n, off):
+    """x rows and the output tile: the item's row tile (a dead item's is
+    the last live one's already)."""
+    return tile[w], 0
+
+
+def _column_step(w, j, n, nf):
+    """The column step a weight block is fetched at: ``j`` for a live
+    item, the last one (``nf - 1``, where the last live step ended) for
+    a dead one, so that no index changes past the last live step."""
+    return jnp.where(w < n[0], j, nf - 1)
+
+
+def weight_cols_index(w, j, gid, tile, n, off, *, nf):
+    """W_gate and W_up: [1, D, tile_f] blocks (expert, 0, column step)."""
+    return gid[w], 0, _column_step(w, j, n, nf)
+
+
+def weight_rows_index(w, j, gid, tile, n, off, *, nf):
+    """W_down: [1, tile_f, D] blocks (expert, row step, 0)."""
+    return gid[w], _column_step(w, j, n, nf), 0
+
+
 def _kernel(gid_ref, tile_ref, n_ref, off_ref, x_ref, wg_ref, wu_ref,
             wd_ref, o_ref, *, tile_m):
     w, f = pl.program_id(0), pl.program_id(1)
@@ -128,16 +168,17 @@ def expert_mlp_tpu(x, w_gate, w_up, w_down, offsets, interpret=False):
     g, _, f = w_gate.shape
     tile_m, tile_f = pick_tiles(m, d, f, x.dtype.itemsize)
     gid, tile, n = work_items(offsets, m, tile_m)
-    rows = pl.BlockSpec((tile_m, d), lambda w, j, gi, ti, *_: (ti[w], 0))
-    # a finished item's successor starts at column 0 again: the item
-    # axis is outermost, so the weight blocks' indices are (expert, step)
+    nf = f // tile_f
+    rows = pl.BlockSpec((tile_m, d), rows_index)
+    # the item axis is outermost: a live item's successor starts at
+    # column 0 again; a dead item stays where the last live step ended
     cols = pl.BlockSpec((1, d, tile_f),
-                        lambda w, j, gi, *_: (gi[w], 0, j))
+                        functools.partial(weight_cols_index, nf=nf))
     down = pl.BlockSpec((1, tile_f, d),
-                        lambda w, j, gi, *_: (gi[w], j, 0))
+                        functools.partial(weight_rows_index, nf=nf))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(gid.shape[0], f // tile_f),
+        grid=(gid.shape[0], nf),
         in_specs=[rows, cols, cols, down],
         out_specs=rows,
     )

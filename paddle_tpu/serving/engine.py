@@ -35,9 +35,13 @@ back:
     int32 ``[expert layers, held experts]``. Both step programs hand it
     back beside the tokens and it rides the same trailing fetch (no
     sync of its own); what it says goes into ``serve.step``'s counts
-    (``moe_rows``, ``moe_rows_max``, ``moe_experts_hit``, ``moe_calls``)
-    and the counters ``serve.moe.rows`` / ``serve.moe.experts_idle``. A
-    model without experts returns nothing there and counts nothing.
+    (``moe_rows``, ``moe_rows_max``, ``moe_experts_hit``, ``moe_calls``;
+    ``moe_items`` / ``moe_item_slots``: the grouped kernel's work items
+    that carried rows and its static grid's items, by the model's
+    ``moe_grid`` at the program's row count) and the counters
+    ``serve.moe.rows`` / ``serve.moe.experts_idle`` /
+    ``serve.moe.items``. A model without experts returns nothing there
+    and counts nothing.
 
 The round: the fetch trails the launch by one round. step() number n
 (1) admits: every admitted request's prefill chunks are LAUNCHED, none
@@ -178,6 +182,7 @@ from paddle_tpu.observability import flight as _flight
 from paddle_tpu.observability import metrics as _metrics
 from paddle_tpu.observability import spans as _spans
 from paddle_tpu.observability.spans import phase, span
+from paddle_tpu.ops.pallas.moe_mlp import live_items
 from paddle_tpu.testing.chaos import fault_point
 
 
@@ -377,6 +382,13 @@ class ServingEngine:
                 "the state back, and no snapshot of it is kept")
         self._model = model
         self._params = variables["params"]
+        # a model with routed experts: the grouped kernel's (row tile,
+        # items a layer's call) at a decode round's and a chunk's rows
+        grid = getattr(model, "moe_grid", None)
+        self._moe_grid = None if grid is None else {
+            t: model.apply({"params": self._params, "state": {}}, t,
+                           method=grid)
+            for t in (cfg.num_slots, cfg.prefill_len)}
         self.version = cfg.model_version
         self._clock = clock
         self._pages_per_slot = -(-cfg.max_len // cfg.page_size)
@@ -1066,15 +1078,24 @@ class ServingEngine:
             if self._routed_read:
                 # rows routed to each held expert, [expert layers, held]
                 # of every program whose tokens this step read
-                routed = np.stack(self._routed_read)
+                routed = np.stack([r for r, _ in self._routed_read])
                 rows = int(routed.sum())
+                # the grouped kernel's items that streamed an expert,
+                # and all its grid's items (the rest move nothing)
+                items = slots = 0
+                for r, t in self._routed_read:
+                    tile_m, per_call = self._moe_grid[t]
+                    items += live_items(r, tile_m)
+                    slots += len(r) * per_call
                 _metrics.counter("serve.moe.rows").inc(rows)
                 _metrics.counter("serve.moe.experts_idle").inc(
                     int((routed == 0).sum()))
+                _metrics.counter("serve.moe.items").inc(items)
                 sp.count(moe_rows=rows, moe_rows_max=int(routed.max()),
                          moe_experts_hit=float(
                              (routed > 0).sum(-1).mean()),
-                         moe_calls=len(routed))
+                         moe_calls=len(routed), moe_items=items,
+                         moe_item_slots=slots)
             wall_s = self._clock() - t0
             if self._run_log is not None:
                 rec = {
@@ -1159,7 +1180,10 @@ class ServingEngine:
             with phase(name, rid=rid):
                 toks, routed = jax.device_get((fl.toks, fl.routed))  # graft-lint: disable=hot-path-sync (the one deliberate wait a decode round, one round BEHIND its launch, and one an admission: the python scheduler needs the token values to append them and to free slots; the device already runs the round launched after this one)
                 got.append((fl, toks))
-                self._routed_read.extend(routed)
+                # with each program's row count: a chunk's or a round's
+                program_rows = self.cfg.prefill_len if fl.first else \
+                    self.cfg.num_slots
+                self._routed_read.extend((r, program_rows) for r in routed)
             if not fl.first:
                 self._round_read()
         if not got:

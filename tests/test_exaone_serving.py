@@ -213,6 +213,14 @@ def test_the_engine_serves_the_references_tokens_and_counts_the_rows(
     for c in routed:
         assert 0 < c["moe_experts_hit"] <= held
         assert c["moe_rows_max"] * c["moe_calls"] * 4 * held >= c["moe_rows"]
+        # the grouped kernel's grid: 3 slots x 4 choices are 16 rows a
+        # decode call, a chunk's 16 x 4 are 64: one row tile each
+        # (float32 weights), 1 + 4 - 1 items a layer; a live item is an
+        # expert with a row, at least, and at most one a held expert
+        assert c["moe_item_slots"] == c["moe_calls"] * 4 * 4
+        assert c["moe_experts_hit"] * c["moe_calls"] * 4 == \
+            pytest.approx(c["moe_items"])
+    assert eng._moe_grid == {3: (16, 4), 16: (64, 4)}
     assert experts == SHAPES["num_experts"]
     # the window layers' state: counted as a model with state counts it
     per_slot = 4 * WINDOW * 2 * (2 * 64) * 4     # layers x rows x k,v

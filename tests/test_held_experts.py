@@ -9,6 +9,8 @@ and row by row, from the equations of ISSUE 35 (and of
 whole model to).
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -134,14 +136,18 @@ def test_rows_that_are_not_live_are_routed_nowhere():
     assert not np.asarray(y[9:]).any()
 
 
-@pytest.mark.parametrize("sizes", [
-    [10, 0, 13, 5], [0, 0, 0, 0], [40, 0, 0, 0], [0, 0, 0, 40],
-    [1, 1, 1, 1], [7, 9, 8, 8], [0, 3, 0, 0]])
-def test_the_grouped_kernel_against_ragged_dot(sizes):
+@pytest.mark.parametrize("sizes,m", [
+    ([10, 0, 13, 5], 40), ([0, 0, 0, 0], 40), ([40, 0, 0, 0], 40),
+    ([0, 0, 0, 40], 40), ([1, 1, 1, 1], 40), ([7, 9, 8, 8], 40),
+    ([0, 3, 0, 0], 40),
+    # most items dead: 2 live of 80 / 16 + 4 - 1 = 8 (tile_m 16)
+    ([0, 5, 0, 2], 80)])
+def test_the_grouped_kernel_against_ragged_dot(sizes, m):
     """Under the Pallas interpreter: runs that cross a row tile, two runs
     in one tile, EMPTY groups (no work item, no weight read), no row at
-    all. Rows behind the last run are undefined and not compared."""
-    g, d, f, m = 4, 64, 256, 40
+    all, a grid whose items are nearly all dead. Rows behind the last
+    run are undefined and not compared."""
+    g, d, f = 4, 64, 256
     ks = jax.random.split(jax.random.key(0), 4)
     x = jax.random.normal(ks[0], (m, d))
     wg, wu = (0.1 * jax.random.normal(k, (g, d, f)) for k in ks[1:3])
@@ -159,6 +165,87 @@ def test_the_grouped_kernel_against_ragged_dot(sizes):
     items = sum(len({r // 8 for r in range(int(a), int(b))})
                 for a, b in zip(offsets[:-1], offsets[1:]))
     assert int(live[0]) == items
+
+
+def block_fetches(index, gid, tile, n, off, nf):
+    """Fetches a weight stream's pipeline issues over the kernel's whole
+    grid: the first step's, then one at every step whose block index
+    differs from the step before."""
+    fetches, last = 0, None
+    for w in range(gid.shape[0]):
+        for j in range(nf):
+            at = tuple(int(i) for i in index(w, j, gid, tile, n, off))
+            fetches += at != last
+            last = at
+    return fetches
+
+
+def routing_of(sizes, m, tile_m):
+    offsets = np.concatenate([[0], np.cumsum(sizes)]).astype(np.int32)
+    gid, tile, n = (np.asarray(a) for a in moe_mlp.work_items(
+        jnp.asarray(offsets), m, tile_m))
+    return gid, tile, n, offsets
+
+
+@pytest.mark.parametrize("sizes,m,tile_m,f,tile_f", [
+    ([10, 0, 13, 5], 40, 8, 512, 128),
+    ([0, 5, 0, 2], 80, 8, 512, 128),
+    ([0, 0, 0, 40], 40, 8, 512, 128),
+    ([1, 1, 1, 1], 40, 8, 512, 128),
+    # the published decode call: 256 x 8 pairs on 16 held experts, tiles
+    # 64 x 128 of a hidden dim of 2048, some 100 rows routed here
+    ("seeded", 2048, 64, 2048, 128)])
+def test_a_dead_work_item_streams_no_weight(sizes, m, tile_m, f, tile_f):
+    """The pipeline fetches a block where its index changes from one
+    step to the next (``pl.when`` skips the products, not the fetches).
+    With the dead items pinned to the last live step's indices, each
+    weight stream fetches ``live items x f / tile_f`` blocks; the maps
+    before PR 36, ``(expert, 0, j)`` and ``(expert, j, 0)``, fetched an
+    expert for every item of the static grid."""
+    if sizes == "seeded":
+        rng = np.random.default_rng(2147493711)
+        sizes = rng.multinomial(100, rng.dirichlet(np.full(16, 0.3)))
+    gid, tile, n, off = routing_of(sizes, m, tile_m)
+    nf = f // tile_f
+    live = int(n[0])
+    assert live == moe_mlp.live_items(sizes, tile_m) > 0
+    assert gid.shape[0] == m // tile_m + len(sizes) - 1 > live
+    for index in (moe_mlp.weight_cols_index, moe_mlp.weight_rows_index):
+        pinned = functools.partial(index, nf=nf)
+        assert block_fetches(pinned, gid, tile, n, off, nf) == live * nf
+    # the control: the parent's maps walk j from 0 again on a dead item
+    parent_cols = lambda w, j, gi, *_: (gi[w], 0, j)  # noqa: E731
+    parent_down = lambda w, j, gi, *_: (gi[w], j, 0)  # noqa: E731
+    for index in (parent_cols, parent_down):
+        assert block_fetches(index, gid, tile, n, off, nf) == \
+            gid.shape[0] * nf
+    # the x rows and the output tile: one fetch a row tile the items use
+    assert block_fetches(moe_mlp.rows_index, gid, tile, n, off, nf) == \
+        1 + int(np.count_nonzero(np.diff(tile[:live])))
+
+
+def test_a_grid_without_a_live_item_fetches_each_block_once():
+    gid, tile, n, off = routing_of([0, 0, 0, 0], 40, 8)
+    assert int(n[0]) == 0
+    for index in (moe_mlp.weight_cols_index, moe_mlp.weight_rows_index):
+        assert block_fetches(functools.partial(index, nf=4), gid, tile, n,
+                             off, 4) == 1
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_the_hosts_item_count_is_the_kernels(seed):
+    """``live_items`` (the engine's count, on the host) against
+    ``work_items``' live count on the device, on random group sizes at
+    the published call's tiles and at a tiny one's; the sizes are
+    summed over a leading axis as the engine sums its layers."""
+    rng = np.random.default_rng(seed)
+    for m, g, tile_m in ((2048, 16, 64), (1024, 16, 64), (40, 4, 8)):
+        layers = rng.integers(1, 5)
+        sizes = np.stack([rng.multinomial(
+            rng.integers(0, m + 1), rng.dirichlet(np.full(g, 0.5)))
+            for _ in range(layers)])
+        want = sum(int(routing_of(s, m, tile_m)[2][0]) for s in sizes)
+        assert moe_mlp.live_items(sizes, tile_m) == want
 
 
 def test_the_layer_takes_the_kernel_under_the_interpreter(interpret):

@@ -254,17 +254,20 @@ def paged_decode_attention(q, k_pages, v_pages, page_table, lengths,
     read). q: [S, H, hd] — one query token per slot; k_pages/v_pages:
     [N, page_size, KVH*hd] (the pool's own head count KVH divides H: 1 is
     multi-query attention, H the ordinary case); page_table: [S, Pmax]
-    int32 with IN-RANGE entries everywhere (0 for unallocated); lengths: [S] int32 valid
-    token counts (0 = inactive slot -> exactly-zero output).
+    int32, IN-RANGE below each slot's length (the kernel copies no page
+    past it; the XLA path gathers every entry, so 0 for unallocated);
+    lengths: [S] int32 valid token counts (0 = inactive slot ->
+    exactly-zero output).
     k_scale/v_scale: [N, page_size] f32 per-row scales when the pool is
     int8 (init_page_pool(kv_dtype=int8)); both paths dequantize the same
     gathered pages, so kernel-vs-fallback parity holds for quantized
     pools too.
 
-    On TPU (or under pallas_interpret): the Pallas kernel gathers only
-    live pages through the page table and runs flash-style online softmax
-    over page tiles. Elsewhere, or with use_pallas_decode=False: the XLA
-    gather-and-mask formulation (same semantics, dense temporaries)."""
+    On TPU (or under pallas_interpret): the Pallas kernel, one grid step
+    a slot, copies that slot's live pages alone through the page table
+    and runs flash-style online softmax over them. Elsewhere, or with
+    use_pallas_decode=False: the XLA gather-and-mask formulation (same
+    semantics, dense temporaries)."""
     from paddle_tpu.core.flags import get_flag
     from paddle_tpu.ops.pallas.core import kernel_mode
     scale = (float(scale) if scale is not None

@@ -440,6 +440,41 @@ def test_ring_decode_with_eight_kv_heads_under_sixty_four(one_chip, as_tpu):
     assert set(_kernels(hlo)) == {"decode_attention"}
 
 
+# The decode kernel at the serving cells' own shapes (jamba2_3b's and
+# k_exaone_236b's ring are the two cases above): (slots, heads, head_dim,
+# pool, pages a slot, pool dtype). The pools stay in HBM and the kernel
+# copies each live page by hand, so the scoped VMEM holds two pages of K
+# and of V, the carry and (int8) two slots' tables of scale rows: what it
+# needs does not grow with the pool (256 slots at 4k context hold 16384
+# pages, whose two whole scale arrays alone would fill 16 MiB).
+SERVED_DECODE = {
+    "gpt2_medium": (64, 16, 64, (1024, 64, 1024), 16, BF16),
+    "k_exaone_236b_full": (256, 64, 128, (8192, 64, 1024), 32, BF16),
+    "gpt2_medium_int8": (64, 16, 64, (1024, 64, 1024), 16, I8),
+    "k_exaone_236b_full_int8": (256, 64, 128, (8192, 64, 1024), 32, I8),
+    "gpt2_medium_int8_4k_context": (256, 16, 64, (16384, 64, 1024), 64,
+                                    I8),
+}
+
+
+@pytest.mark.parametrize("cell", list(SERVED_DECODE))
+def test_paged_decode_at_the_served_shapes(one_chip, as_tpu, cell):
+    from paddle_tpu.ops.attention import paged_decode_attention
+    slots, heads, hd, pool, p_max, dtype = SERVED_DECODE[cell]
+    shapes = [((slots, heads, hd), F32), (pool, dtype), (pool, dtype),
+              ((slots, p_max), I32), ((slots,), I32)]
+    if dtype == I8:
+        shapes += [(pool[:2], F32)] * 2
+
+    def fn(q, k, v, table, lengths, *scales):
+        kw = dict(zip(("k_scale", "v_scale"), scales))
+        return paged_decode_attention(q, k, v, table, lengths, **kw)
+
+    kernels = _kernels(_compile(one_chip, fn, *shapes))
+    assert set(kernels) == {"decode_attention"}
+    assert 0 < kernels["decode_attention"] <= 16 * 2 ** 20
+
+
 def test_fused_mlp_at_six_thousand_wide_reads_its_weights_once(one_chip,
                                                                as_tpu):
     """K-EXAONE's dense FFN (6144 x 18432) and shared expert (6144 x

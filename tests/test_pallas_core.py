@@ -151,6 +151,105 @@ class TestDecodeParity:
         assert float(jnp.abs(out[0]).max()) == 0.0
         assert float(jnp.abs(out[1:]).min(axis=(1, 2)).max()) > 0.0
 
+    # (heads, K/V heads, head_dim, page_size, pages a slot, int8 pool):
+    # the serving models' head layouts at small pools; a window layer's
+    # ring is one page of 128 rows a slot
+    LAYOUTS = {"mha": (16, 16, 64, 16, 3, False),
+               "gqa": (64, 8, 128, 16, 3, False),
+               "mqa": (20, 1, 128, 16, 3, False),
+               "ring": (64, 8, 128, 128, 1, False),
+               "int8": (16, 16, 64, 16, 3, True)}
+
+    @staticmethod
+    def _lengths(pattern, page, p_max):
+        full = page * p_max
+        return [min(n, full) for n in {
+            # empty, one token, exactly a page, a page and one, full
+            "edges": [0, 1, page, page + 1, full, 0],
+            # only the last slot has pages: the first copy is started
+            # before any slot runs
+            "last_live": [0, 0, 0, 0, 0, full - 3],
+            # live slots between idle ones: the next live slot's first
+            # page is fetched across the idle steps
+            "gaps": [0, page + 1, 0, 0, 2, 0],
+        }[pattern]]
+
+    def _paged_case(self, layout, lengths, seed):
+        from paddle_tpu.ops.attention import init_page_pool, paged_write
+        h, kvh, hd, page, p_max, int8 = self.LAYOUTS[layout]
+        rng = _rs(seed)
+        s = len(lengths)
+        n_pages = s * p_max + 1
+        pool = init_page_pool(n_pages, kvh, page, hd, jnp.float32,
+                              kv_dtype=jnp.int8 if int8 else None)
+        rows = n_pages * page
+        pool = paged_write(
+            pool, jnp.asarray(rng.randn(rows, kvh, hd).astype(np.float32)),
+            jnp.asarray(rng.randn(rows, kvh, hd).astype(np.float32)),
+            jnp.repeat(jnp.arange(n_pages), page),
+            jnp.tile(jnp.arange(page), n_pages))
+        table = (1 + rng.permutation(s * p_max)).reshape(s, p_max)
+        q = jnp.asarray(rng.randn(s, h, hd).astype(np.float32))
+        return pool, table.astype(np.int32), q
+
+    @pytest.mark.parametrize("pattern", ["edges", "last_live", "gaps"])
+    @pytest.mark.parametrize("layout", list(LAYOUTS))
+    def test_live_pages_vs_dense_gather(self, flags, layout, pattern):
+        """The kernel loops over each slot's live pages alone (one grid
+        step a slot, the pages copied by hand, the next one in flight):
+        against the gather-and-mask oracle at every head layout a served
+        model has, the length edges and idle slots between live ones."""
+        from paddle_tpu.ops.attention import (_paged_attention_xla,
+                                              paged_decode_attention)
+        h, kvh, hd, page, p_max, int8 = self.LAYOUTS[layout]
+        lengths = jnp.asarray(self._lengths(pattern, page, p_max),
+                              jnp.int32)
+        pool, table, q = self._paged_case(layout, lengths, seed=p_max)
+        scales = {n: pool[n] for n in ("k_scale", "v_scale") if n in pool}
+        flags({"pallas_interpret": True, "use_pallas_decode": True})
+        before = metrics.counter("pallas.fallback").snapshot().get(
+            "kernel=decode_attention", 0)
+        out = paged_decode_attention(q, pool["k"], pool["v"],
+                                     jnp.asarray(table), lengths, **scales)
+        assert metrics.counter("pallas.fallback").snapshot().get(
+            "kernel=decode_attention", 0) == before  # the kernel ran
+        ref = _paged_attention_xla(q, pool["k"], pool["v"],
+                                   jnp.asarray(table), lengths,
+                                   1.0 / hd ** 0.5, **scales)
+        np.testing.assert_allclose(out, ref, atol=1e-5, rtol=1e-5)
+        idle = np.asarray(lengths) == 0
+        assert float(jnp.abs(out[idle]).max()) == 0.0
+        assert float(jnp.abs(out[~idle]).min(axis=(1, 2)).max()) > 0.0
+
+    @pytest.mark.parametrize("layout", ["mha", "gqa", "int8"])
+    def test_pages_past_a_slot_length_are_never_read(self, flags, layout):
+        """Table entries past a slot's length name a page of NaN (and,
+        int8, NaN scales): the output equals, bit for bit, the same call
+        with those entries 0. A loop that ran on past the live pages and
+        masked them would carry the NaN into the sums (0 x NaN)."""
+        from paddle_tpu.ops.attention import paged_decode_attention
+        h, kvh, hd, page, p_max, int8 = self.LAYOUTS[layout]
+        lengths = jnp.asarray([0, 5, page + 1, 0, 2 * page, 1], jnp.int32)
+        pool, table, q = self._paged_case(layout, lengths, seed=7)
+        poison = table.shape[0] * p_max          # the pool's last page
+        table = np.where(table == poison, 0, table)    # no live entry
+        pool = {n: x.at[poison].set(jnp.nan) if x.dtype == jnp.float32
+                else x for n, x in pool.items()}
+        if not int8:
+            assert bool(jnp.isnan(pool["k"][poison]).all())
+        dead = (np.arange(p_max)[None, :] * page
+                >= np.asarray(lengths)[:, None])
+        scales = {n: pool[n] for n in ("k_scale", "v_scale") if n in pool}
+        flags({"pallas_interpret": True, "use_pallas_decode": True})
+        outs = [paged_decode_attention(
+                    q, pool["k"], pool["v"],
+                    jnp.asarray(np.where(dead, fill, table)), lengths,
+                    **scales)
+                for fill in (poison, 0)]
+        assert not bool(jnp.isnan(outs[0]).any())
+        np.testing.assert_array_equal(np.asarray(outs[0]),
+                                      np.asarray(outs[1]))
+
 
 # --- fused (add+)layer norm -------------------------------------------
 

@@ -29,6 +29,10 @@ Layer map (mirrors reference SURVEY.md §1, re-architected TPU-first):
                                             (ref: platform/profiler.h, tools/timeline.py)
 """
 
+import time as _time
+
+_T_IMPORT = _time.perf_counter()    # the process record `setup.import`
+
 __version__ = "0.1.0"
 
 from paddle_tpu.core import enforce, flags
@@ -62,3 +66,6 @@ from paddle_tpu import profiler
 from paddle_tpu import observability
 from paddle_tpu import initializer
 from paddle_tpu.core.random import seed
+from paddle_tpu.observability import spans as _spans
+
+_spans.note_process("setup.import", _T_IMPORT, _time.perf_counter())

@@ -68,7 +68,7 @@ from paddle_tpu.observability.runlog import (RunLog, read_records,
 # lazily-resolved members -> defining submodule (PEP 562): these pull in
 # jax/profiler, which early importers of the metrics registry must not
 _LAZY = {
-    "span": "spans", "annotate_span": "spans", "span_summary": "spans",
+    "span": "spans", "span_summary": "spans",
     "span_report": "spans", "reset_spans": "spans",
     "spans": None, "telemetry": None, "perf": None,
     "catalog": None, "exporter": None, "watchdog": None,

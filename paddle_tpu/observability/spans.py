@@ -37,6 +37,22 @@ window it holds that window's spans and nothing else, and `records()` /
 reads, one `is_enabled()`, one histogram observation and one ring
 append, and keeps nothing.
 
+Two kinds of record stand outside the span tree (`path` and `parent`
+None, never on a thread's stack), each in a bounded list of its own:
+
+  * `host.gc`: while a session is on, and only then, `gc.callbacks`
+    holds `_on_gc` (installed when the store sees a session begin,
+    removed when it sees one end), so that each collection of the
+    interpreter is a `host.gc` annotation on the trace and one record,
+    counts `generation`, `collected`, `uncollectable`, `thread`.
+    Emptied with the spans when a session begins; `gc_records()`.
+  * process records, one-off and kept across sessions
+    (`process_records()`): `setup.import`, the body of
+    `paddle_tpu/__init__.py`, and `setup.jit.<stage>` for every
+    `jax.monitoring` duration of building a program (`JIT_STAGES`),
+    stamped when it ended. `opened()` is the instant the store saw the
+    newest session begin: what ended before it is set-up.
+
 Nesting concatenates names with '/': a span("ingest") inside
 span("step") has the path "step/ingest" (per-thread stacks — ingestion
 threads and the device loop don't interleave each other's paths).
@@ -51,6 +67,7 @@ threads and the device loop don't interleave each other's paths).
 """
 
 import collections
+import gc
 import itertools
 import threading
 import time
@@ -63,6 +80,22 @@ from paddle_tpu.profiler import event_table
 
 #: the most records the process-wide store keeps; the oldest fall out
 MAX_RECORDS = 200_000
+#: the most `host.gc` records it keeps (the oldest fall out), and the
+#: most process records (the first stay: a program traced inside a
+#: serving loop would otherwise push set-up out)
+MAX_GC_RECORDS = 50_000
+MAX_PROCESS_RECORDS = 16_384
+GC_SPAN = "host.gc"
+#: `jax.monitoring` duration events of building a program -> the
+#: `<stage>` of its `setup.jit.<stage>` record. A fetch from the
+#: persistent cache lies inside its `backend_compile`, and a nested
+#: jit's trace inside its caller's
+JIT_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load",
+}
 
 _TLS = threading.local()
 _HISTOGRAMS = {}                # path -> its `span.<path>` Histogram
@@ -72,24 +105,36 @@ _session_on = jax.profiler.TraceAnnotation.is_enabled
 
 class SpanStore:
     """Bounded in-memory store of one profiler session's span and event
-    records. `dropped` counts what fell out at the old end."""
+    records, its `host.gc` records, and the process's one-off records.
+    `dropped` and `gc_dropped` count what fell out at the old end of
+    theirs; the process list keeps its first records (set-up comes
+    first) and `process_dropped` counts what came after it was full."""
 
-    def __init__(self, max_records=MAX_RECORDS):
+    def __init__(self, max_records=MAX_RECORDS, max_gc=MAX_GC_RECORDS,
+                 max_process=MAX_PROCESS_RECORDS):
         self._lock = threading.Lock()
         self._records = collections.deque(maxlen=max_records)
-        self.dropped = 0
+        self._gc = collections.deque(maxlen=max_gc)
+        self._process, self._max_process = [], max_process
+        self.dropped = self.gc_dropped = self.process_dropped = 0
+        self.opened = None
         self._on = False    # read unlocked: a stale read costs one lock
 
     def session(self):
         """Is a profiler session on? Called at every span's entry; the
-        call that first sees one begin empties the store."""
+        call that first sees one begin empties the session's lists and
+        hooks the collector, the one that sees it end unhooks it."""
         on = _session_on()
         if on != self._on:
             with self._lock:
-                if on and not self._on:
-                    self._records.clear()
-                    self.dropped = 0
-                self._on = on
+                if on != self._on:
+                    if on:
+                        self._records.clear()
+                        self._gc.clear()
+                        self.dropped = self.gc_dropped = 0
+                        self.opened = time.perf_counter()
+                    _hook_gc(on)
+                    self._on = on
         return on
 
     def add(self, record):
@@ -98,9 +143,32 @@ class SpanStore:
                 self.dropped += 1
             self._records.append(record)
 
+    def add_gc(self, record):
+        """Takes no lock: a collection may begin while its own thread
+        holds it. Collections run one at a time, and a deque's append
+        is atomic."""
+        if len(self._gc) == self._gc.maxlen:
+            self.gc_dropped += 1
+        self._gc.append(record)
+
+    def add_process(self, record):
+        with self._lock:
+            if len(self._process) < self._max_process:
+                self._process.append(record)
+            else:
+                self.process_dropped += 1
+
     def records(self):
         with self._lock:
             return list(self._records)
+
+    def gc_records(self):
+        with self._lock:
+            return list(self._gc)
+
+    def process_records(self):
+        with self._lock:
+            return list(self._process)
 
     def __len__(self):
         with self._lock:
@@ -111,9 +179,82 @@ _STORE = SpanStore()
 
 
 def records():
-    """The records of the newest profiler session, oldest first (a span
-    is appended when it ends, so a parent follows its children)."""
+    """The span and event records of the newest profiler session, oldest
+    first (a span is appended when it ends, so a parent follows its
+    children). Reading notices a session's end, as a span's entry
+    does."""
+    _STORE.session()
     return _STORE.records()
+
+
+def gc_records():
+    """The newest profiler session's `host.gc` records, oldest first;
+    reading notices a session's end, as `records()` does."""
+    _STORE.session()
+    return _STORE.gc_records()
+
+
+def process_records():
+    """The process's one-off records (set-up), oldest first: a session
+    does not empty them."""
+    return _STORE.process_records()
+
+
+def opened():
+    """`perf_counter` instant at which the store saw the newest profiler
+    session begin, or None."""
+    return _STORE.opened
+
+
+def note_process(name, start, end):
+    """Keep one process record of ``name`` over ``[start, end]``
+    (`time.perf_counter` seconds)."""
+    _STORE.add_process({"id": next(_IDS), "name": name, "path": None,
+                        "start": start, "end": end, "parent": None,
+                        "rid": None, "counts": {}})
+
+
+_gc_open = None     # (annotation, start) of the collection in progress
+
+
+def _on_gc(phase, info):
+    """`gc.callbacks` entry while a profiler session is on. Collections
+    do not nest and run one at a time, so one slot holds the open one."""
+    global _gc_open
+    if phase == "start":
+        if _session_on():
+            ann = jax.profiler.TraceAnnotation(GC_SPAN)
+            ann.__enter__()
+            _gc_open = ann, time.perf_counter()
+    elif _gc_open is not None:
+        ann, t0 = _gc_open
+        _gc_open = None
+        t1 = time.perf_counter()
+        ann.__exit__(None, None, None)
+        _STORE.add_gc({
+            "id": next(_IDS), "name": GC_SPAN, "path": None, "start": t0,
+            "end": t1, "parent": None, "rid": None,
+            "counts": {"generation": info["generation"],
+                       "collected": info["collected"],
+                       "uncollectable": info["uncollectable"],
+                       "thread": threading.current_thread().name}})
+
+
+def _hook_gc(on):
+    if on and _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
+    elif not on and _on_gc in gc.callbacks:
+        gc.callbacks.remove(_on_gc)
+
+
+def _on_duration(event, duration, **_):
+    stage = JIT_STAGES.get(event)
+    if stage is not None:
+        end = time.perf_counter()
+        note_process("setup.jit." + stage, end - duration, end)
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_duration)
 
 
 def _stack():
@@ -237,16 +378,6 @@ def self_segments(recs):
             own.append((cur, r["end"]))
         out[r["id"]] = own
     return out
-
-
-def annotate_span(name):
-    """Decorator twin of span() (ref: profiler.annotate_fn)."""
-    def deco(fn):
-        def wrapped(*a, **kw):
-            with span(name):
-                return fn(*a, **kw)
-        return wrapped
-    return deco
 
 
 def _span_histograms():

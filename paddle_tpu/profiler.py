@@ -1,4 +1,4 @@
-"""Profiling — trace collection + op-level annotation.
+"""Profiling — trace collection and its tables.
 
 Ref: /root/reference/paddle/fluid/platform/profiler.h:81 (RAII RecordEvent
 around every op run), :166 EnableProfiler/DisableProfiler with sorted event
@@ -7,15 +7,14 @@ timeline.py), and the Python context manager
 python/paddle/fluid/profiler.py.
 
 TPU-first: jax.profiler (XPlane) replaces CUPTI — traces open in
-TensorBoard/Perfetto; `record_event` maps to TraceAnnotation so framework-
-level scopes show up inside device traces; a light host-side EventRecorder
-keeps the reference's sorted-table text report.
+TensorBoard/Perfetto. Framework-level scopes are
+`observability.spans` (annotated on the device trace while a session is
+on); `event_table` prints the reference's sorted text table of their
+summary rows.
 """
 
 import contextlib
 import os
-import time
-from collections import defaultdict
 
 import jax
 
@@ -34,87 +33,9 @@ def profiler(output_dir=None):
         jax.profiler.stop_trace()
 
 
-def record_event(name):
-    """RAII op annotation (ref: platform/profiler.h:81 RecordEvent).
-    Shows up as a named range in the XPlane trace."""
-    return jax.profiler.TraceAnnotation(name)
-
-
-def span(name, rid=None):
-    """record_event promoted: the registry-backed span
-    (observability/spans.py) — times the scope into a metrics histogram
-    AND the device trace, and records it while a profiler session is
-    on."""
-    from paddle_tpu.observability.spans import span as _span
-    return _span(name, rid)
-
-
-def annotate_fn(name):
-    def deco(fn):
-        def wrapped(*a, **kw):
-            with jax.profiler.TraceAnnotation(name):
-                return fn(*a, **kw)
-        return wrapped
-    return deco
-
-
-class EventRecorder:
-    """Host-side timing table (ref: profiler.cc event tables printed by
-    DisableProfiler). Times python-visible spans (incl. dispatch+block).
-
-    `add()` is the non-context entry, `reset()` starts a fresh epoch
-    (state is otherwise append-forever: observability.span() no longer
-    feeds one), and summary/report carry p50/p95 alongside min/max —
-    the tail is where step-time regressions live."""
-
-    def __init__(self):
-        self._events = defaultdict(list)
-
-    @contextlib.contextmanager
-    def record(self, name):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.add(name, time.perf_counter() - t0)
-
-    def add(self, name, seconds):
-        """Record one externally-timed occurrence of `name`."""
-        self._events[name].append(seconds)
-
-    def reset(self):
-        """Drop all recorded events (ref: ResetProfiler)."""
-        self._events.clear()
-
-    @staticmethod
-    def _pctl(sorted_times, q):
-        idx = (len(sorted_times) - 1) * q
-        lo, hi = int(idx), min(int(idx) + 1, len(sorted_times) - 1)
-        frac = idx - lo
-        return sorted_times[lo] * (1.0 - frac) + sorted_times[hi] * frac
-
-    def summary(self, sort_by="total"):
-        rows = []
-        for name, times in self._events.items():
-            ts = sorted(times)
-            rows.append({
-                "name": name, "calls": len(times),
-                "total_s": sum(times),
-                "avg_ms": 1e3 * sum(times) / len(times),
-                "min_ms": 1e3 * ts[0], "max_ms": 1e3 * ts[-1],
-                "p50_ms": 1e3 * self._pctl(ts, 0.50),
-                "p95_ms": 1e3 * self._pctl(ts, 0.95),
-            })
-        rows.sort(key=lambda r: -r["total_s"])
-        return rows
-
-    def report(self):
-        return event_table(self.summary())
-
-
 def event_table(rows):
-    """The sorted text table of summary rows (EventRecorder.summary's,
-    or observability.span_summary's)."""
+    """The sorted text table of summary rows
+    (observability.span_summary's)."""
     lines = [f"{'Event':<40}{'Calls':>8}{'Total(s)':>12}{'Avg(ms)':>12}"
              f"{'p50(ms)':>12}{'p95(ms)':>12}"
              f"{'Min(ms)':>12}{'Max(ms)':>12}"]

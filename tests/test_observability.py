@@ -314,26 +314,190 @@ class TestSpanStore:
         assert 6 not in own
 
 
-class TestEventRecorder:
-    def test_percentiles_and_reset(self):
-        from paddle_tpu.profiler import EventRecorder
-        r = EventRecorder()
-        for v in [0.010] * 9 + [1.0]:
-            r.add("op", v)
-        row = r.summary()[0]
-        assert row["calls"] == 10
-        assert row["p50_ms"] == pytest.approx(10.0)
-        assert 100.0 < row["p95_ms"] < 1000.0      # the tail outlier
-        assert "p95(ms)" in r.report()
-        r.reset()
-        assert r.summary() == []
+class TestHostRecords:
+    """What stands outside the span tree: a collection of the interpreter
+    while a session is on (`host.gc`), and the process's set-up."""
 
-    def test_record_context_still_works(self):
-        from paddle_tpu.profiler import EventRecorder
-        r = EventRecorder()
-        with r.record("ctx"):
+    @pytest.fixture
+    def no_automatic_gc(self):
+        """Only the test's own gc.collect() collects."""
+        import gc
+        was = gc.isenabled()
+        gc.disable()
+        yield
+        if was:
+            gc.enable()
+
+    def test_a_collection_in_a_session_is_one_host_gc_record(
+            self, fresh_store, profiler_session, no_automatic_gc):
+        import gc
+        import threading
+        import time
+
+        from paddle_tpu.observability import spans
+        with profiler_session():
+            spans.event("opened")       # the store sees the session
+            t0 = time.perf_counter()
+            gc.collect()
+            t1 = time.perf_counter()
+        [rec] = spans.gc_records()
+        assert rec["name"] == "host.gc" and t0 <= rec["start"] <= \
+            rec["end"] <= t1
+        assert rec["counts"]["generation"] == 2
+        assert rec["counts"]["thread"] == threading.current_thread().name
+        assert {"collected", "uncollectable"} <= set(rec["counts"])
+        # the tree's readers see the session's spans and events alone
+        assert [r["name"] for r in spans.records()] == ["opened"]
+
+    def test_the_hook_is_in_only_while_a_session_is_on(
+            self, fresh_store, profiler_session, no_automatic_gc):
+        import gc
+
+        from paddle_tpu.observability import spans
+        assert spans._on_gc not in gc.callbacks
+        with spans.span("before"):
+            gc.collect()
+        assert spans._on_gc not in gc.callbacks
+        with profiler_session():
+            with spans.phase("inside"):
+                assert spans._on_gc in gc.callbacks
+        gc.collect()                  # after the session: recorded by none
+        with spans.phase("after"):    # the next scope sees the end ...
             pass
-        assert r.summary()[0]["name"] == "ctx"
+        assert spans._on_gc not in gc.callbacks
+        assert spans.gc_records() == []
+        with profiler_session():
+            spans.event("again")
+        spans.records()               # ... and so does the readers' read
+        assert spans._on_gc not in gc.callbacks
+
+    def test_a_collection_is_no_parent_and_no_child(
+            self, fresh_store, profiler_session, no_automatic_gc):
+        import gc
+
+        from paddle_tpu.observability import spans
+        with profiler_session():
+            with spans.span("outer"):
+                with spans.phase("inner"):
+                    gc.collect()
+                gc.collect()
+        inner, outer = spans.records()
+        pauses = spans.gc_records()
+        assert len(pauses) == 2
+        for p in pauses:
+            assert p["parent"] is None and p["path"] is None
+            assert outer["start"] <= p["start"] <= p["end"] <= outer["end"]
+        ids = {p["id"] for p in pauses}
+        assert not ids & {r["parent"] for r in spans.records()}
+        # self time reads the same with the collections handed in or not
+        own = spans.self_segments([inner, outer])
+        assert spans.self_segments([inner, outer] + pauses) == own
+        assert own[outer["id"]][0] == (outer["start"], inner["start"])
+
+    def test_process_records_outlive_a_sessions_clearing(
+            self, fresh_store, profiler_session, no_automatic_gc):
+        import gc
+
+        import jax
+        import jax.numpy as jnp
+
+        from paddle_tpu.observability import spans
+        spans.note_process("setup.import", 1.0, 2.5)
+        jax.jit(lambda x: jnp.tanh(x) * 3.0 + 0.25)(jnp.ones(7))
+        stages = [r["name"] for r in spans.process_records()]
+        assert stages[0] == "setup.import"
+        assert {"setup.jit.trace", "setup.jit.lower",
+                "setup.jit.compile"} <= set(stages)
+        for r in spans.process_records():
+            assert r["path"] is None and r["parent"] is None
+            assert r["end"] >= r["start"]
+        with profiler_session():
+            with spans.span("one"):
+                gc.collect()
+        assert len(spans.gc_records()) == 1 and spans.opened() is not None
+        with profiler_session():
+            with spans.span("two"):      # a second session empties the
+                pass                     # tree's and the collections' lists
+        assert [r["name"] for r in spans.records()] == ["two"]
+        assert spans.gc_records() == []
+        assert [r["name"] for r in spans.process_records()] == stages
+
+    def test_the_monitoring_stages_become_records(self, fresh_store):
+        import time
+
+        from paddle_tpu.observability import spans
+        t = time.perf_counter()
+        spans._on_duration(
+            "/jax/compilation_cache/cache_retrieval_time_sec", 0.25)
+        spans._on_duration("/jax/some/other_duration", 3.0)
+        [rec] = spans.process_records()
+        assert rec["name"] == "setup.jit.cache_load"
+        assert rec["end"] - rec["start"] == pytest.approx(0.25)
+        assert rec["end"] >= t
+        assert set(spans.JIT_STAGES.values()) == {
+            "trace", "lower", "compile", "cache_load"}
+
+    def test_the_process_wide_store_holds_the_package_import(self):
+        import paddle_tpu
+        from paddle_tpu.observability import spans
+        [imp] = [r for r in spans.process_records()
+                 if r["name"] == "setup.import"]
+        assert 0 < imp["end"] - imp["start"] < 600
+        assert imp["start"] == paddle_tpu._T_IMPORT
+
+    def test_collections_on_many_threads_under_spans(
+            self, fresh_store, profiler_session):
+        """Collections begin wherever a thread allocates, inside a span's
+        bookkeeping too: the hook takes no lock of the store, so no
+        thread waits on itself, and no span is lost."""
+        import gc
+        import sys
+        import threading
+
+        from paddle_tpu.observability import spans
+        threads, rounds = 8, 300
+        names = {f"w{i}" for i in range(threads)}
+
+        def work():
+            for _ in range(rounds):
+                with spans.phase("work"):
+                    [[] for _ in range(20)]
+
+        was_threshold, was_switch = gc.get_threshold(), sys.getswitchinterval()
+        gc.set_threshold(50)
+        sys.setswitchinterval(1e-5)
+        try:
+            with profiler_session():
+                spans.event("opened")
+                pool = [threading.Thread(target=work, name=n) for n in names]
+                for t in pool:
+                    t.start()
+                for t in pool:
+                    t.join(timeout=120)
+                assert not any(t.is_alive() for t in pool)
+        finally:
+            gc.set_threshold(*was_threshold)
+            sys.setswitchinterval(was_switch)
+        assert len(spans.records()) == 1 + threads * rounds
+        pauses = spans.gc_records()
+        assert pauses and {p["counts"]["thread"] for p in pauses} & names
+        assert all(p["end"] >= p["start"] for p in pauses)
+        ends = [p["end"] for p in pauses]
+        starts = [p["start"] for p in pauses]
+        # one collection at a time: none begins before the last one ended
+        assert all(a >= b for a, b in zip(starts[1:], ends))
+
+    def test_the_process_and_collection_lists_are_bounded(self, monkeypatch):
+        from paddle_tpu.observability import spans
+        store = spans.SpanStore(max_gc=3, max_process=2)
+        monkeypatch.setattr(spans, "_STORE", store)
+        for i in range(5):
+            spans.note_process(f"setup.jit.{i}", i, i + 0.5)
+            store.add_gc({"id": i, "name": "host.gc"})
+        assert [r["name"] for r in spans.process_records()] == \
+            ["setup.jit.0", "setup.jit.1"]      # set-up is kept
+        assert [r["id"] for r in spans.gc_records()] == [2, 3, 4]
+        assert (store.process_dropped, store.gc_dropped) == (3, 2)
 
 
 class TestTraceOpTable:

@@ -977,14 +977,29 @@ class GroupedQueryAttention(Module):
                 [ring_pos >= 0, rel[None, :] < chunk_lengths[:, None]],
                 axis=1))
         # two positions of one chunk may share a row (a chunk longer
-        # than the window): only the last ``window`` real ones write
-        keep = ((rel[None, :] < chunk_lengths[:, None])
-                & (rel[None, :] >= chunk_lengths[:, None] - w))
+        # than the window): only the last ``window`` real ones write.
+        # The last ``n`` positions of each chunk's real ones go over (a
+        # negative one, before ``starts``, is dropped), rotated so that
+        # the one on ring row 0 comes first: their rows then rise, and
+        # rows sharing a tile of the ring are adjacent (paged_write's
+        # contract) where the chunk wraps round the ring
+        n = min(t, w)
+        first = starts + chunk_lengths - n                      # [B]
+        wrap = -first % w          # from the first position to row 0
+        shift = jnp.where(wrap < n, wrap, 0)
+        src = (chunk_lengths - n)[:, None] + (
+            jnp.arange(n)[None, :] + shift[:, None]) % n       # [B, n]
+
+        def rotated(y):                                         # [B*n, E]
+            return jnp.take_along_axis(
+                y, jnp.maximum(src, 0)[:, :, None], axis=1).reshape(b * n,
+                                                                    -1)
+
         ring = paged_write(
-            ring, k.reshape(b * t, -1), v.reshape(b * t, -1),
-            jnp.where(keep, slots[:, None], ring["k"].shape[0]
-                      ).reshape(b * t),
-            (pos % w).reshape(b * t))
+            ring, rotated(k), rotated(v),
+            jnp.where(src >= 0, slots[:, None], ring["k"].shape[0]
+                      ).reshape(b * n),
+            ((starts[:, None] + src) % w).reshape(b * n))
         return matmul(ctx, self.p("wo")), ring
 
 

@@ -273,6 +273,14 @@ CATALOG = {
         "(the device never waited for the host between the two); every "
         "round but the first after an empty engine, a speculative round "
         "and a recovery. `overlapped` on the serve.step span's counts."),
+    "serve.kv_rows_written": MetricSpec(
+        "counter", (),
+        "K/V rows the launched programs write into the paged pools, "
+        "counted on the host from its own write targets: a running "
+        "slot's row a decode round (a verify window's rows a "
+        "speculative one), a prefill chunk's real tokens at or past "
+        "its write floor. `kv_rows_written` on the serve.step span's "
+        "counts."),
     "serve.shed": MetricSpec(
         "counter", ("cause",),
         "Queued requests shed by deadline expiry or watchdog-driven "

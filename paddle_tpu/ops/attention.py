@@ -97,8 +97,8 @@ def _as_key_padding_mask(mask, batch, tk):
 #
 # THE pool layout is token-major and lane-dense: [num_pages, page_size,
 # H*hd], one row per cached token with its heads side by side. All three
-# users take it as it lies: paged_write scatters whole rows on the two
-# leading dims (in place on the donated buffer), the decode kernel's page
+# users take it as it lies: paged_write sets whole rows on the two leading
+# dims (in place on the donated buffer), the decode kernel's page
 # block is one contiguous (page_size, H*hd) tile, and the chunked prefill's
 # gather pool[page_rows] is a reshape away from [B, T, H, hd]. There is no
 # second layout. A head-major pool ([N, H, ps, hd]) cost three copies of
@@ -186,11 +186,44 @@ def init_page_pool(num_pages, num_heads, page_size, head_dim,
 def paged_write(pool, k_t, v_t, page_ids, offsets):
     """Scatter per-token K/V rows into pool pages. k_t/v_t: [T, H, hd]
     (or [T, H*hd]); page_ids/offsets: [T] int32 index the pool's two
-    leading dims, so each token is one contiguous H*hd row. An
-    out-of-range page id DROPS the write (mode="drop") — the engine
-    routes inactive slots and pad positions to page id == num_pages on
-    purpose. On a quantized pool each row is quantized on the way in and
-    its scale written beside it."""
+    leading dims, so each token is one contiguous H*hd row. A page id or
+    offset out of range, a negative one too, DROPS the write — the
+    engine routes inactive slots and pad positions to page id ==
+    num_pages on purpose. On a quantized pool each row is quantized on
+    the way in and its scale written beside it.
+
+    On TPU (or under pallas_interpret), a pool of 2- or 4-byte values
+    whose token row (H*hd) is a multiple of 128 lanes and whose pages a
+    multiple of 8 rows is written by one Pallas call for K and V
+    (ops/pallas/paged_write.py), which moves only the rows that land.
+    Its contract: rows that land in one 8-row tile of a page are
+    adjacent among the rows that land (one row a page in a decode
+    round, a chunk's consecutive positions); the interpreter raises on
+    a call that breaks it. Int8 pools (their [N, ps] scales are
+    narrower than a copyable slice), narrow rows and other backends
+    take XLA's scatter."""
+    _, page_size, width = pool["k"].shape
+    if (not quantized_pool(pool) and width % 128 == 0
+            and page_size % 8 == 0 and pool["k"].dtype.itemsize in (2, 4)):
+        from paddle_tpu.ops.pallas.core import INTERPRET, kernel_mode
+        mode = kernel_mode("paged_write")
+        if mode is not None:
+            from paddle_tpu.ops.pallas.paged_write import paged_write_tpu
+
+            # rounded to the pool dtype here, widened for the kernel's
+            # row loads: it narrows back exactly
+            def rows(x):
+                return x.reshape(x.shape[0], -1).astype(
+                    pool["k"].dtype).astype(jnp.float32)
+
+            k, v = paged_write_tpu(
+                pool["k"], pool["v"], rows(k_t), rows(v_t), page_ids,
+                offsets, interpret=mode == INTERPRET)
+            return {"k": k, "v": v}
+    # ``.at[]`` counts a negative index from the end: route it past the
+    # pool, where mode="drop" drops it as the kernel does
+    page_ids = jnp.where((page_ids < 0) | (offsets < 0), pool["k"].shape[0],
+                         page_ids)
     new = {}
     for name, x in (("k", k_t), ("v", v_t)):
         x = x.reshape(x.shape[0], -1)

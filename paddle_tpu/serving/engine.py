@@ -108,7 +108,7 @@ Architecture (the three serving invariants):
 Telemetry (PR-4 registry): serve.queue_depth / serve.active_slots
 gauges, serve.ttft_s + serve.token_latency_s histograms, serve.tokens +
 serve.requests{status} + serve.page_stalls + serve.rounds_overlapped +
-serve.late_rows counters; optional per-step
+serve.late_rows + serve.kv_rows_written counters; optional per-step
 RunLog records (`ServeConfig.run_log`) that tools/run_report.py renders.
 
 Live observability plane (this layer's serving half):
@@ -432,6 +432,10 @@ class ServingEngine:
         #                               before them was read
         self.late_rows = 0            # rows computed for a request that
         #                               had already ended at EOS
+        self.kv_rows_written = 0      # K/V rows the launched programs
+        #                               write into the pools: a running
+        #                               slot's a round, a chunk's real
+        #                               tokens past its write floor
         self._free_slots = list(range(s))
         self._free_pages = collections.deque(range(cfg.num_pages))
         # per-slot sampling state: traced [slots] VALUES of the one
@@ -519,7 +523,7 @@ class ServingEngine:
             "serve.state_bytes_in_use", "serve.spec_proposed",
             "serve.spec_accepted", "serve.spec_rollbacks",
             "serve.rounds_overlapped", "serve.late_rows",
-            "jit.retraces"])
+            "serve.kv_rows_written", "jit.retraces"])
         self._retired = 0
         self._retired_ok = 0
         self._viol_base = dict(
@@ -974,6 +978,7 @@ class ServingEngine:
             t0 = self._clock()
             finished = []
             late0 = self.late_rows
+            rows0 = self.kv_rows_written
             self._routed_read = []
             with phase("serve.admit"):
                 self._shed_expired(finished)
@@ -1052,11 +1057,14 @@ class ServingEngine:
                         spec, self._clock() - t0, finished)
                     new_tokens += n
             late_rows = self.late_rows - late0
+            kv_rows = self.kv_rows_written - rows0
             if overlapped:
                 self.rounds_overlapped += 1
                 _metrics.counter("serve.rounds_overlapped").inc()
             if late_rows:
                 _metrics.counter("serve.late_rows").inc(late_rows)
+            if kv_rows:
+                _metrics.counter("serve.kv_rows_written").inc(kv_rows)
             _metrics.counter("serve.tokens").inc(new_tokens)
             _metrics.gauge("serve.active_slots").set(len(self._running))
             _metrics.gauge("serve.queue_depth").set(len(self._queue))
@@ -1067,7 +1075,8 @@ class ServingEngine:
             _metrics.gauge("serve.kv_pages_in_use").set(in_use)
             sp.count(pages_in_use=in_use, pages_cached=self.pages_cached(),
                      num_pages=self.cfg.num_pages, sampled_rows=sampled_rows,
-                     overlapped=overlapped, late_rows=late_rows)
+                     overlapped=overlapped, late_rows=late_rows,
+                     kv_rows_written=kv_rows)
             if self._stateful:
                 # every running slot holds its recurrent state whole
                 state_bytes = len(self._running) * self._state_bytes_per_slot
@@ -1144,6 +1153,8 @@ class ServingEngine:
                 self._active.copy(), self._temps * self._active,
                 self._top_ks.copy(), self._top_ps.copy(),
                 self._seeds.copy(), self._gen_counts.copy())
+        # an active slot's row lands; the others are routed to drop
+        self.kv_rows_written += int(np.count_nonzero(self._active))
         self._lengths[self._active] += 1     # the pending token is cached
         self._gen_counts[self._active] += 1  # next draw = fold(seed, i)
         return self._took_off(rows, routed=routed)
@@ -1812,6 +1823,8 @@ class ServingEngine:
                         np.asarray([slot], np.int32),
                         *self._sampling_rows(req))
                 routed.extend(chunk)
+                # the chunk's real tokens at or past the write floor
+                self.kv_rows_written += start + clen - max(start, matched)
                 if self._spec_on:
                     # mirror the chunk into the draft pools (same pages,
                     # same write floor — shared prefix pages keep their
@@ -1941,6 +1954,7 @@ class ServingEngine:
                     self._seeds, self._gen_counts + i)
                 props_dev.append(tok)
             window = jnp.stack([jnp.asarray(last)] + props_dev, axis=1)
+            self.kv_rows_written += int(win.sum())   # the target's
             sampled_dev, self._caches = self._verify_jit(
                 self._params, self._caches, window, self._lengths, win,
                 self._page_table, self._temps, self._top_ks, self._top_ps,

@@ -313,11 +313,19 @@ POOL_LAYERS = {
 def _pool_relayouts(hlo, pool_shape):
     """(copies, writes, aliased): the instructions of the compiled
     module that write a whole pool anew (a `copy`, a `transpose`, any
-    fusion but the page write), its in-place page writes (the scatter
-    fusions), and the parameters the module's outputs alias."""
+    fusion but the page write), its in-place page writes (a pool the
+    `paged_write` kernel's aliased custom call hands back, or a scatter
+    fusion: XLA's write, as the control's head-major pool takes it),
+    and the parameters the module's outputs alias."""
     shape = "bf16[" + ",".join(map(str, pool_shape)) + "]"
     copies, writes = [], 0
     for line in hlo.splitlines():
+        kernel = re.match(r"\s*(?:ROOT )?%\S+ = (\(.*?\)) custom-call\(",
+                          line)
+        if (kernel and 'custom_call_target="tpu_custom_call"' in line
+                and "output_to_operand_aliasing" in line):
+            writes += kernel.group(1).count(shape + "{")
+            continue
         m = re.match(r"\s*(?:ROOT )?%\S+ = " + re.escape(shape)
                      + r"\{[^}]*\} (\w[\w-]*)\(", line)
         if not m or m.group(1) in ("parameter", "scatter",
@@ -345,15 +353,17 @@ def test_pool_layout_no_relayout(one_chip, as_tpu, layer):
     """The writer, the decode kernel and the prefill gather take the
     pool as it lies: one layer of `_decode_jit` (write + kernel) and of
     `_prefill_jit` (write + pool[page_rows] gather) holds no copy of a
-    pool, and the donated pools come back in place. The head-major pool
-    paid two such copies a pool in a prefill chunk and three in a decode
-    round: 92% of gpt2_medium.chat's device time (PERF.md, PR 27)."""
+    pool, and the donated pools come back in place, each written once
+    by the write kernel's aliased call. The head-major pool paid two
+    such copies a pool in a prefill chunk and three in a decode round:
+    92% of gpt2_medium.chat's device time (PERF.md section 6)."""
     hlo, pool_shape = _compile_pool_layer(one_chip, layer)
     copies, writes, aliased = _pool_relayouts(hlo, pool_shape)
     assert not copies, copies
     assert writes == 2 and aliased == {0, 1}
-    if layer == "decode":
-        assert set(_kernels(hlo)) == {"decode_attention"}
+    want = {"decode": {"decode_attention", "paged_write"},
+            "prefill": {"paged_write"}}[layer]
+    assert set(_kernels(hlo)) == want
 
 
 def test_pool_layout_control_trips(one_chip):
@@ -364,6 +374,40 @@ def test_pool_layout_control_trips(one_chip):
     copies, writes, _ = _pool_relayouts(hlo, pool_shape)
     assert writes == 2 and len(copies) >= 4, copies
     assert all(" copy(" in c for c in copies)
+
+
+# The K/V write kernel at the served widths, in one layer's call: a
+# decode round of gpt2_medium (64 slots, rows of 16 x 64), of jamba2_3b
+# (128 slots, one K/V head of 128) and of k_exaone_236b's full layer
+# (256 slots, 8 K/V heads of 128) and window ring (one page of 128 rows
+# a slot), and a prefill chunk of 128 positions. Rows come in as f32;
+# the pools are donated and come back written in place.
+SERVED_WRITES = {
+    "gpt2_medium": ((1024, 64, 1024), 64),
+    "gpt2_medium_chunk": ((1024, 64, 1024), CHUNK),
+    "jamba2_3b": ((4096, 64, 128), 128),
+    "k_exaone_236b_full": ((8192, 64, 1024), 256),
+    "k_exaone_236b_ring": ((256, 128, 1024), 256),
+}
+
+
+@pytest.mark.parametrize("cell", list(SERVED_WRITES))
+def test_paged_write_at_the_served_widths(one_chip, as_tpu, cell):
+    from paddle_tpu.ops.attention import paged_write
+    pool, rows = SERVED_WRITES[cell]
+
+    def fn(pk, pv, k, v, ids, offs):
+        out = paged_write({"k": pk, "v": pv}, k, v, ids, offs)
+        return out["k"], out["v"]
+
+    hlo = _compile(one_chip, fn, (pool, BF16), (pool, BF16),
+                   ((rows, pool[2]), F32), ((rows, pool[2]), F32),
+                   ((rows,), I32), ((rows,), I32), donate=(0, 1))
+    kernels = _kernels(hlo)
+    assert set(kernels) == {"paged_write"}
+    assert 0 < kernels["paged_write"] <= 16 * 2 ** 20
+    copies, writes, aliased = _pool_relayouts(hlo, pool)
+    assert not copies and writes == 2 and aliased == {0, 1}, copies
 
 
 # ------------------------------------------ the hybrid decoder's kernels

@@ -329,7 +329,8 @@ class TestEngineSpans:
             decoded += launched
             c = s["counts"]
             assert set(c) == {"pages_in_use", "pages_cached", "num_pages",
-                              "sampled_rows", "overlapped", "late_rows"}
+                              "sampled_rows", "overlapped", "late_rows",
+                              "kv_rows_written"}
             assert c["sampled_rows"] == 0            # every request greedy
             assert c["late_rows"] == 0               # all end by max_new
             # every round but the first after an empty engine is

@@ -328,6 +328,58 @@ class TestServingEngine:
         assert eng.decode_traces == 1 and eng.prefill_traces == 1
         eng.close()
 
+    def test_step_span_counts_the_kv_rows_written(
+            self, rng, new_step_counts, profiler_session):
+        """``kv_rows_written`` on the ``serve.step`` span: the rows the
+        step's launched programs write into the pools, from the host's
+        own write targets. The first step admits two prompts (chunks of
+        5 and 7 real tokens) and launches a round over both slots; each
+        later round writes a row for each slot still running (4 and 6
+        new tokens: 3 and 5 decode rows); the last steps only read. The
+        sum is the rows that changed in a layer's pool."""
+        from paddle_tpu.observability import metrics as _metrics
+        from paddle_tpu.serving import ServeConfig, ServingEngine
+        model, v, cfg = _tiny_decoder()
+        eng = ServingEngine(model, v, ServeConfig(
+            num_slots=2, page_size=8, max_len=32, prefill_len=16,
+            num_pages=8, prefix_cache=False, metrics_port=0))
+        before = _metrics.counter("serve.kv_rows_written").snapshot()
+        with profiler_session():
+            for length, new in ((5, 4), (7, 6)):
+                eng.submit(rng.randint(0, cfg.vocab_size, (length,)
+                                       ).astype(np.int32), max_new=new)
+            eng.drain()
+        rows = new_step_counts("kv_rows_written")
+        assert rows[:5] == [5 + 7 + 2, 2, 2, 1, 1] and not any(rows[5:])
+        assert eng.kv_rows_written == sum(rows) == 20
+        after = _metrics.counter("serve.kv_rows_written").snapshot()
+        assert sum(after.values()) - sum(before.values()) == 20
+        written = np.asarray(jnp.any(eng._caches[0]["k"] != 0, axis=-1))
+        assert int(written.sum()) == 20
+        eng.close()
+
+    def test_kv_rows_written_skips_the_shared_prefix(self, rng):
+        """With the prefix cache, a second prompt whose two first pages
+        are hits skips its first chunk and writes only its tokens past
+        the write floor: 16 + 3 rows for the first prompt's chunks, 5
+        for the second's, one decode row each; every row lands once in
+        the pool."""
+        from paddle_tpu.serving import ServeConfig, ServingEngine
+        model, v, cfg = _tiny_decoder()
+        eng = ServingEngine(model, v, ServeConfig(
+            num_slots=2, page_size=8, max_len=48, prefill_len=16,
+            num_pages=12, metrics_port=0))
+        shared = rng.randint(0, cfg.vocab_size, (16,)).astype(np.int32)
+        for tail in (3, 5):
+            eng.submit(np.concatenate([shared, rng.randint(
+                0, cfg.vocab_size, (tail,)).astype(np.int32)]), max_new=2)
+        eng.drain()
+        assert eng.prefill_tokens_skipped == 16
+        assert eng.kv_rows_written == 16 + 3 + 5 + 2
+        written = np.asarray(jnp.any(eng._caches[0]["k"] != 0, axis=-1))
+        assert int(written.sum()) == eng.kv_rows_written
+        eng.close()
+
     def test_page_exhaustion_stalls_then_recovers(self, rng):
         """With a pool too small for both requests' full growth, a slot
         stalls (counter fires) but decoding still completes correctly
